@@ -63,6 +63,7 @@ import socket
 import stat
 import struct
 import threading
+import time
 from collections import deque
 from typing import Callable, Dict, Optional
 
@@ -256,6 +257,23 @@ class FramedServer:
                 os.unlink(self.path)
             except OSError:
                 pass
+
+    def join(self, timeout: float = 2.0) -> bool:
+        """Wait (bounded, after :meth:`shutdown`) for the selector and
+        worker threads to exit; True when all did. A process about to
+        exit calls this so no server thread outlives the objects its
+        handler owns: a daemon thread's exit drops its bound-method
+        target, and a thread that thereby frees jax objects while the
+        interpreter finalises aborts the process (see
+        obs/profiling.ensure_profiler)."""
+        deadline = time.monotonic() + timeout
+        me = threading.current_thread()
+        threads = [t for t in (self._selector_thread,
+                               *self._worker_threads)
+                   if t is not None and t is not me]
+        for t in threads:
+            t.join(timeout=max(0.0, deadline - time.monotonic()))
+        return not any(t.is_alive() for t in threads)
 
     def sever(self) -> int:
         """Cut every live connection WITHOUT stopping the server — the

@@ -39,7 +39,6 @@ from namazu_tpu.ops.schedule import (
     replicated_trace_specs,
     score_population_multi,
 )
-from namazu_tpu.parallel.mesh import shard_map as compat_shard_map
 
 NO_CHILD = jnp.int32(-1)
 
@@ -347,7 +346,7 @@ def make_parallel_mcts(mesh, H: int, cfg: MCTSConfig = MCTSConfig(),
         return all_fit[g], all_d[g], all_f[g]
 
     def make_sharded(trace_spec):
-        return compat_shard_map(
+        return jax.shard_map(
             _local,
             mesh=mesh,
             in_specs=(P(), trace_spec, P(), P(), P(), P(), P(), P()),

@@ -258,23 +258,28 @@ def make_score_weights(
     )
 
 
-def _enable_persistent_compile_cache() -> None:
-    """Point XLA's persistent compilation cache at a stable user dir.
+#: where the persistent compile cache lives when the caller placed none:
+#: a fixed path inside the checkout, so every process of one experiment
+#: (sidecar, in-process ``run`` children, chip_smoke.py phases) shares
+#: it — the directory is part of the cache key, so it must never move
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-    Policy searches run inside short-lived ``run`` processes (SURVEY.md
-    3.1 — the repro loop is many processes); without the cache every run
-    re-pays the scorer's compile, which dwarfs the actual search at
-    config-2 sizes. Idempotent and best-effort (older jax versions or
-    read-only homes just skip it)."""
+
+def configure_compile_cache() -> None:
+    """Place XLA's persistent compilation cache. One rule: a caller who
+    set ``JAX_COMPILATION_CACHE_DIR`` owns the placement (JAX reads the
+    variable itself; nothing is set in code); otherwise the cache goes
+    to :data:`COMPILE_CACHE_DIR`. Policy searches run inside
+    short-lived ``run`` processes (SURVEY.md 3.1 — the repro loop is
+    many processes); without the cache every one re-pays the fused
+    step's compile."""
     import jax
 
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.expanduser("~/.cache/namazu_tpu/xla"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:  # pragma: no cover - config name drift
-        pass
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
 class SearchBase:
@@ -285,7 +290,7 @@ class SearchBase:
     BACKEND = "base"
 
     def __init__(self, cfg: SearchConfig):
-        _enable_persistent_compile_cache()
+        configure_compile_cache()
         self.cfg = cfg
         self.pairs = te.sample_pairs(cfg.K, cfg.H, cfg.seed)
         # neutral (0.5) features = "no information"; rings overwrite oldest

@@ -33,6 +33,7 @@ extended to profiles).
 
 from __future__ import annotations
 
+import atexit
 import os
 import sys
 import threading
@@ -188,7 +189,10 @@ class Profiler:
                     stack.append(f.f_code)
                     f = f.f_back
                 buf.append((tid, stack))
-            del frames
+            # hold no foreign frame across the wait: a frame kept alive
+            # only by this thread is deallocated HERE, together with
+            # whatever its locals own (see ensure_profiler's exit hook)
+            frames = frame = f = None
 
     # -- fold path (may take the registry lock, off the sample path) ---
 
@@ -538,6 +542,16 @@ def ensure_profiler(job: str, *, interval_s: Optional[float] = None,
         if _PROFILER is None:
             p = Profiler(job, interval_s=interval_s)
             p.start()
+            # join the sampler before the interpreter finalises: it
+            # holds other threads' frames, so it can be the last owner
+            # of a returned function's locals — and when those are jax
+            # objects their C++ destructors drop and retake the GIL. A
+            # daemon thread retaking it after finalisation began is
+            # killed mid-destructor and aborts the process (rc 134
+            # after a successful in-process searched `run`). Registered
+            # after the telemetry relay's exit flush, so it runs first
+            # and the flush still carries the final samples.
+            atexit.register(p.stop)
             _PROFILER = p
     return _PROFILER
 

@@ -47,7 +47,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from namazu_tpu.models.ga import GAConfig, Population, ga_generation, init_population
-from namazu_tpu.parallel.mesh import shard_map as compat_shard_map
 from namazu_tpu.ops.schedule import (
     ScoreWeights,
     TraceArrays,
@@ -261,14 +260,14 @@ def make_multiaxis_island_step(
             P(),  # mutation bias f32[H] (replicated; guidance plane)
         )
 
-    sharded_fault = compat_shard_map(
+    sharded_fault = jax.shard_map(
         _local_step,
         mesh=mesh,
         in_specs=base_specs(fault_trace_spec) + (P(),),  # + fault coin
         out_specs=(pop_spec, P(), P(), P()),
         check_vma=False,
     )
-    sharded_nofault = compat_shard_map(
+    sharded_nofault = jax.shard_map(
         _local_step,
         mesh=mesh,
         in_specs=base_specs(nofault_trace_spec),
@@ -381,14 +380,14 @@ def make_fused_island_step(
         )
         return specs + ((P(),) if with_coin else ())
 
-    sharded_fault = compat_shard_map(
+    sharded_fault = jax.shard_map(
         _fused_local,
         mesh=mesh,
         in_specs=fused_specs(fault_trace_spec, True),
         out_specs=(state_spec, P()),
         check_vma=False,
     )
-    sharded_nofault = compat_shard_map(
+    sharded_nofault = jax.shard_map(
         _fused_local,
         mesh=mesh,
         in_specs=fused_specs(nofault_trace_spec, False),

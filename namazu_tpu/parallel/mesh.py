@@ -8,29 +8,18 @@ import jax
 from jax.sharding import Mesh
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions: older releases ship it as
-    ``jax.experimental.shard_map.shard_map``, and the replication-check
-    kwarg was spelled ``check_rep`` before the ``check_vma`` rename —
-    the two renames landed independently, so detect each by signature
-    rather than assuming they travel together."""
-    import inspect
-
-    if hasattr(jax, "shard_map"):
-        impl = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as impl
-    try:
-        params = inspect.signature(impl).parameters
-        flag = "check_vma" if "check_vma" in params else "check_rep"
-    except (TypeError, ValueError):  # pragma: no cover - exotic wrappers
-        flag = "check_vma"
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **{flag: check_vma})
-
-
 def default_device_count() -> int:
     return len(jax.devices())
+
+
+def device_summary() -> dict:
+    """The devices this process's JAX backend reports — what the search
+    states once per install/reply so a caller can tell a chip run from
+    a CPU one: ``{"platform", "kind", "count"}``."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices)}
 
 
 def make_topology_mesh(

@@ -16,12 +16,14 @@ keep-alive: a connection may carry any number of request/response pairs
 served in order). Old one-shot clients — send one frame, read the
 reply, close — keep working: the server loop simply sees EOF.
 
-* ``{"op": "ping"}`` -> ``{"ok": true, "searches": N}``
+* ``{"op": "ping"}`` -> ``{"ok": true, "searches": N, "device": {...}}``
+  (``device`` = platform/kind/count of the chips this process owns,
+  present once the first search has initialised the backend)
 * ``{"op": "search", "key": str, "storage": dir,
      "search_params": {...}, "ingest_params": {...},
      "generations": N, "checkpoint": path}``
   -> ``{"ok": true, "fitness": f, "delays": [...], "faults": [...],
-        "generations_run": N}``
+        "generations_run": N, "device": {...}}``
 * knowledge-plane ops (``pool_push`` / ``pool_pull`` /
   ``surrogate_predict`` / ``stats``; doc/knowledge.md) when the sidecar
   was started with ``--pool-dir`` — without it they answer
@@ -134,11 +136,17 @@ class SearchService:
         # the archives mid-evolve (set_occupied_buckets) and corrupt the
         # shared checkpoint
         self._key_locks: Dict[str, threading.Lock] = {}
+        # parallel.mesh.device_summary() of the backend the searches run
+        # on; None until the first search is built (a ping must not be
+        # what initialises the device backend)
+        self._device: Optional[dict] = None
 
     def handle(self, req: dict) -> dict:
         op = req.get("op")
         if op == "ping":
             resp = {"ok": True, "searches": len(self._searches)}
+            if self._device is not None:
+                resp["device"] = self._device
         elif op == "search":
             resp = self._search(req)
         else:
@@ -159,6 +167,12 @@ class SearchService:
         # caller already holds this key's lock, which serializes
         # same-key requests (ADVICE r4)
         search = build_search_from_params(params)
+        if self._device is None:
+            from namazu_tpu.parallel.mesh import device_summary
+
+            self._device = device_summary()
+            log.info("searching on %s/%s x%d", self._device["platform"],
+                     self._device["kind"], self._device["count"])
         if checkpoint and os.path.exists(checkpoint):
             try:
                 search.load(checkpoint)
@@ -173,11 +187,13 @@ class SearchService:
 
     def _maybe_reload(self, search, checkpoint: str) -> None:
         """Reload a cached search whose on-disk checkpoint is AHEAD of
-        it: when a sidecar request fails the policy falls back to an
-        in-process evolve and saves, so serving the next request from
-        the stale in-memory state would overwrite those generations at
-        the next save (lost update, ADVICE r4). generations_run is
-        monotonic, so disk-ahead detection is one npz field read."""
+        it: the two homes of the search are interchangeable
+        mid-experiment, so runs under the in-process config may have
+        evolved and saved since this key's last request, and serving
+        the next one from the stale in-memory state would overwrite
+        those generations at the next save (lost update, ADVICE r4).
+        generations_run is monotonic, so disk-ahead detection is one
+        npz field read."""
         if not checkpoint or not os.path.exists(checkpoint):
             return
         try:
@@ -191,7 +207,7 @@ class SearchService:
                 search.load(checkpoint)
                 log.info(
                     "reloaded checkpoint %s: disk at gen %d, cached "
-                    "search at %d (in-process fallback ran between "
+                    "search at %d (an in-process search ran between "
                     "requests)", checkpoint, disk_gen,
                     search.generations_run)
             except Exception:
@@ -255,6 +271,7 @@ class SearchService:
             "delays": [float(x) for x in best.delays],
             "faults": [float(x) for x in best.faults],
             "generations_run": search.generations_run,
+            "device": self._device,
         }
 
 
@@ -294,6 +311,10 @@ class SidecarServer:
         srv, self._srv = self._srv, None
         if srv is not None:
             srv.shutdown()
+            # the handler threads reach the searches' device state
+            # through this object: see them out before the caller (a
+            # process on its way to exit) lets go of it
+            srv.join()
         if self.knowledge is not None:
             self.knowledge.close()
 

@@ -233,10 +233,13 @@ def _replay_once(storage_dir: str, base_cfg: Config, H: int,
             # numbers mean milliseconds in duration params; write the
             # unit out so the seconds value survives verbatim
             "max_interval": f"{max_interval_s}s",
+            # install-only: with one stored run and this cadence the
+            # policy installs triage_repro.npz (an np.load) and returns
+            # before any search is built, so the replay child never
+            # imports jax and needs no device pin
             "search_every": 1_000_000,
             "generations": 1,
             "population": 8,
-            "platform": "cpu",
         })
         cfg["explore_policy_param"] = param
         # NOT config.toml/json: init copies the config by basename, and

@@ -15,11 +15,7 @@ from __future__ import annotations
 
 import json
 import re
-
-try:
-    import tomllib
-except ImportError:  # Python < 3.11: tomli is the same parser/API
-    import tomli as tomllib  # type: ignore[no-redef]
+import tomllib
 from typing import Any, Dict, Optional
 
 DEFAULTS: Dict[str, Any] = {

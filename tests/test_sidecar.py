@@ -1,7 +1,8 @@
 """Persistent search sidecar (SURVEY.md §5.8's orchestrator ⇄ JAX
 boundary): framed-JSON wire, shared ingest with the in-process policy,
 warm-search amortization, checkpoint interchangeability, and the
-policy's sidecar delegation with in-process fallback.
+policy's sidecar delegation — which never searches in-process, because
+the sidecar is the one process that owns the chip.
 """
 
 import time
@@ -53,8 +54,23 @@ def search_req(history, ckpt=""):
 
 
 def test_ping(server):
+    # no search built yet: the ping itself must not be what initialises
+    # the device backend, so it carries no device
     resp = request(f"127.0.0.1:{server.port}", {"op": "ping"})
     assert resp == {"ok": True, "searches": 0}
+
+
+def test_replies_state_the_device(server, history):
+    """The search states which device it ran on where a caller can read
+    it: every search reply, and the ping once a search exists."""
+    import jax
+
+    addr = f"127.0.0.1:{server.port}"
+    want = {"platform": jax.devices()[0].platform,
+            "kind": jax.devices()[0].device_kind,
+            "count": len(jax.devices())}
+    assert request(addr, search_req(history))["device"] == want
+    assert request(addr, {"op": "ping"})["device"] == want
 
 
 def test_search_and_warm_amortization(server, history, tmp_path):
@@ -95,10 +111,10 @@ def test_checkpoint_interchangeable_with_in_process(server, history,
 
 
 def test_cached_search_reloads_newer_checkpoint(server, history, tmp_path):
-    """A failed sidecar request makes the policy evolve in-process and
-    save; the sidecar's next request for that key must reload the newer
-    on-disk checkpoint instead of overwriting it with its stale cached
-    state (lost update, ADVICE r4)."""
+    """Runs under the in-process config may evolve and save between two
+    sidecar requests; the sidecar's next request for that key must
+    reload the newer on-disk checkpoint instead of overwriting it with
+    its stale cached state (lost update, ADVICE r4)."""
     from namazu_tpu.models.ingest import IngestParams, ingest_history
     from namazu_tpu.sidecar import build_search_from_params
 
@@ -107,7 +123,7 @@ def test_cached_search_reloads_newer_checkpoint(server, history, tmp_path):
     r1 = request(addr, search_req(history, ckpt))
     assert r1["ok"]
 
-    # simulate the in-process fallback evolving past the cached state
+    # simulate an in-process search evolving past the cached state
     s = build_search_from_params(SEARCH_PARAMS)
     s.load(ckpt)
     refs = ingest_history(s, history, IngestParams(**INGEST_PARAMS))
@@ -194,9 +210,16 @@ def test_sidecar_without_checkpoint_fails_fast():
         }))
 
 
-def test_policy_falls_back_when_sidecar_down(history):
+def _sidecar_policy(history, addr, monkeypatch):
     from namazu_tpu.policy import create_policy
+    from namazu_tpu.policy.tpu import TPUSearchPolicy
 
+    def no_search(self):
+        raise AssertionError(
+            "a policy in sidecar mode built an in-process search — a "
+            "second process on a chip the sidecar owns")
+
+    monkeypatch.setattr(TPUSearchPolicy, "_build_search", no_search)
     pol = create_policy("tpu_search")
     pol.load_config(Config({
         "explore_policy": "tpu_search",
@@ -204,12 +227,41 @@ def test_policy_falls_back_when_sidecar_down(history):
             "seed": 5, "max_interval": 50, "hint_buckets": 32,
             "feature_pairs": 32, "population": 64, "generations": 2,
             "migrate_k": 2, "surrogate_topk": 0,
-            "sidecar": "127.0.0.1:1",  # nothing listens there
-            "checkpoint": "fb.npz",
+            "sidecar": addr, "checkpoint": "fb.npz",
         },
     }))
     pol.set_history_storage(history)
+    return pol
+
+
+def test_policy_keeps_hash_delays_when_sidecar_down(history, monkeypatch):
+    """One process per chip: with a sidecar configured the policy never
+    constructs a search, whatever happens to the request — here nothing
+    listens, and the hash fallback simply remains."""
+    pol = _sidecar_policy(history, "127.0.0.1:1", monkeypatch)
     pol.start()
-    assert pol.wait_for_search(timeout=180)
-    assert pol._delays is not None  # in-process fallback produced one
+    assert not pol.wait_for_search(timeout=60)  # nothing installed
+    assert pol._search is None and pol._table_source() == "hash"
+    pol.shutdown()
+
+
+def test_policy_keeps_its_table_when_sidecar_refuses(server, history,
+                                                     monkeypatch):
+    """Same rule when the sidecar answers but fails the request: the
+    checkpointed table installed at start stays, no local search."""
+    import os
+
+    ckpt = os.path.join(history.dir, "fb.npz")
+    assert request(f"127.0.0.1:{server.port}",
+                   search_req(history, ckpt))["ok"]
+    monkeypatch.setattr(server.service, "_search",
+                        lambda req: {"ok": False, "error": "boom"})
+    pol = _sidecar_policy(history, f"127.0.0.1:{server.port}",
+                          monkeypatch)
+    pol.start()
+    assert pol.wait_for_search(timeout=60)
+    assert pol._search is None and pol._table_source() == "table"
+    installed = np.array(pol._delays)
+    with np.load(ckpt) as z:
+        np.testing.assert_array_equal(installed, z["best_delays"])
     pol.shutdown()
