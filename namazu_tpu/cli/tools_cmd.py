@@ -412,6 +412,33 @@ def register(sub) -> None:
                                  "started with --pool-dir)")
     pk.set_defaults(func=knowledge_stats)
 
+    psp = tsub.add_parser(
+        "spans",
+        help="a search sidecar's request-scoped spans "
+             "(doc/observability.md \"Request spans\"): one tree per "
+             "search request — queue, handle (lock_wait, load, ingest "
+             "by stage, encode, evolve by place/dispatch/wait, "
+             "surrogate, save), reply — with each span's self time",
+    )
+    psp.add_argument("--sidecar", required=True, metavar="HOST:PORT")
+    psp.add_argument("--chrome", default="", metavar="FILE",
+                     help="also write the rows as Chrome-trace JSON "
+                          "(chrome://tracing, https://ui.perfetto.dev)")
+    psp.set_defaults(func=spans_cmd)
+
+    pdt = tsub.add_parser(
+        "device-trace",
+        help="capture a jax.profiler device trace on a search sidecar "
+             "for some seconds; its nmz:<phase> host spans carry the "
+             "request ids `tools spans` prints",
+    )
+    pdt.add_argument("--sidecar", required=True, metavar="HOST:PORT")
+    pdt.add_argument("--dir", required=True,
+                     help="directory ON THE SIDECAR'S HOST the trace "
+                          "is written into")
+    pdt.add_argument("--seconds", type=float, default=5.0)
+    pdt.set_defaults(func=device_trace_cmd)
+
     pf = tsub.add_parser(
         "fsck",
         help="storage integrity check (doc/robustness.md): list "
@@ -1306,6 +1333,55 @@ def knowledge_stats(args) -> int:
               file=sys.stderr)
         return 1
     print(json.dumps(stats, sort_keys=True, indent=2))
+    return 0
+
+
+def spans_cmd(args) -> int:
+    """Page the sidecar's span ring empty (the ``spans`` op,
+    obs/federation.py) and print it per request."""
+    from namazu_tpu.obs import export
+    from namazu_tpu.sidecar import request
+
+    rows, cursor, dropped = [], 0, 0
+    while True:
+        resp = request(args.sidecar, {"op": "spans", "since": cursor})
+        if not resp.get("ok"):
+            print(f"error: {resp.get('error', resp)}", file=sys.stderr)
+            return 1
+        dropped = resp["dropped"]
+        if not resp["rows"]:
+            break
+        rows += resp["rows"]
+        cursor = resp["next"]
+    if args.chrome:
+        with open(args.chrome, "w") as f:
+            json.dump(export.chrome_trace(None, spans=rows), f)
+    sys.stdout.write(export.render_span_trees(rows))
+    if dropped:
+        print(f"({dropped} older row(s) were pushed out of the ring)")
+    return 0
+
+
+def device_trace_cmd(args) -> int:
+    """Start a capture (the sidecar's ``device_trace`` op) and wait
+    until its timer has stopped it."""
+    import time
+
+    from namazu_tpu.sidecar import request
+
+    resp = request(args.sidecar, {"op": "device_trace", "dir": args.dir,
+                                  "seconds": args.seconds})
+    if not resp.get("ok"):
+        print(f"error: {resp.get('error', resp)}", file=sys.stderr)
+        return 1
+    deadline = time.monotonic() + resp["seconds"] + 120.0
+    time.sleep(resp["seconds"])
+    while request(args.sidecar, {"op": "device_trace"}).get("live"):
+        if time.monotonic() > deadline:
+            print("error: the capture did not stop", file=sys.stderr)
+            return 1
+        time.sleep(0.2)
+    print(resp["dir"])
     return 0
 
 

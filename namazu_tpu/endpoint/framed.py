@@ -39,6 +39,10 @@ request/response pairs per connection):
   has its Lamport clock merged before the handler runs, and the
   response echoes a fresh ``ctx`` stamp; context-less requests get
   byte-identical responses to the pre-context wire;
+* **request scope** (obs/spans.py): the selector stamps each frame as
+  it completes; for the ops in :data:`SPAN_OPS` the worker opens a
+  request scope from that stamp (id from the frame's ``ctx`` when it
+  carries one), so the wait for a pool slot is a span like any other;
 * per-connection ``codec`` negotiation is answered by the serve loop
   itself, uniformly across every framed wire;
 * shutdown severs live connections (a parked long-poll must error and
@@ -55,6 +59,7 @@ rebind its port immediately.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import queue
@@ -91,6 +96,14 @@ Decorator = Callable[[dict, dict], None]
 #: slot — a campaign winding down several serve slots at once must not
 #: convoy every other tenant's wire.
 DEFAULT_BLOCKING_OPS = frozenset({"poll", "lease", "release"})
+
+#: ops served under a request scope (obs/spans.py "request-scoped
+#: spans"): the request gets an id, its wait between "frame complete"
+#: and "worker starts" is the ``queue`` span, the response write the
+#: ``reply`` span, and the handler's own phases nest under the id. Only
+#: the search plane's requests — the event plane's six-figure frame
+#: rates pay one clock read per frame for the stamp and nothing else.
+SPAN_OPS = frozenset({"search"})
 
 
 def reclaim_stale_unix_socket(path: str, what: str = "server") -> None:
@@ -368,7 +381,7 @@ class FramedServer:
                 self._close_conn(sel, conn)
                 return
             codec, body = frame
-            if not self._enqueue(conn, codec, body):
+            if not self._enqueue(conn, codec, body, time.monotonic()):
                 self._close_conn(sel, conn)
                 return
 
@@ -393,28 +406,30 @@ class FramedServer:
         del buf[:4 + length]
         return codec, body
 
-    def _enqueue(self, conn: _Conn, codec: str, body: bytes) -> bool:
-        """Queue one raw frame for processing, preserving per-connection
-        FIFO; False = the client pipelined past the bound (drop it)."""
+    def _enqueue(self, conn: _Conn, codec: str, body: bytes,
+                 arrived: float) -> bool:
+        """Queue one raw frame (complete at monotonic ``arrived``) for
+        processing, preserving per-connection FIFO; False = the client
+        pipelined past the bound (drop it)."""
         with conn.plock:
             if conn.busy:
                 if len(conn.pending) >= conn.MAX_PENDING:
                     return False
-                conn.pending.append((codec, body))
+                conn.pending.append((codec, body, arrived))
                 return True
             conn.busy = True
-        self._work.put((conn, codec, body))
+        self._work.put((conn, codec, body, arrived))
         return True
 
     def _finish_task(self, conn: _Conn) -> None:
         """A request finished: start the next queued frame, or go idle."""
         with conn.plock:
             if conn.pending:
-                codec, body = conn.pending.popleft()
+                task = conn.pending.popleft()
             else:
                 conn.busy = False
                 return
-        self._work.put((conn, codec, body))
+        self._work.put((conn, *task))
 
     def _close_conn(self, sel, conn: _Conn) -> None:
         if sel is not None:
@@ -442,9 +457,9 @@ class FramedServer:
             task = self._work.get()
             if task is None:
                 return
-            conn, codec, body = task
+            conn, codec, body, arrived = task
             try:
-                self._process(conn, codec, body)
+                self._process(conn, codec, body, arrived)
             except Exception:  # pragma: no cover - defensive
                 log.exception("%s frame processing failed", self._name)
                 self._finish_task(conn)
@@ -453,7 +468,18 @@ class FramedServer:
         with conn.wlock:
             return write_frame(conn.sock, resp, codec=codec)
 
-    def _process(self, conn: _Conn, codec: str, body: bytes) -> None:
+    def _send_in_codec(self, conn: _Conn, resp: dict, codec: str) -> int:
+        """Answer in the codec the request arrived in — per-frame,
+        stateless, so mixed-codec clients on one endpoint work. A
+        handler value the binary codec cannot carry degrades THIS
+        response to JSON rather than desync."""
+        try:
+            return self._send(conn, resp, codec)
+        except TypeError:
+            return self._send(conn, resp, _binary.CODEC_JSON)
+
+    def _process(self, conn: _Conn, codec: str, body: bytes,
+                 arrived: float) -> None:
         """Decode one frame and answer it (worker thread)."""
         try:
             if codec == _binary.CODEC_BINARY:
@@ -516,23 +542,37 @@ class FramedServer:
             if not over:
                 threading.Thread(
                     target=self._answer_parked,
-                    args=(conn, req, codec, len(body)),
+                    args=(conn, req, codec, len(body), arrived),
                     name=f"{self._name}-poll", daemon=True).start()
                 return
-        self._answer(conn, req, codec, len(body))
+        self._answer(conn, req, codec, len(body), arrived)
         self._finish_task(conn)
 
     def _answer_parked(self, conn: _Conn, req: dict, codec: str,
-                       n_in: int) -> None:
+                       n_in: int, arrived: float) -> None:
         try:
-            self._answer(conn, req, codec, n_in)
+            self._answer(conn, req, codec, n_in, arrived)
         finally:
             with self._parked_lock:
                 self._parked -= 1
             self._finish_task(conn)
 
     def _answer(self, conn: _Conn, req: dict, codec: str,
-                n_in: int) -> None:
+                n_in: int, arrived: float) -> None:
+        if req.get("op") not in SPAN_OPS:
+            self._answer_scoped(conn, req, codec, n_in, False)
+            return
+        # the request's id and arrival stamp are the handler's to read
+        # (obs.current_request) for as long as this thread serves it
+        scoped = _spans.request_begin(req.get(_context.CTX_KEY),
+                                      arrived) is not None
+        try:
+            self._answer_scoped(conn, req, codec, n_in, scoped)
+        finally:
+            _spans.request_end()
+
+    def _answer_scoped(self, conn: _Conn, req: dict, codec: str,
+                       n_in: int, scoped: bool) -> None:
         ctx_seen = self._observe_ctx(req)
         try:
             resp = self._handler(req)
@@ -551,16 +591,9 @@ class FramedServer:
             # context-less peers get the pre-context wire byte for byte
             resp.setdefault(_context.CTX_KEY, _context.wire_stamp())
         try:
-            # answer in the codec the request arrived in — per-frame,
-            # stateless, so mixed-codec clients on one endpoint work
-            n_out = self._send(conn, resp, codec)
-        except TypeError:
-            # a handler value the binary codec cannot carry: degrade
-            # THIS response to JSON rather than desync
-            try:
-                n_out = self._send(conn, resp, _binary.CODEC_JSON)
-            except OSError:
-                return
+            with (_spans.search_phase("reply") if scoped
+                  else contextlib.nullcontext()):
+                n_out = self._send_in_codec(conn, resp, codec)
         except OSError:
             return
         _spans.wire_bytes(codec, str(req.get("op") or "frame"),

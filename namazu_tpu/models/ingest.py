@@ -9,6 +9,7 @@ a schedule trained in one home would not replay in the other.
 
 from __future__ import annotations
 
+import time
 from typing import List, NamedTuple, Optional
 
 import numpy as np
@@ -124,9 +125,34 @@ def failure_seed(trace, H: int, max_interval: float):
     return seed if got else None
 
 
+class _Stages:
+    """Wall time accumulated per ingest stage over the per-run loops:
+    one ``obs.search_phase_observed`` each per ingest (a span per
+    stored run would cost more than it shows)."""
+
+    def __init__(self) -> None:
+        self.stages: dict = {}  # stage -> [first start, seconds, pieces]
+
+    def add(self, stage: str, start: float) -> float:
+        """Charge ``start``..now to ``stage``; returns now."""
+        now = time.monotonic()
+        row = self.stages.setdefault(stage, [start, 0.0, 0])
+        row[1] += now - start
+        row[2] += 1
+        return now
+
+    def report(self) -> None:
+        for stage, (first, seconds, pieces) in self.stages.items():
+            obs.search_phase_observed(stage, seconds, first,
+                                      pieces=pieces)
+
+
 def ingest_history(search, storage, p: IngestParams) -> List:
     """Feed stored traces into the search's archives; return the
-    reference traces to evolve against.
+    reference traces to evolve against. One ``ingest`` phase per call
+    in whichever home runs it, with its stages beside it
+    (``ingest_read`` / ``ingest_encode`` / ``ingest_embed`` /
+    ``ingest_pool``, doc/observability.md "Request spans").
 
     References are the most recent SUCCESSFUL runs (padded with failures
     only when no success exists yet): the counterfactual asks "what
@@ -139,10 +165,24 @@ def ingest_history(search, storage, p: IngestParams) -> List:
     """
     if storage is None:
         return []
+    stages = _Stages()
+    with obs.search_phase("ingest") as attrs:
+        try:
+            return _ingest_history(search, storage, p, stages, attrs)
+        finally:
+            stages.report()
+
+
+def _ingest_history(search, storage, p: IngestParams, stages: _Stages,
+                    attrs: dict) -> List:
+    t = time.monotonic()
     try:
         n = storage.nr_stored_histories()
     except Exception:
         return []
+    attrs["runs"] = n
+    obs.ingest_runs(n)
+    stages.add("ingest_read", t)
     # causality guidance: wire the map BEFORE any archive write so the
     # DAG-shape feature fragments land slot-aligned with the archive.
     # ``fresh``: every ingest re-feeds the WHOLE stored history, so the
@@ -156,10 +196,12 @@ def ingest_history(search, storage, p: IngestParams) -> List:
     encoded = []
     skipped_unstamped = 0
     for i in range(n):
+        t = time.monotonic()
         try:
             trace = storage.get_stored_history(i)
             ok = storage.is_successful(i)
         except Exception:
+            stages.add("ingest_read", t)
             continue
         # runs recorded under a different replay-hint format hash into a
         # different bucket space — training on them would deliver
@@ -173,6 +215,7 @@ def ingest_history(search, storage, p: IngestParams) -> List:
                      .get("hint_space", "content-v1"))
         except Exception:
             stamp = "content-v1"
+        t = stages.add("ingest_read", t)
         if stamp != HINT_SPACE:
             skipped_unstamped += 1
             continue
@@ -193,6 +236,7 @@ def ingest_history(search, storage, p: IngestParams) -> List:
                 else "order-mode memory bound")
         seed = None if ok else failure_seed(trace, p.H, p.max_interval)
         encoded.append((enc, enc_rt, ok, seed))
+        stages.add("ingest_encode", t)
     if skipped_unstamped:
         log.warning(
             "%d stored run(s) recorded in another hint space were "
@@ -208,6 +252,7 @@ def ingest_history(search, storage, p: IngestParams) -> List:
     # client logs one warning; a campaign never fails on knowledge)
     pooled = []
     client = None
+    t = time.monotonic()
     if p.knowledge:
         from namazu_tpu.knowledge import shared_client
 
@@ -267,6 +312,7 @@ def ingest_history(search, storage, p: IngestParams) -> List:
                      "search (pool %s%s)", len(pooled),
                      p.failure_pool or "-",
                      f", knowledge {p.knowledge}" if p.knowledge else "")
+        t = stages.add("ingest_pool", t)
     # concentrate the feature pairs on the buckets the experiment
     # actually produces BEFORE embedding anything (a pair change clears
     # the archives; the loop below repopulates them in full)
@@ -318,6 +364,7 @@ def ingest_history(search, storage, p: IngestParams) -> List:
             failures.append(enc)
         else:
             successes.append(enc)
+    t = stages.add("ingest_embed", t)
     if gmap is not None:
         scenario = p.knowledge_scenario or "local"
         obs.relation_coverage(scenario, gmap.covered(), gmap.width,
@@ -332,6 +379,7 @@ def ingest_history(search, storage, p: IngestParams) -> List:
             })
     if client is not None and encoded:
         _push_surrogate_examples(client, search, encoded)
+        stages.add("ingest_pool", t)
     if p.reference_mode == "envelope" and successes:
         return [te.envelope_trace(successes)]
     pool = successes if successes else failures

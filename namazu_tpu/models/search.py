@@ -74,15 +74,6 @@ class SearchConfig(NamedTuple):
     # DCN ring every dcn_migrate_every (1 = the pre-cadence behavior)
     migrate_every: int = 1
     dcn_migrate_every: int = 1
-    # device-trace capture knob (doc/observability.md "Profiling"):
-    # when non-empty, the FIRST fused run() of this search records a
-    # jax.profiler device trace of its evolve section into
-    # <device_trace_dir>/device_trace (open in perfetto / xprof) —
-    # one capture per search, not continuous, so the dump cost never
-    # taxes the loop it measures. The host-vs-device split stays in
-    # nmz_search_phase_seconds; the trace is the per-op zoom-in.
-    # "" disables (the default).
-    device_trace_dir: str = ""
 
 
 class BestSchedule(NamedTuple):
@@ -291,6 +282,9 @@ class SearchBase:
 
     def __init__(self, cfg: SearchConfig):
         configure_compile_cache()
+        # both backends construct through here: the first search of a
+        # process is what starts counting its lowerings
+        obs.ensure_compile_listener()
         self.cfg = cfg
         self.pairs = te.sample_pairs(cfg.K, cfg.H, cfg.seed)
         # neutral (0.5) features = "no information"; rings overwrite oldest
@@ -584,25 +578,26 @@ class SearchBase:
     def save(self, path: str) -> None:
         import jax
 
-        flat = {
-            "backend": np.asarray(self.BACKEND),
-            "hint_space": np.asarray(te.HINT_SPACE),
-            "pairs": self.pairs,
-            "archive": self.archive,
-            "archive_labels": self.archive_labels,
-            "archive_n": np.asarray(self._archive_n),
-            "failures": self.failures,
-            "failure_n": np.asarray(self._failure_n),
-            "failure_digests": np.asarray(self._failure_digests),
-            "key": np.asarray(jax.random.key_data(self._key)),
-            "generations_run": np.asarray(self.generations_run),
-        }
-        if self.guidance_feats is not None:
-            flat["guidance_feats"] = self.guidance_feats
-        flat.update(self._state_dict())
-        tmp = path + ".tmp.npz"
-        np.savez(tmp, **flat)
-        os.replace(tmp, path)
+        with obs.search_phase("save"):
+            flat = {
+                "backend": np.asarray(self.BACKEND),
+                "hint_space": np.asarray(te.HINT_SPACE),
+                "pairs": self.pairs,
+                "archive": self.archive,
+                "archive_labels": self.archive_labels,
+                "archive_n": np.asarray(self._archive_n),
+                "failures": self.failures,
+                "failure_n": np.asarray(self._failure_n),
+                "failure_digests": np.asarray(self._failure_digests),
+                "key": np.asarray(jax.random.key_data(self._key)),
+                "generations_run": np.asarray(self.generations_run),
+            }
+            if self.guidance_feats is not None:
+                flat["guidance_feats"] = self.guidance_feats
+            flat.update(self._state_dict())
+            tmp = path + ".tmp.npz"
+            np.savez(tmp, **flat)
+            os.replace(tmp, path)
 
     def load(self, path: str) -> None:
         import jax
@@ -740,8 +735,6 @@ class ScheduleSearch(SearchBase):
         # dispatch leaves self._state pointing at deleted buffers, and
         # this (a few KB) is what _recover_state rebuilds the best from
         self._best_snapshot = None
-        # one-shot device-trace capture latch (cfg.device_trace_dir)
-        self._device_traced = False
 
     def _reset_best(self) -> None:
         import jax.numpy as jnp
@@ -944,40 +937,6 @@ class ScheduleSearch(SearchBase):
         with obs.search_phase("extract"):
             return self.best()
 
-    def _maybe_start_device_trace(self) -> bool:
-        """Start the one-shot ``jax.profiler`` device-trace capture
-        when ``cfg.device_trace_dir`` is set and nothing was captured
-        yet. Fail-open: a profiler the runtime can't start (no jax, a
-        capture already live elsewhere) degrades to no trace, never an
-        error into the search."""
-        if not self.cfg.device_trace_dir or self._device_traced:
-            return False
-        self._device_traced = True
-        out = os.path.join(self.cfg.device_trace_dir, "device_trace")
-        try:
-            import jax
-
-            os.makedirs(out, exist_ok=True)
-            jax.profiler.start_trace(out)
-        except Exception as e:
-            log.warning("device-trace capture unavailable (%s); "
-                        "search continues untraced", e)
-            return False
-        log.info("capturing device trace of this evolve section "
-                 "into %s", out)
-        return True
-
-    def _stop_device_trace(self) -> None:
-        try:
-            import jax
-
-            jax.profiler.stop_trace()
-        except Exception:  # stop must never mask the evolve outcome
-            log.debug("device-trace stop failed", exc_info=True)
-            return
-        obs.search_device_trace(
-            os.path.join(self.cfg.device_trace_dir, "device_trace"))
-
     def _run_fused(self, encoded, generations: int) -> BestSchedule:
         """The device-resident loop (doc/performance.md "Fused search
         loop"): generations run in fused_chunk-sized scans — one jitted
@@ -986,7 +945,10 @@ class ScheduleSearch(SearchBase):
         (``jax.device_get`` on arrays the device finished or is
         finishing while the current chunk computes). The host gap shows
         up as ``nmz_search_phase_seconds{phase="host_io"}`` and the
-        generation record's ``host_io_s``."""
+        generation record's ``host_io_s``. Inside ``evolve``: ``place``
+        (re-sharding the state), ``dispatch`` (time inside the fused
+        calls, accumulated: the host's share of an async dispatch) and
+        ``wait`` (the final block on the device)."""
         with obs.search_phase("encode"):
             encs, trace, pairs, archive, failures = \
                 self._device_inputs_fused(encoded)
@@ -1001,7 +963,7 @@ class ScheduleSearch(SearchBase):
         host_io_s = 0.0
         fit_curve: list = []
         pending = None
-        tracing = self._maybe_start_device_trace()
+        dispatch_s, dispatch_t0, dispatches = 0.0, None, 0
         t0 = time.perf_counter()
         with obs.search_phase("evolve"):
             # the whole evolve section recovers as one unit: dispatch
@@ -1012,16 +974,21 @@ class ScheduleSearch(SearchBase):
             # self._state must be rebuilt, or every later run() of a
             # long-lived sidecar search fails against deleted arrays.
             try:
-                self._place_state()  # one jit cache entry, not two
+                with obs.search_phase("place"):
+                    self._place_state()  # one jit cache entry, not two
                 done = 0
                 while done < generations:
                     g = min(self.cfg.fused_chunk, generations - done)
                     fused = self._fused_step_for(g)
                     # the input state is DONATED: keep only the
                     # returned one
+                    td = time.monotonic()
                     state, fit_hist = fused(
                         self._state, self._key, trace, pairs, archive,
                         failures, coin, nov_scale, bias)
+                    dispatch_s += time.monotonic() - td
+                    dispatch_t0 = td if dispatch_t0 is None else dispatch_t0
+                    dispatches += 1
                     self._state = state
                     done += g
                     if pending is not None:
@@ -1037,13 +1004,15 @@ class ScheduleSearch(SearchBase):
                     with obs.search_phase("host_io"):
                         self._drain_host_lane(pending, fit_curve)
                     host_io_s += time.perf_counter() - th
-                self._state.best_fitness.block_until_ready()
+                if dispatch_t0 is not None:
+                    obs.search_phase_observed("dispatch", dispatch_s,
+                                              dispatch_t0,
+                                              pieces=dispatches)
+                with obs.search_phase("wait"):
+                    self._state.best_fitness.block_until_ready()
             except Exception:
                 self._recover_state()
                 raise
-            finally:
-                if tracing:
-                    self._stop_device_trace()
         elapsed = time.perf_counter() - t0
         self.generations_run += generations
         # recovery snapshot (tiny: two [H] rows + a scalar): the newest

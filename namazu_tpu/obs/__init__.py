@@ -119,8 +119,12 @@ from namazu_tpu.obs.spans import (  # noqa: F401
     schedule_install,
     scorer_throughput,
     scorer_throughput_value,
+    current_request,
+    ensure_compile_listener,
+    ingest_runs,
     search_device_trace,
     search_phase,
+    search_phase_observed,
     search_progress,
     search_round,
     search_stall,

@@ -11,6 +11,10 @@ A :class:`~namazu_tpu.obs.recorder.RunTrace` renders three ways:
 * :func:`to_ndjson` — newline-delimited JSON, one record per line with
   run-relative timestamps (µs precision), stable across identical
   scripted runs, so two runs diff with plain ``diff``.
+* :func:`span_trees` / :func:`render_span_trees` — the search plane's
+  request-scoped span rows (obs/spans.py, read over the framed ``spans``
+  op) as per-request trees with self time; :func:`chrome_trace` takes
+  the same rows as a second input and draws them on a fourth block.
 * :func:`order_lines` / :func:`diff_runs` — the realized dispatch
   ORDER only (entity + event class + hint), the thing Namazu exists to
   control; :func:`diff_runs` renders two runs' orders as a unified
@@ -24,7 +28,7 @@ from __future__ import annotations
 
 import difflib
 import json
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
 # Chrome-trace process ids: one synthetic "process" per plane so the
 # viewer groups entity tracks, policy tracks, and the search plane's
@@ -32,11 +36,13 @@ from typing import Any, Dict, List
 PID_ENTITIES = 1
 PID_POLICIES = 2
 PID_SEARCH = 3
+PID_REQUESTS = 4
 
 _PROCESS_NAMES = {
     PID_ENTITIES: "entities",
     PID_POLICIES: "policies",
     PID_SEARCH: "search plane",
+    PID_REQUESTS: "search requests",
 }
 
 
@@ -67,11 +73,33 @@ class _Tracks:
         return tid
 
 
-def chrome_trace(run) -> Dict[str, Any]:
-    """Render a recorded run as a Chrome Trace Event JSON document."""
-    snap = run.snapshot()
+def chrome_trace(run, spans: Optional[List] = None) -> Dict[str, Any]:
+    """Render a recorded run as a Chrome Trace Event JSON document.
+    ``spans`` — request-scoped span rows (obs/spans.py) — draw on a
+    block of their own, one track per serving thread; ``run`` may be
+    None when the rows are all there is (``nmz-tpu tools spans
+    --chrome``), the clock then starts at the earliest row."""
+    spans = spans or []
+    if run is not None:
+        snap = run.snapshot()
+    else:
+        snap = {"run_id": "", "records": [], "generations": [],
+                "dropped_records": 0,
+                "started_mono": min([r[4] for r in spans] or [0.0]),
+                "started_wall": min([r[3] for r in spans] or [0.0])}
     tracks = _Tracks()
     events: List[Dict[str, Any]] = []
+
+    for rid, name, parent, _wall, mono, seconds, thread, attrs in spans:
+        # async pairs keyed by the request: a ``queue`` span was waited
+        # out while its worker still served the request before, so
+        # complete 'X' slices on the thread's track would not nest
+        pair = {"name": name, "cat": "span", "id": str(rid or "none"),
+                "pid": PID_REQUESTS,
+                "tid": tracks.tid(PID_REQUESTS, str(thread))}
+        events.append(dict(pair, ph="b", ts=_us(snap, mono), args=dict(
+            attrs or {}, rid=rid, parent=parent, seconds=seconds)))
+        events.append(dict(pair, ph="e", ts=_us(snap, mono + seconds)))
 
     for entry in snap["records"]:
         rec, doc = entry["rec"], entry["json"]
@@ -159,7 +187,8 @@ def chrome_trace(run) -> Dict[str, Any]:
     meta = [{
         "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
         "args": {"name": pname},
-    } for pid, pname in sorted(_PROCESS_NAMES.items())]
+    } for pid, pname in sorted(_PROCESS_NAMES.items())
+        if spans or pid != PID_REQUESTS]
     return {
         "traceEvents": meta + tracks.meta + events,
         "displayTimeUnit": "ms",
@@ -170,6 +199,74 @@ def chrome_trace(run) -> Dict[str, Any]:
             "dropped_records": snap["dropped_records"],
         },
     }
+
+
+def span_trees(rows: List) -> List[Dict[str, Any]]:
+    """Span rows ``(rid, name, parent, t_wall, t_mono, seconds, thread,
+    attrs)`` as one tree per request, oldest request first. A node:
+    ``{"name", "t_mono", "seconds", "self_s", "attrs", "children"}``;
+    ``self_s`` is the span minus the part of it its children cover
+    (a child accumulated over a loop, ``pieces=<n>``, by its length). A
+    row's parent is the span of that name, under the same request,
+    that was open when the row started."""
+    by_rid: Dict[Any, List[Dict[str, Any]]] = {}
+    for rid, name, parent, _wall, mono, seconds, _thread, attrs in rows:
+        by_rid.setdefault(rid, []).append({
+            "name": name, "parent": parent, "t_mono": mono,
+            "seconds": seconds, "attrs": attrs or {}, "children": []})
+    trees = []
+    for rid, nodes in by_rid.items():
+        nodes.sort(key=lambda n: (n["t_mono"], -n["seconds"]))
+        roots = []
+        for node in nodes:
+            home = [p for p in nodes if p["name"] == node["parent"]
+                    and p is not node]
+            inside = [p for p in home if p["t_mono"] <= node["t_mono"]
+                      <= p["t_mono"] + p["seconds"]]
+            owner = (inside or home or [None])[-1]
+            (roots if owner is None else owner["children"]).append(node)
+        for node in nodes:
+            covered, upto = 0.0, node["t_mono"]
+            end = node["t_mono"] + node["seconds"]
+            for child in node["children"]:
+                if "pieces" in child["attrs"]:
+                    # accumulated over a loop: no interval of its own
+                    covered += child["seconds"]
+                    continue
+                a = max(child["t_mono"], upto)
+                b = min(child["t_mono"] + child["seconds"], end)
+                if b > a:
+                    covered += b - a
+                    upto = b
+            node["self_s"] = max(0.0, node["seconds"] - covered)
+            del node["parent"]
+        trees.append({"rid": rid, "t_mono": nodes[0]["t_mono"],
+                      "seconds": max(n["t_mono"] + n["seconds"]
+                                     for n in nodes) - nodes[0]["t_mono"],
+                      "spans": roots})
+    trees.sort(key=lambda t: t["t_mono"])
+    return trees
+
+
+def render_span_trees(rows: List) -> str:
+    """:func:`span_trees` as indented text, one block per request."""
+    out: List[str] = []
+
+    def walk(node, depth):
+        attrs = "".join(f"  {k}={v}" for k, v in sorted(
+            node["attrs"].items()))
+        out.append(f"{'  ' * depth}{node['name']:<{24 - 2 * depth}}"
+                   f"{node['seconds']:10.6f} s  self "
+                   f"{node['self_s']:10.6f} s{attrs}")
+        for child in node["children"]:
+            walk(child, depth + 1)
+
+    for tree in span_trees(rows):
+        out.append(f"request {tree['rid'] or '(none)'}  "
+                   f"{tree['seconds']:.6f} s")
+        for node in tree["spans"]:
+            walk(node, 1)
+    return "\n".join(out) + ("\n" if out else "")
 
 
 def to_ndjson(run) -> str:

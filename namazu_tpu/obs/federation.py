@@ -1292,7 +1292,8 @@ def handle_obs_op(req: dict,
                   agg: Optional[FleetAggregator] = None
                   ) -> Optional[dict]:
     """Answer one framed observability op (``telemetry`` / ``fleet`` /
-    ``metrics``); None = not an obs op (the caller keeps dispatching).
+    ``metrics`` / ``profile`` / ``spans``); None = not an obs op (the
+    caller keeps dispatching).
     Both framed wires — the uds event endpoint and the campaign
     supervisor's collector — route here, so the fleet surface is
     identical wherever the aggregator is hosted."""
@@ -1329,6 +1330,17 @@ def handle_obs_op(req: dict,
         if req.get("format") == "collapsed":
             return {"ok": True, "text": profiling.render_collapsed()}
         return {"ok": True, "profile": profiling.payload()}
+    if op == "spans":
+        # this process's request-scoped span rows (obs/spans.py), paged
+        # by the cursor the previous reply returned as ``next``
+        from namazu_tpu.obs import spans
+
+        try:
+            since = int(req.get("since") or 0)
+            limit = int(req.get("limit") or 1024)
+        except (TypeError, ValueError):
+            return {"ok": False, "error": "since/limit must be integers"}
+        return dict(spans.span_ring().since(since, limit), ok=True)
     return None
 
 

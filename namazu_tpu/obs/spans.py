@@ -30,7 +30,9 @@ tests/test_obs.py pins this down).
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
 import threading
 import time
 from typing import Optional
@@ -62,6 +64,12 @@ SCORER_THROUGHPUT = "nmz_scorer_schedules_per_sec"
 SEARCH_PHASE = "nmz_search_phase_seconds"
 SEARCH_HOST_GAP = "nmz_search_host_gap_share"
 SEARCH_DEVICE_TRACES = "nmz_search_device_traces_total"
+SPAN_ROWS_DROPPED = "nmz_span_rows_dropped_total"
+INGEST_RUNS = "nmz_ingest_runs_total"
+COMPILES = "nmz_compiles_total"
+COMPILE_SECONDS = "nmz_compile_seconds"
+#: the jax.monitoring event of one jaxpr->MLIR lowering
+LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 SEARCH_STALL = "nmz_search_stall"
 SIDECAR_REQUESTS = "nmz_sidecar_requests_total"
 ENTITY_LABEL_OVERFLOW = "nmz_entity_label_overflow_total"
@@ -1113,7 +1121,7 @@ def scorer_throughput_value(source: str) -> Optional[float]:
 _trace_annotation_cls = None
 
 
-def _trace_annotation(name: str):
+def _trace_annotation(name: str, rid: Optional[str] = None):
     global _trace_annotation_cls
     cls = _trace_annotation_cls
     if cls is None:
@@ -1124,41 +1132,226 @@ def _trace_annotation(name: str):
         _trace_annotation_cls = cls
     if cls is False:
         return contextlib.nullcontext()
-    return cls(name)
+    return cls(name) if rid is None else cls(name, rid=rid)
+
+
+# -- request-scoped spans (search plane) ----------------------------------
+#
+# ``search_phase`` is the search plane's ONE span source: a histogram
+# observation, a profiler annotation and a row in a bounded in-memory
+# ring, all carrying the id of the request being served. The framed
+# server opens a request scope around its handler (``request_begin`` /
+# ``request_end``, endpoint/framed.py); inside it every phase nests
+# under the phase that is open on the same thread. Rows are read over
+# the framed ``spans`` op (obs/federation.py) and rendered by
+# ``nmz-tpu tools spans``.
+
+#: rows the span ring keeps; overflow drops the oldest
+SPAN_RING_ROWS = 8192
+
+
+class SpanRing:
+    """Span rows ``(rid, name, parent, t_wall, t_mono, seconds, thread,
+    attrs)``, newest last, read by a cursor that counts every row ever
+    appended (so a reader that falls behind sees a gap, not a
+    repeat)."""
+
+    def __init__(self, rows: int = SPAN_RING_ROWS) -> None:
+        self._rows: collections.deque = collections.deque(maxlen=rows)
+        self._lock = threading.Lock()
+        self._appended = 0
+
+    def append(self, row: tuple) -> None:
+        with self._lock:
+            full = len(self._rows) == self._rows.maxlen
+            self._rows.append(row)
+            self._appended += 1
+        if full:
+            metrics.get().counter(
+                SPAN_ROWS_DROPPED,
+                "span rows pushed out of the ring by newer ones").inc()
+
+    def since(self, cursor: int = 0, limit: int = 1024) -> dict:
+        """Rows from ``cursor`` on (at most ``limit``), the cursor to
+        pass next, the rows lost to overflow so far, and a
+        ``{wall, mono}`` anchor read in one call."""
+        with self._lock:
+            first = self._appended - len(self._rows)
+            start = min(max(int(cursor), first), self._appended)
+            rows = list(itertools.islice(
+                self._rows, start - first, start - first + max(0, limit)))
+            dropped = first
+        return {"rows": [list(r) for r in rows],
+                "next": start + len(rows), "dropped": dropped,
+                "anchor": {"wall": time.time(), "mono": time.monotonic()}}
+
+
+_span_ring = SpanRing()
+#: per-thread request scope: ``rid`` / ``arrived`` (set by the framed
+#: server) and ``stack`` (names of the phases open on this thread)
+_scope = threading.local()
+_request_counter = itertools.count(1)
+
+
+def span_ring() -> SpanRing:
+    return _span_ring
+
+
+def reset_span_ring(rows: int = SPAN_RING_ROWS) -> SpanRing:
+    """Fresh empty ring (tests)."""
+    global _span_ring
+    _span_ring = SpanRing(rows)
+    return _span_ring
+
+
+def request_begin(ctx, arrived: float) -> Optional[str]:
+    """Open the calling thread's request scope: mint the request id —
+    ``"<o>:<lc>"`` of the frame's ``ctx`` stamp when it carries one
+    (obs/context.py ``wire_stamp``), so a client's id survives the hop,
+    else a per-process counter — and record the ``queue`` span from
+    ``arrived`` (monotonic, stamped where the frame completed) to now.
+    Returns the id; None (and no scope) while observability is off."""
+    if not metrics.enabled():
+        return None
+    if (isinstance(ctx, dict) and ctx.get("o")
+            and isinstance(ctx.get("lc"), int)):
+        rid = f"{ctx['o']}:{ctx['lc']}"
+    else:
+        from namazu_tpu.obs import context
+
+        rid = f"{context.origin()}:r{next(_request_counter)}"
+    _scope.rid, _scope.arrived = rid, arrived
+    search_phase_observed("queue", time.monotonic() - arrived, arrived)
+    return rid
+
+
+def request_end() -> None:
+    _scope.__dict__.clear()
+
+
+def current_request() -> Optional[tuple]:
+    """``(rid, arrived)`` of the request this thread is serving."""
+    rid = _scope.__dict__.get("rid")
+    return None if rid is None else (rid, _scope.arrived)
+
+
+def _open_phase() -> Optional[str]:
+    """The innermost phase open on this thread."""
+    stack = _scope.__dict__.get("stack")
+    return stack[-1] if stack else None
+
+
+def _append_row(name: str, seconds: float, t_mono: float,
+                parent: Optional[str], attrs: dict) -> None:
+    _span_ring.append((
+        _scope.__dict__.get("rid"), name, parent,
+        time.time() - (time.monotonic() - t_mono), t_mono, seconds,
+        threading.current_thread().name, attrs))
+
+
+def _record_phase(phase: str, seconds: float, t_mono: float,
+                  parent: Optional[str], attrs: dict) -> None:
+    metrics.get().histogram(
+        SEARCH_PHASE,
+        "wall time per search-plane phase",
+        ("phase",),
+    ).labels(phase=phase).observe(seconds)
+    _append_row(phase, seconds, t_mono, parent, attrs)
 
 
 @contextlib.contextmanager
-def search_phase(phase: str):
-    """Time one search-plane phase (ingest / evolve / extract / install
-    / surrogate / host_io — the last is the fused loop's overlapped
-    host-I/O lane, doc/performance.md) into
-    ``nmz_search_phase_seconds{phase=...}`` and, when
-    jax's profiler is importable, annotate the region into any active
-    device profile via ``jax.profiler.TraceAnnotation`` (no-op without a
-    profiler session, no-op fallback when jax is absent). Finer-grained
-    in-step phases (mutate/score/select/migrate) are annotated with
-    ``jax.named_scope`` inside the jitted island step
+def search_phase(phase: str, **attrs):
+    """Time one search-plane phase into
+    ``nmz_search_phase_seconds{phase=...}``, annotate the region into
+    any active device profile via ``jax.profiler.TraceAnnotation``
+    (``nmz:<phase>``, with the request id as ``rid``; no-op without a
+    profiler session, no-op fallback when jax is absent) and append one
+    row to the span ring under the current request and parent phase.
+    Yields ``attrs`` so the body can add what it learns (``runs=``).
+    The phase vocabulary is doc/observability.md "Request spans".
+    Finer-grained in-step phases (mutate/score/select/migrate) are
+    annotated with ``jax.named_scope`` inside the jitted island step
     (parallel/islands.py), where host-side timers cannot reach."""
     if not metrics.enabled():
-        yield
+        yield attrs
         return
-    t0 = time.perf_counter()
+    scope = _scope.__dict__
+    stack = scope.setdefault("stack", [])
+    parent = stack[-1] if stack else None
+    rid = scope.get("rid")
+    stack.append(phase)
+    t0 = time.monotonic()
     try:
-        with _trace_annotation(f"nmz:{phase}"):
-            yield
+        with _trace_annotation(f"nmz:{phase}", rid):
+            yield attrs
     finally:
-        metrics.get().histogram(
-            SEARCH_PHASE,
-            "wall time per search-plane phase",
-            ("phase",),
-        ).labels(phase=phase).observe(time.perf_counter() - t0)
+        stack.pop()
+        _record_phase(phase, time.monotonic() - t0, t0, parent, attrs)
+
+
+def search_phase_observed(phase: str, seconds: float, t_mono_start: float,
+                          **attrs) -> None:
+    """Record a phase that was measured across threads or accumulated
+    over a loop: histogram + row like :func:`search_phase`, under the
+    phase open on this thread, but no profiler annotation. A phase
+    accumulated over a loop states ``pieces=<n>``: its row starts at
+    the first piece and is ``seconds`` long, so it may overlap its
+    siblings, and readers charge it by its length (obs/export.py
+    ``span_trees``)."""
+    if not metrics.enabled():
+        return
+    _record_phase(phase, seconds, t_mono_start, _open_phase(), attrs)
+
+
+_compile_listener_on = False
+
+
+def ensure_compile_listener() -> None:
+    """Register, once per process, the search plane's lowering
+    listener: every jaxpr->MLIR lowering counts into
+    ``nmz_compiles_total`` and ``nmz_compile_seconds{phase}`` with the
+    innermost phase open on the compiling thread (``none`` outside
+    one), and leaves a ``compile`` row under the current request —
+    which step recompiled, for which request. Called by the search
+    backends' constructors (the first thing in a process that can
+    compile)."""
+    global _compile_listener_on
+    if _compile_listener_on:
+        return
+    _compile_listener_on = True
+    import jax.monitoring
+
+    def on_duration(event, duration, **_kw):
+        if event != LOWERING_EVENT or not metrics.enabled():
+            return
+        phase = _open_phase()
+        reg = metrics.get()
+        reg.counter(COMPILES, "jaxpr->MLIR lowerings (each one a "
+                              "compile or a cache load)").inc()
+        reg.histogram(
+            COMPILE_SECONDS, "lowering time by the search phase that "
+            "was open on the compiling thread", ("phase",),
+        ).labels(phase=phase or "none").observe(duration)
+        _append_row("compile", float(duration),
+                    time.monotonic() - duration, phase, {})
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
+def ingest_runs(n: int) -> None:
+    """Stored runs one ingest walked (the divisor of the per-run
+    ingest stage times)."""
+    if not metrics.enabled():
+        return
+    metrics.get().counter(
+        INGEST_RUNS, "stored runs walked by history ingests").inc(n)
 
 
 def search_device_trace(path: str) -> None:
     """One completed ``jax.profiler`` device-trace capture dumped into
-    ``path`` (the ``device_trace_dir`` knob, models/search.py): counted
-    and stamped into the flight recorder so the trace directory
-    correlates with the run that produced it."""
+    ``path`` (the sidecar's ``device_trace`` op): counted and stamped
+    into the flight recorder so the trace directory correlates with the
+    run that produced it."""
     if not metrics.enabled():
         return
     metrics.get().counter(

@@ -74,12 +74,6 @@ class TPUSearchPolicy(QueueBackedPolicy):
         # a semantics one. fused = false restores the pre-fusion loop.
         self.fused = True
         self.fused_chunk = 16
-        # device-trace capture knob (doc/observability.md "Profiling"):
-        # non-empty = the FIRST fused evolve of this search dumps a
-        # jax.profiler device trace under <dir>/device_trace, folding
-        # device time into the nmz_search_phase_seconds host-side story.
-        # One-shot per search object; "" (default) = off.
-        self.device_trace_dir = ""
         # migration cadence, decoupled from the generation count: the
         # intra-host ICI ring permutes every migrate_every generations;
         # on a hybrid host x chip mesh (dcn_hosts > 1) the cross-host
@@ -243,8 +237,6 @@ class TPUSearchPolicy(QueueBackedPolicy):
         self.migrate_k = int(p("migrate_k", self.migrate_k))
         self.fused = bool(p("fused", self.fused))
         self.fused_chunk = max(1, int(p("fused_chunk", self.fused_chunk)))
-        self.device_trace_dir = str(
-            p("device_trace_dir", self.device_trace_dir) or "")
         self.migrate_every = max(1, int(p("migrate_every",
                                           self.migrate_every)))
         self.dcn_migrate_every = max(1, int(p("dcn_migrate_every",
@@ -690,7 +682,6 @@ class TPUSearchPolicy(QueueBackedPolicy):
             fused_chunk=self.fused_chunk,
             migrate_every=self.migrate_every,
             dcn_migrate_every=self.dcn_migrate_every,
-            device_trace_dir=self.device_trace_dir,
         )
         mesh = None
         if self.dcn_hosts > 1:
@@ -912,8 +903,7 @@ class TPUSearchPolicy(QueueBackedPolicy):
                     log.info(
                         "installed checkpointed schedule (fitness %.4f) "
                         "before this run's search", b.fitness)
-            with obs.search_phase("ingest"):
-                references = self._ingest_history(search)
+            references = self._ingest_history(search)
             if not references:
                 log.info("no stored history yet; keeping hash-based delays")
                 return
@@ -949,7 +939,6 @@ class TPUSearchPolicy(QueueBackedPolicy):
             "migrate_k": self.migrate_k,
             "fused": self.fused,
             "fused_chunk": self.fused_chunk,
-            "device_trace_dir": self.device_trace_dir,
             "migrate_every": self.migrate_every,
             "dcn_migrate_every": self.dcn_migrate_every,
             "seed": self.seed,
@@ -983,13 +972,14 @@ class TPUSearchPolicy(QueueBackedPolicy):
         keeps its current table."""
         import numpy as _np
 
+        from namazu_tpu.obs.context import wire_stamp
         from namazu_tpu.sidecar import request
 
         storage_dir = getattr(self._storage, "dir", None)
         if not storage_dir:
             raise RuntimeError(
                 "sidecar search needs a directory-backed storage")
-        resp = request(self.sidecar, {
+        req = {
             "op": "search",
             "key": os.path.abspath(storage_dir),
             "storage": os.path.abspath(storage_dir),
@@ -997,7 +987,16 @@ class TPUSearchPolicy(QueueBackedPolicy):
             "ingest_params": self._ingest_params()._asdict(),
             "generations": self.generations,
             "checkpoint": os.path.abspath(ckpt) if ckpt else "",
-        }, timeout=max(self.search_join_timeout, 30.0))
+        }
+        rid = ""
+        if obs.metrics.enabled():
+            # the stamp is the request's id on the sidecar's spans
+            # (obs/spans.py request_begin), so this run's log line and
+            # the sidecar's span tree name the same request
+            stamp = req["ctx"] = wire_stamp()
+            rid = f", request {stamp['o']}:{stamp['lc']}"
+        resp = request(self.sidecar, req,
+                       timeout=max(self.search_join_timeout, 30.0))
         if not resp.get("ok"):
             raise RuntimeError(f"sidecar: {resp.get('error', 'failed')}")
         if resp.get("no_history"):
@@ -1007,9 +1006,9 @@ class TPUSearchPolicy(QueueBackedPolicy):
         self._install_tables(_np.asarray(resp["delays"], _np.float32),
                              _np.asarray(resp["faults"], _np.float32),
                              "sidecar")
-        log.info("installed sidecar schedule (fitness %.4f, gen %d) on %s",
-                 resp["fitness"], resp["generations_run"],
-                 _device_str(resp.get("device")))
+        log.info("installed sidecar schedule (fitness %.4f, gen %d) on "
+                 "%s%s", resp["fitness"], resp["generations_run"],
+                 _device_str(resp.get("device")), rid)
         self._knowledge_push_best(self._delays, float(resp["fitness"]))
 
     # -- global failure-knowledge plane (doc/knowledge.md) ---------------

@@ -24,6 +24,10 @@ reply, close — keep working: the server loop simply sees EOF.
      "generations": N, "checkpoint": path}``
   -> ``{"ok": true, "fitness": f, "delays": [...], "faults": [...],
         "generations_run": N, "device": {...}}``
+* ``{"op": "device_trace", "dir": D, "seconds": S}`` -> a
+  ``jax.profiler`` capture of the next S seconds into D
+  (:class:`DeviceTraceCapture`); ``{"op": "spans", "since": N}`` -> the
+  request-scoped span rows (obs/federation.py ``handle_obs_op``)
 * knowledge-plane ops (``pool_push`` / ``pool_pull`` /
   ``surrogate_predict`` / ``stats``; doc/knowledge.md) when the sidecar
   was started with ``--pool-dir`` — without it they answer
@@ -97,7 +101,6 @@ def build_search_from_params(p: dict):
         fused_chunk=int(p.get("fused_chunk", 16)),
         migrate_every=int(p.get("migrate_every", 1)),
         dcn_migrate_every=int(p.get("dcn_migrate_every", 1)),
-        device_trace_dir=str(p.get("device_trace_dir", "") or ""),
     )
     n_devices = p.get("devices")
     if p.get("search_backend", "ga") == "mcts":
@@ -123,6 +126,75 @@ def build_search_from_params(p: dict):
     return search
 
 
+class DeviceTraceCapture:
+    """The device trace on demand: one ``jax.profiler`` capture at a
+    time, started by the ``device_trace`` op and stopped by a timer, so
+    the op holds a framed worker for the start only. The program's
+    ``nmz:<phase>`` annotations carry the request id as ``rid``, so the
+    capture and the ``spans`` op describe the same requests. Fail-open:
+    a capture already live, or a profiler the runtime cannot start,
+    answers ``{"ok": false}`` and never raises into the wire."""
+
+    MAX_SECONDS = 600.0
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._timer: Optional[threading.Timer] = None
+        self._dir = ""
+
+    def handle(self, req: dict) -> dict:
+        """The ``device_trace`` op: start a capture into ``dir`` for
+        ``seconds``, or (no ``dir``) read whether one is live."""
+        out = str(req.get("dir") or "")
+        if not out:
+            # a status read: the CLI polls this until the timer fired
+            return {"ok": True, "live": self._timer is not None,
+                    "dir": self._dir}
+        try:
+            seconds = min(max(float(req.get("seconds", 5.0)), 0.0),
+                          self.MAX_SECONDS)
+        except (TypeError, ValueError):
+            return {"ok": False, "error": "seconds must be a number"}
+        with self._lock:
+            if self._timer is not None:
+                return {"ok": False, "live": True, "dir": self._dir,
+                        "error": "a device trace is already live"}
+            try:
+                import jax
+
+                os.makedirs(out, exist_ok=True)
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                opts.host_tracer_level = 2
+                jax.profiler.start_trace(out, profiler_options=opts)
+            except Exception as e:
+                log.warning("device-trace capture unavailable (%s)", e)
+                return {"ok": False, "error": f"profiler: {e}"}
+            self._dir = out
+            self._timer = threading.Timer(seconds, self.stop)
+            self._timer.daemon = True
+            self._timer.start()
+        log.info("capturing a %.1f s device trace into %s", seconds, out)
+        return {"ok": True, "live": True, "dir": out, "seconds": seconds}
+
+    def stop(self) -> None:
+        """End the live capture, if any (the timer, and shutdown: a
+        profiler session must not outlive the server)."""
+        with self._lock:
+            timer, self._timer = self._timer, None
+            if timer is None:
+                return
+            timer.cancel()
+            try:
+                import jax
+
+                jax.profiler.stop_trace()
+            except Exception:
+                log.debug("device-trace stop failed", exc_info=True)
+                return
+        obs.search_device_trace(self._dir)
+
+
 class SearchService:
     """Holds one live search per experiment key."""
 
@@ -140,6 +212,7 @@ class SearchService:
         # on; None until the first search is built (a ping must not be
         # what initialises the device backend)
         self._device: Optional[dict] = None
+        self.device_trace = DeviceTraceCapture()
 
     def handle(self, req: dict) -> dict:
         op = req.get("op")
@@ -148,7 +221,11 @@ class SearchService:
             if self._device is not None:
                 resp["device"] = self._device
         elif op == "search":
-            resp = self._search(req)
+            # the root of the request's span tree (obs/spans.py)
+            with obs.search_phase("handle"):
+                resp = self._search(req)
+        elif op == "device_trace":
+            resp = self.device_trace.handle(req)
         else:
             resp = {"ok": False, "error": f"unknown op {op!r}"}
         obs.sidecar_request(str(op), bool(resp.get("ok")))
@@ -220,20 +297,27 @@ class SearchService:
 
     def _search(self, req: dict) -> dict:
         key = str(req.get("key") or req.get("storage") or "default")
-        with self._key_lock(key):
+        lock = self._key_lock(key)
+        with obs.search_phase("lock_wait"):
+            lock.acquire()
+        try:
             return self._search_locked(key, req)
+        finally:
+            lock.release()
 
     def _search_locked(self, key: str, req: dict) -> dict:
         from namazu_tpu.models.ingest import IngestParams, ingest_history
 
         params = req.get("search_params") or {}
         checkpoint = str(req.get("checkpoint") or "")
-        search, fresh = self._get_search(key, params, checkpoint)
         storage_dir = req.get("storage")
-        try:
-            storage = load_storage(storage_dir) if storage_dir else None
-        except Exception as e:
-            return {"ok": False, "error": f"storage: {e}"}
+        with obs.search_phase("load"):
+            search, fresh = self._get_search(key, params, checkpoint)
+            try:
+                storage = (load_storage(storage_dir) if storage_dir
+                           else None)
+            except Exception as e:
+                return {"ok": False, "error": f"storage: {e}"}
         ip = req.get("ingest_params") or {}
         if ip.get("knowledge"):
             # a sidecar-hosted search serves knowledge-wired tenants
@@ -315,6 +399,7 @@ class SidecarServer:
             # through this object: see them out before the caller (a
             # process on its way to exit) lets go of it
             srv.join()
+        self.service.device_trace.stop()
         if self.knowledge is not None:
             self.knowledge.close()
 
