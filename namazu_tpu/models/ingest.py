@@ -40,8 +40,9 @@ def _push_surrogate_examples(client, search, encoded) -> None:
 
     try:
         examples = []
-        for enc, enc_rt, ok, _seed in encoded[-MAX_EXAMPLE_PUSH:]:
-            feats = search._feats_of(enc_rt)
+        recent = encoded[-MAX_EXAMPLE_PUSH:]
+        rows = search._embed([enc_rt for _, enc_rt, _, _ in recent])
+        for (enc, enc_rt, ok, _seed), feats in zip(recent, rows):
             if search.guidance_feats is not None:
                 # guided campaigns train on [precedence | DAG-shape];
                 # the widened K keys a separate service-side store, so
@@ -133,12 +134,12 @@ class _Stages:
     def __init__(self) -> None:
         self.stages: dict = {}  # stage -> [first start, seconds, pieces]
 
-    def add(self, stage: str, start: float) -> float:
+    def add(self, stage: str, start: float, pieces: int = 1) -> float:
         """Charge ``start``..now to ``stage``; returns now."""
         now = time.monotonic()
         row = self.stages.setdefault(stage, [start, 0.0, 0])
         row[1] += now - start
-        row[2] += 1
+        row[2] += pieces
         return now
 
     def report(self) -> None:
@@ -152,7 +153,9 @@ def ingest_history(search, storage, p: IngestParams) -> List:
     reference traces to evolve against. One ``ingest`` phase per call
     in whichever home runs it, with its stages beside it
     (``ingest_read`` / ``ingest_encode`` / ``ingest_embed`` /
-    ``ingest_pool``, doc/observability.md "Request spans").
+    ``ingest_pool``, doc/observability.md "Request spans"). The embed
+    stage hands the whole history to the device at once
+    (``SearchBase.embed_batch``); its row's ``pieces`` = device calls.
 
     References are the most recent SUCCESSFUL runs (padded with failures
     only when no success exists yet): the counterfactual asks "what
@@ -340,31 +343,38 @@ def _ingest_history(search, storage, p: IngestParams, stages: _Stages,
             gmap.observe(bucket_sequence_from_encoded(e.realized))
         for _enc, enc_rt, _ok, _seed in encoded:
             gmap.observe(bucket_sequence_from_encoded(enc_rt))
-    for e in pooled:
-        # same treatment as an in-storage failure: archive embedding
-        # (novelty + surrogate positive) and failure-signature target —
-        # once per distinct signature (re-requests must not duplicate
-        # surrogate positives or evict diverse runs from the archive).
-        # Pooled entries go in FIRST: the failure archive is a ring, and
-        # adding them after the storage's own failures could wrap around
-        # and evict exactly the signatures most relevant to THIS
-        # experiment — the storage's own must always survive a full pool
-        if search.has_failure_signature(e.digest):
-            continue
-        search.add_executed_trace(e.realized, reproduced=True,
-                                  arrival=e.arrival)
-        search.add_failure_trace(e.realized)
     failures, successes = [], []
-    for enc, enc_rt, ok, _ in encoded:
-        # "failure" = the run reproduced the bug (validate failed); the
-        # label feeds the surrogate's training set
-        search.add_executed_trace(enc_rt, reproduced=not ok, arrival=enc)
-        if not ok:
-            search.add_failure_trace(enc_rt)
-            failures.append(enc)
-        else:
-            successes.append(enc)
-    t = stages.add("ingest_embed", t)
+    # every add below queues its trace; the batch's exit embeds them
+    # all (models/search.py embed_batch) — same rows, same slots, same
+    # order as one device round trip per run
+    with search.embed_batch() as batch:
+        for e in pooled:
+            # same treatment as an in-storage failure: archive
+            # embedding (novelty + surrogate positive) and
+            # failure-signature target — once per distinct signature
+            # (re-requests must not duplicate surrogate positives or
+            # evict diverse runs from the archive). Pooled entries go
+            # in FIRST: the failure archive is a ring, and adding them
+            # after the storage's own failures could wrap around and
+            # evict exactly the signatures most relevant to THIS
+            # experiment — the storage's own must always survive a
+            # full pool
+            if search.has_failure_signature(e.digest):
+                continue
+            search.add_executed_trace(e.realized, reproduced=True,
+                                      arrival=e.arrival)
+            search.add_failure_trace(e.realized)
+        for enc, enc_rt, ok, _ in encoded:
+            # "failure" = the run reproduced the bug (validate failed);
+            # the label feeds the surrogate's training set
+            search.add_executed_trace(enc_rt, reproduced=not ok,
+                                      arrival=enc)
+            if not ok:
+                search.add_failure_trace(enc_rt)
+                failures.append(enc)
+            else:
+                successes.append(enc)
+    t = stages.add("ingest_embed", t, pieces=batch.calls)
     if gmap is not None:
         scenario = p.knowledge_scenario or "local"
         obs.relation_coverage(scenario, gmap.covered(), gmap.width,

@@ -9,6 +9,7 @@ the reference has no equivalent for (SURVEY.md section 5.4).
 
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 from typing import NamedTuple, Optional
@@ -84,14 +85,47 @@ class BestSchedule(NamedTuple):
 
 # -- device-resident buffers (fused search loop) ---------------------------
 
+#: stored runs per call of the batched embed program
+#: (``ops.schedule.batched_trace_features``): ONE chunk shape whatever
+#: the depth, so a history that grows by a run per request never meets
+#: a new shape — a warm request compiles nothing
+EMBED_CHUNK = 64
+
 _row_update_jit = None
+_rows_scatter_jit = None
+
+
+def _device_rows_scatter(archive, failures, rows, archive_slots,
+                         failure_slots):
+    """Write a chunk of embedded rows into the device-resident rings in
+    place: one scatter per ring with the ring DONATED, from rows
+    already on the device (the embed program's output), both in ONE
+    compiled call — so the program exists from the first warm request
+    on, whether or not a failure has been written yet. ``*_slots``
+    i32[C] name each row's slot in that ring; a row that is not
+    written there (chunk padding, a success in the failure ring, a
+    slot a later row of the request takes) carries the ring's size,
+    which ``mode="drop"`` discards. Slots in range are distinct (the
+    caller keeps the last write of each), so the result does not
+    depend on the scatter's order."""
+    global _rows_scatter_jit
+    import jax
+
+    if _rows_scatter_jit is None:
+        def f(a, f_, r, sa, sf):
+            return (a.at[sa].set(r, mode="drop"),
+                    f_.at[sf].set(r, mode="drop"))
+
+        _rows_scatter_jit = jax.jit(f, donate_argnums=(0, 1))
+    return _rows_scatter_jit(archive, failures, rows, archive_slots,
+                             failure_slots)
 
 
 def _device_row_update(buf, row, slot: int):
     """Write one row of a device-resident 2-D buffer in place:
-    ``dynamic_update_slice`` with the buffer DONATED, so a ring-slot
-    overwrite costs one [K]- or [L]-row upload instead of re-staging the
-    whole buffer next run. ``slot`` is traced — every occupancy hits the
+    ``dynamic_update_slice`` with the buffer DONATED, so a resident
+    trace row costs one [L]-row upload instead of re-staging the whole
+    buffer next run. ``slot`` is traced — every occupancy hits the
     same compiled update. One jit serves all buffers (cache keys on
     shape/dtype)."""
     global _row_update_jit
@@ -273,6 +307,25 @@ def configure_compile_cache() -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
+class _EmbedBatch:
+    """What an open :meth:`SearchBase.embed_batch` has queued: the
+    traces to embed, in order, and per ring the ``(slot, row)`` writes
+    the adds worked out. ``calls`` = device calls its flush made."""
+
+    def __init__(self) -> None:
+        self.encs: list = []
+        self.writes: dict = {"archive": [], "failures": []}
+        # id(trace) -> its archive row, until a failure-ring write
+        # claims it: a new failure's row IS its archive row, and no row
+        # is written to two slots of one ring
+        self.unclaimed: dict = {}
+        self.calls = 0
+
+    def queue(self, encoded: te.EncodedTrace) -> int:
+        self.encs.append(encoded)
+        return len(self.encs) - 1
+
+
 class SearchBase:
     """Shared host-side state of every search backend: the precedence-pair
     sample, the novelty/failure feature archives (ring buffers), and the
@@ -303,6 +356,7 @@ class SearchBase:
         # Slot-aligned digests (evicted slot -> digest leaves the set).
         self._failure_digests = [""] * cfg.failure_size
         self._failure_digest_set: set = set()
+        self._batch: Optional[_EmbedBatch] = None  # the open embed_batch
         self.generations_run = 0
         # optional shared-surrogate hook (doc/knowledge.md): a callable
         # ``feats [N, K] -> probs [N] | None`` serving predictions from
@@ -426,19 +480,94 @@ class SearchBase:
         """Invalidate the best-so-far record (feature space changed)."""
         raise NotImplementedError
 
+    # -- embedding executed runs into the rings ----------------------------
+
+    def _embed_chunks(self, encs):
+        """Yield ``(indices, rows)`` per device call of the batched
+        embed program: the traces grouped by padded length (encodes
+        are quantized to ``te.L_QUANTUM``, so a request is one or two
+        groups), :data:`EMBED_CHUNK` at a time, a short chunk padded
+        with masked rows. ``rows`` f32[EMBED_CHUNK, K] stays on the
+        device; ``rows[j]`` embeds ``encs[indices[j]]``."""
+        from namazu_tpu.ops.schedule import batched_trace_features
+
+        embed = batched_trace_features(self.cfg.weights.tau, self.cfg.H)
+        by_length: dict = {}
+        for i, enc in enumerate(encs):
+            by_length.setdefault(enc.hint_ids.shape[0], []).append(i)
+        for L, group in by_length.items():
+            for k in range(0, len(group), EMBED_CHUNK):
+                indices = group[k:k + EMBED_CHUNK]
+                hint = np.zeros((EMBED_CHUNK, L), np.int32)
+                arrival = np.zeros((EMBED_CHUNK, L), np.float32)
+                mask = np.zeros((EMBED_CHUNK, L), bool)
+                for j, i in enumerate(indices):
+                    hint[j] = encs[i].hint_ids
+                    arrival[j] = encs[i].arrival
+                    mask[j] = encs[i].mask
+                obs.ingest_embed_call()
+                yield indices, embed(hint, arrival, mask, self.pairs)
+
+    def _embed(self, encs) -> np.ndarray:
+        """Feature rows f32[N, K] of executed traces, in order."""
+        out = np.empty((len(encs), self.cfg.K), np.float32)
+        for indices, rows in self._embed_chunks(encs):
+            out[indices] = np.asarray(rows)[:len(indices)]
+        return out
+
     def _feats_of(self, encoded: te.EncodedTrace) -> np.ndarray:
-        import jax.numpy as jnp
+        return self._embed([encoded])[0]
 
-        from namazu_tpu.ops.schedule import TraceArrays, trace_features
+    @contextlib.contextmanager
+    def embed_batch(self):
+        """Defer the embedding of every ``add_executed_trace`` /
+        ``add_failure_trace`` inside the context to its exit: the adds
+        do their bookkeeping at once (slot, label, digest, guidance
+        fragment, fill count — so dedupe and eviction see each other
+        exactly as in a per-run loop) and queue the trace; the exit
+        embeds all of them in ``ceil(N / EMBED_CHUNK)`` device calls
+        per trace length and writes the rows. Ingest wraps a whole
+        request's history in one; an add outside any is the batch of
+        one. Re-entrant: the outermost context flushes. Between an add
+        and the flush the rings' ROWS lag their counts — nothing reads
+        them inside a batch. Yields the batch (``calls``)."""
+        if self._batch is not None:
+            yield self._batch
+            return
+        batch = self._batch = _EmbedBatch()
+        try:
+            yield batch
+        finally:
+            # also on an error in the body: the adds that did happen
+            # advanced the fill counts, and their rows must follow
+            self._batch = None
+            self._flush(batch)
 
-        trace = TraceArrays(
-            jnp.asarray(encoded.hint_ids),
-            jnp.asarray(encoded.arrival),
-            jnp.asarray(encoded.mask),
-        )
-        f = trace_features(trace, jnp.asarray(self.pairs),
-                           self.cfg.weights.tau, self.cfg.H)
-        return np.asarray(f)
+    def _flush(self, batch: _EmbedBatch) -> None:
+        """Embed a batch's traces and write its rows: per device call
+        one fetch (the host rings, labels and checkpoints need the
+        rows) and, where the device mirrors exist, one call that
+        scatters into both from the rows already on the device. Of
+        several writes to one slot (a request with more rows than the
+        ring) the last is kept — what the per-run order leaves
+        behind."""
+        rings = {"archive": self.archive, "failures": self.failures}
+        # ring -> {row: slot}, through {slot: row of its LAST write}
+        slot_of = {
+            which: {row: slot for slot, row in dict(writes).items()}
+            for which, writes in batch.writes.items()}
+        for indices, rows in self._embed_chunks(batch.encs):
+            batch.calls += 1
+            host = np.asarray(rows)
+            slots = {}
+            for which, ring in rings.items():
+                size = ring.shape[0]
+                slots[which] = np.full((EMBED_CHUNK,), size, np.int32)
+                for j, i in enumerate(indices):
+                    slots[which][j] = slot_of[which].get(i, size)
+                written = slots[which] < size
+                ring[slots[which][written]] = host[written]
+            self._mirror_rows(rows, slots)
 
     def seed_population(self, delay_tables) -> None:
         """Inject imitation genomes before evolving; backends without an
@@ -453,33 +582,40 @@ class SearchBase:
         labeled with whether it reproduced the bug (surrogate target).
         ``arrival`` (the same run's arrival-anchored view) feeds the
         guidance plane's DAG-shape features when guidance is wired."""
-        slot = self._archive_n % self.cfg.archive_size
-        self.archive[slot] = self._feats_of(encoded)
-        self.archive_labels[slot] = 1.0 if reproduced else 0.0
-        if self.guidance_feats is not None:
-            self.guidance_feats[slot] = self._guidance_feats_of(
-                encoded, arrival)
-        self._archive_n += 1
-        self._mirror_note("archive", slot, self.archive[slot])
+        with self.embed_batch() as batch:
+            slot = self._archive_n % self.cfg.archive_size
+            row = batch.queue(encoded)
+            batch.writes["archive"].append((slot, row))
+            batch.unclaimed[id(encoded)] = row
+            self.archive_labels[slot] = 1.0 if reproduced else 0.0
+            if self.guidance_feats is not None:
+                self.guidance_feats[slot] = self._guidance_feats_of(
+                    encoded, arrival)
+            self._archive_n += 1
 
     def add_failure_trace(self, encoded: te.EncodedTrace) -> None:
         """Record a bug-reproducing run — the bug-affinity target.
         Idempotent per distinct signature (content digest): re-ingesting
-        the same stored failure never spends a ring slot."""
+        the same stored failure never spends a ring slot. Inside a
+        batch that already queued this trace for the archive, the
+        failure ring takes that row instead of a second embedding."""
         from namazu_tpu.models.failure_pool import trace_digest
 
         digest = trace_digest(encoded)
         if digest in self._failure_digest_set:
             return
-        slot = self._failure_n % self.cfg.failure_size
-        evicted = self._failure_digests[slot]
-        if evicted:
-            self._failure_digest_set.discard(evicted)
-        self.failures[slot] = self._feats_of(encoded)
-        self._failure_digests[slot] = digest
-        self._failure_digest_set.add(digest)
-        self._failure_n += 1
-        self._mirror_note("failures", slot, self.failures[slot])
+        with self.embed_batch() as batch:
+            slot = self._failure_n % self.cfg.failure_size
+            evicted = self._failure_digests[slot]
+            if evicted:
+                self._failure_digest_set.discard(evicted)
+            row = batch.unclaimed.pop(id(encoded), None)
+            if row is None:
+                row = batch.queue(encoded)
+            batch.writes["failures"].append((slot, row))
+            self._failure_digests[slot] = digest
+            self._failure_digest_set.add(digest)
+            self._failure_n += 1
 
     def distinct_failure_signatures(self) -> int:
         """How many distinct failure signatures the archive currently
@@ -494,11 +630,12 @@ class SearchBase:
         into the novelty archive / surrogate training set."""
         return digest in self._failure_digest_set
 
-    def _mirror_note(self, which: str, slot: int, row: np.ndarray) -> None:
-        """Hook: one archive ring slot was overwritten — backends with a
-        device-resident mirror (ScheduleSearch's fused loop) apply the
-        same write on device via ``dynamic_update_slice`` instead of
-        re-uploading the whole buffer next run. Base: no mirror."""
+    def _mirror_rows(self, rows, slots: dict) -> None:
+        """Hook: each ring ``which`` took ``rows[j]`` (on the device)
+        at ``slots[which][j]`` wherever that is in range — backends
+        with device-resident mirrors (ScheduleSearch's fused loop)
+        apply the same writes there instead of re-uploading the whole
+        buffers next run. Base: no mirror."""
 
     def _mirror_invalidate(self) -> None:
         """Hook: a bulk archive/pairs mutation happened (checkpoint
@@ -722,7 +859,7 @@ class ScheduleSearch(SearchBase):
         self._surrogate = None  # built lazily on first labeled training
         # fused-loop machinery (doc/performance.md "Fused search loop"):
         # per-chunk-length fused step cache, device mirrors of the host
-        # archive rings (kept in sync by _mirror_note's row updates),
+        # archive rings (kept in sync by _mirror_rows' scatters),
         # and the device-resident reference-trace store
         self._fused_steps: dict = {}
         self._dev_mirrors = {"archive": None, "failures": None}
@@ -744,16 +881,17 @@ class ScheduleSearch(SearchBase):
 
     # -- device-resident mirrors (fused loop) -----------------------------
 
-    def _mirror_note(self, which: str, slot: int, row: np.ndarray) -> None:
-        """A host archive ring slot was overwritten: apply the same row
-        write to the device mirror (donated dynamic_update_slice) so the
-        next fused run stages one [K] row instead of the whole ring."""
-        mirrors = getattr(self, "_dev_mirrors", None)
-        if mirrors is None:
-            return
-        buf = mirrors.get(which)
-        if buf is not None:
-            mirrors[which] = _device_row_update(buf, row, slot)
+    def _mirror_rows(self, rows, slots: dict) -> None:
+        """A chunk of rows went into the host rings: apply the same
+        writes to the device mirrors (one call, both donated), so the
+        next fused run stages nothing. No mirrors (none built yet, or
+        invalidated — they are built and dropped together) = nothing
+        to do: the next fused run stages the host rings."""
+        m = self._dev_mirrors
+        if m["archive"] is not None and m["failures"] is not None:
+            m["archive"], m["failures"] = _device_rows_scatter(
+                m["archive"], m["failures"], rows, slots["archive"],
+                slots["failures"])
 
     def _mirror_invalidate(self) -> None:
         """Bulk host-side mutation (checkpoint load, pair refit,
@@ -770,7 +908,7 @@ class ScheduleSearch(SearchBase):
         """The fused-run analogue of ``_device_inputs``: the ordered
         trace view comes from the resident store (only missing rows
         upload), pairs/archive/failure buffers from the device mirrors
-        (row-synced by ``_mirror_note``; staged whole only after a bulk
+        (synced by ``_mirror_rows``; staged whole only after a bulk
         invalidation). Array VALUES are identical to ``_device_inputs``
         for the same references — the property the fused-vs-unfused
         bit-exactness test leans on."""

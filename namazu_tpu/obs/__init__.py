@@ -121,6 +121,7 @@ from namazu_tpu.obs.spans import (  # noqa: F401
     scorer_throughput_value,
     current_request,
     ensure_compile_listener,
+    ingest_embed_call,
     ingest_runs,
     search_device_trace,
     search_phase,

@@ -66,6 +66,7 @@ SEARCH_HOST_GAP = "nmz_search_host_gap_share"
 SEARCH_DEVICE_TRACES = "nmz_search_device_traces_total"
 SPAN_ROWS_DROPPED = "nmz_span_rows_dropped_total"
 INGEST_RUNS = "nmz_ingest_runs_total"
+INGEST_EMBED_CALLS = "nmz_ingest_embed_calls_total"
 COMPILES = "nmz_compiles_total"
 COMPILE_SECONDS = "nmz_compile_seconds"
 #: the jax.monitoring event of one jaxpr->MLIR lowering
@@ -1345,6 +1346,18 @@ def ingest_runs(n: int) -> None:
         return
     metrics.get().counter(
         INGEST_RUNS, "stored runs walked by history ingests").inc(n)
+
+
+def ingest_embed_call() -> None:
+    """One device call of the batched embed program
+    (``SearchBase._embed_chunks``): up to ``EMBED_CHUNK`` executed
+    traces embedded at once. ``nmz_ingest_runs_total`` over this is
+    how full the chunks run."""
+    if not metrics.enabled():
+        return
+    metrics.get().counter(
+        INGEST_EMBED_CALLS,
+        "device calls of the batched trace-embed program").inc()
 
 
 def search_device_trace(path: str) -> None:

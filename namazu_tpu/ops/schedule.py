@@ -273,6 +273,28 @@ def trace_features(
     return schedule_features(zero, trace, pairs, tau)
 
 
+@functools.lru_cache(maxsize=None)
+def batched_trace_features(tau: float, H: int):
+    """The compiled, batched :func:`trace_features`:
+    ``f(hint_ids [C, L], arrival [C, L], mask [C, L], pairs [K, 2]) ->
+    f32[C, K]``, runs mapped and the pair sample shared. It is the
+    same function under ``jax.vmap`` and ``jax.jit``, so the same
+    float32 arithmetic, and the dense / blockwise branch
+    (:data:`LONG_TRACE_THRESHOLD`) is still chosen per static L. A row
+    whose mask is all False embeds to the neutral 0.5 — what a padded
+    row of a short chunk reads. One jitted function per ``(tau, H)``
+    for the life of the process, shared by every search in it (a
+    sidecar's tenants compile the embed once, not once each)."""
+
+    def rows(hint_ids, arrival, mask, pairs):
+        return jax.vmap(
+            lambda h, a, m: trace_features(TraceArrays(h, a, m), pairs,
+                                           tau, H)
+        )(hint_ids, arrival, mask)
+
+    return jax.jit(rows)
+
+
 def _matmul_dtype():
     """bf16 on TPU (MXU-native), f32 elsewhere (the CPU backend has no
     bf16xbf16->f32 dot)."""

@@ -3,11 +3,13 @@ retry/backoff, the endpoint hub's unroutable-action accounting, the
 liveness watchdog, ScheduledQueue.expedite, the REST transceiver's
 bounded POST retry, and run_cmd's clean-in-finally contract."""
 
+import contextlib
 import json
 import os
 import subprocess
 import threading
 import time
+import types
 import urllib.error
 
 import pytest
@@ -231,6 +233,11 @@ def test_quarantined_runs_invisible_to_history_ingest(tmp_path):
 
         def add_failure_trace(self, enc):
             pass
+
+        def embed_batch(self):
+            # ingest wraps its adds in the search's batch; a stub has
+            # nothing to defer
+            return contextlib.nullcontext(types.SimpleNamespace(calls=0))
 
     path = _storage_with_crash(tmp_path)
     st = load_storage(path)

@@ -124,3 +124,30 @@ def test_fused_reorder_step_compiles_for_v5e(v5e, tpu_paths):
     weights = ScoreWeights(order_mode=True, order_gap=0.002,
                            order_window=0.05, tau=0.001, delay_cost=0.0)
     assert MOSAIC in _compile_fused(v5e[:1], weights=weights)
+
+
+@pytest.mark.parametrize("L", [128, 1152], ids=["dense", "blockwise"])
+def test_batched_embed_and_ring_scatter_compile_for_v5e(v5e, L):
+    """Ingest's embed stage at the shipped width (H 256, K 256, chunks
+    of 64 runs; every run of both benchmark configurations pads to L
+    128) and the donated ring scatter behind it (archive 512, failures
+    64)."""
+    from namazu_tpu.models import search
+    from namazu_tpu.ops.schedule import batched_trace_features
+
+    mesh = Mesh(np.array(v5e[:1]), ("i",))
+    C, H, K = search.EMBED_CHUNK, 256, 256
+    embed = batched_trace_features(0.005, H).lower(
+        _on(mesh, (C, L), jnp.int32), _on(mesh, (C, L)),
+        _on(mesh, (C, L), jnp.bool_), _on(mesh, (K, 2), jnp.int32)
+    ).compile()
+    assert ("while" in embed.as_text()) == (L > 1024)  # the scan
+    # the helper builds its jit on first use: one traced call on the
+    # CPU, then the same function lowered for the chip
+    A, F = 512, 64
+    search._device_rows_scatter(
+        jnp.zeros((A, K)), jnp.zeros((F, K)), jnp.zeros((C, K)),
+        jnp.full((C,), A, jnp.int32), jnp.full((C,), F, jnp.int32))
+    search._rows_scatter_jit.lower(
+        _on(mesh, (A, K)), _on(mesh, (F, K)), _on(mesh, (C, K)),
+        _on(mesh, (C,), jnp.int32), _on(mesh, (C,), jnp.int32)).compile()

@@ -1,6 +1,8 @@
 """tpu_search policy integration: history -> search -> installed schedule."""
 
+import contextlib
 import time
+import types
 
 import numpy as np
 import pytest
@@ -149,6 +151,11 @@ class _RecordingSearch:
 
     def add_failure_trace(self, enc):
         self.failures.append(enc)
+
+    def embed_batch(self):
+        # ingest wraps its adds in the search's batch; a stub has
+        # nothing to defer
+        return contextlib.nullcontext(types.SimpleNamespace(calls=0))
 
 
 def _policy_with_storage(storage):
