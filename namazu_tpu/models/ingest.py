@@ -132,20 +132,24 @@ class _Stages:
     stored run would cost more than it shows)."""
 
     def __init__(self) -> None:
-        self.stages: dict = {}  # stage -> [first start, seconds, pieces]
+        # stage -> [first start, seconds, {"pieces": n, <counts>}]
+        self.stages: dict = {}
 
-    def add(self, stage: str, start: float, pieces: int = 1) -> float:
-        """Charge ``start``..now to ``stage``; returns now."""
+    def add(self, stage: str, start: float, pieces: int = 1,
+            **counts: int) -> float:
+        """Charge ``start``..now to ``stage``, and add ``counts`` (what
+        the stage walked: ``events=``, ``groups=``) to its row's
+        arguments; returns now."""
         now = time.monotonic()
-        row = self.stages.setdefault(stage, [start, 0.0, 0])
+        row = self.stages.setdefault(stage, [start, 0.0, {}])
         row[1] += now - start
-        row[2] += pieces
+        for name, n in dict(counts, pieces=pieces).items():
+            row[2][name] = row[2].get(name, 0) + n
         return now
 
     def report(self) -> None:
-        for stage, (first, seconds, pieces) in self.stages.items():
-            obs.search_phase_observed(stage, seconds, first,
-                                      pieces=pieces)
+        for stage, (first, seconds, counts) in self.stages.items():
+            obs.search_phase_observed(stage, seconds, first, **counts)
 
 
 def ingest_history(search, storage, p: IngestParams) -> List:
@@ -155,7 +159,9 @@ def ingest_history(search, storage, p: IngestParams) -> List:
     (``ingest_read`` / ``ingest_encode`` / ``ingest_embed`` /
     ``ingest_pool``, doc/observability.md "Request spans"). The embed
     stage hands the whole history to the device at once
-    (``SearchBase.embed_batch``); its row's ``pieces`` = device calls.
+    (``SearchBase.embed_batch``); its row's ``pieces`` = device calls and
+    ``groups`` = padded trace lengths among the runs (each a compiled
+    embed of its own); the encode row's ``events`` = events encoded.
 
     References are the most recent SUCCESSFUL runs (padded with failures
     only when no success exists yet): the counterfactual asks "what
@@ -197,7 +203,7 @@ def _ingest_history(search, storage, p: IngestParams, stages: _Stages,
                                       p.guidance_window or None,
                                       fresh=True)
     encoded = []
-    skipped_unstamped = 0
+    skipped_unstamped = events = 0
     for i in range(n):
         t = time.monotonic()
         try:
@@ -239,7 +245,9 @@ def _ingest_history(search, storage, p: IngestParams, stages: _Stages,
                 else "order-mode memory bound")
         seed = None if ok else failure_seed(trace, p.H, p.max_interval)
         encoded.append((enc, enc_rt, ok, seed))
-        stages.add("ingest_encode", t)
+        events += len(trace)
+        stages.add("ingest_encode", t, events=len(trace))
+    obs.ingest_events(events)
     if skipped_unstamped:
         log.warning(
             "%d stored run(s) recorded in another hint space were "
@@ -374,7 +382,8 @@ def _ingest_history(search, storage, p: IngestParams, stages: _Stages,
                 failures.append(enc)
             else:
                 successes.append(enc)
-    t = stages.add("ingest_embed", t, pieces=batch.calls)
+    t = stages.add("ingest_embed", t, pieces=batch.calls,
+                   groups=batch.groups)
     if gmap is not None:
         scenario = p.knowledge_scenario or "local"
         obs.relation_coverage(scenario, gmap.covered(), gmap.width,

@@ -19,7 +19,7 @@ import numpy as np
 from namazu_tpu import obs
 from namazu_tpu.models.ga import GAConfig
 from namazu_tpu.ops import trace_encoding as te
-from namazu_tpu.ops.schedule import ScoreWeights
+from namazu_tpu.ops.schedule import ScoreWeights, scorer_branch
 from namazu_tpu.utils.log import get_logger
 
 log = get_logger("models.search")
@@ -310,7 +310,8 @@ def configure_compile_cache() -> None:
 class _EmbedBatch:
     """What an open :meth:`SearchBase.embed_batch` has queued: the
     traces to embed, in order, and per ring the ``(slot, row)`` writes
-    the adds worked out. ``calls`` = device calls its flush made."""
+    the adds worked out. ``calls`` = device calls its flush made,
+    ``groups`` = padded trace lengths among the queued traces."""
 
     def __init__(self) -> None:
         self.encs: list = []
@@ -319,7 +320,7 @@ class _EmbedBatch:
         # claims it: a new failure's row IS its archive row, and no row
         # is written to two slots of one ring
         self.unclaimed: dict = {}
-        self.calls = 0
+        self.calls = self.groups = 0
 
     def queue(self, encoded: te.EncodedTrace) -> int:
         self.encs.append(encoded)
@@ -556,6 +557,7 @@ class SearchBase:
         slot_of = {
             which: {row: slot for slot, row in dict(writes).items()}
             for which, writes in batch.writes.items()}
+        batch.groups = len({e.hint_ids.shape[0] for e in batch.encs})
         for indices, rows in self._embed_chunks(batch.encs):
             batch.calls += 1
             host = np.asarray(rows)
@@ -1061,6 +1063,7 @@ class ScheduleSearch(SearchBase):
                 state = self._step(state, self._key, trace, pairs, archive,
                                    failures, coin, nov_scale, bias)
             state.best_fitness.block_until_ready()
+        self._count_evolve(trace)
         elapsed = time.perf_counter() - t0
         self._state = state
         self.generations_run += generations
@@ -1151,6 +1154,7 @@ class ScheduleSearch(SearchBase):
             except Exception:
                 self._recover_state()
                 raise
+        self._count_evolve(trace)
         elapsed = time.perf_counter() - t0
         self.generations_run += generations
         # recovery snapshot (tiny: two [H] rows + a scalar): the newest
@@ -1177,6 +1181,14 @@ class ScheduleSearch(SearchBase):
             return picked
         with obs.search_phase("extract"):
             return self.best()
+
+    def _count_evolve(self, trace) -> None:
+        """One completed evolve (counted where its ``evolve`` span
+        ends, so the two agree over any window), under the scorer
+        branch the island step takes for these reference traces'
+        padded length."""
+        obs.evolve_request(scorer_branch(trace.hint_ids.shape[-1],
+                                         self.cfg.weights.order_mode))
 
     def _drain_host_lane(self, fit_hist, fit_curve: list) -> None:
         """The overlapped host-I/O work for one completed chunk: fetch

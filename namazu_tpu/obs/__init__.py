@@ -121,7 +121,9 @@ from namazu_tpu.obs.spans import (  # noqa: F401
     scorer_throughput_value,
     current_request,
     ensure_compile_listener,
+    evolve_request,
     ingest_embed_call,
+    ingest_events,
     ingest_runs,
     search_device_trace,
     search_phase,

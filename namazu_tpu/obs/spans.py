@@ -67,6 +67,8 @@ SEARCH_DEVICE_TRACES = "nmz_search_device_traces_total"
 SPAN_ROWS_DROPPED = "nmz_span_rows_dropped_total"
 INGEST_RUNS = "nmz_ingest_runs_total"
 INGEST_EMBED_CALLS = "nmz_ingest_embed_calls_total"
+INGEST_EVENTS = "nmz_ingest_events_total"
+EVOLVE_REQUESTS = "nmz_evolve_requests_total"
 COMPILES = "nmz_compiles_total"
 COMPILE_SECONDS = "nmz_compile_seconds"
 #: the jax.monitoring event of one jaxpr->MLIR lowering
@@ -1358,6 +1360,29 @@ def ingest_embed_call() -> None:
     metrics.get().counter(
         INGEST_EMBED_CALLS,
         "device calls of the batched trace-embed program").inc()
+
+
+def ingest_events(n: int) -> None:
+    """Events of the stored runs one ingest encoded (the divisor of
+    the per-event ingest stage times: a stored run is 18 events in one
+    hunt and 1,500 in another)."""
+    if not metrics.enabled():
+        return
+    metrics.get().counter(
+        INGEST_EVENTS, "events of the stored runs history ingests "
+                       "encoded").inc(n)
+
+
+def evolve_request(scorer: str) -> None:
+    """One evolve of a search backend, by the first-occurrence branch
+    its compiled step took for the request's padded trace length
+    (``ops/schedule.py::scorer_branch``: ``dense`` | ``blockwise``)."""
+    if not metrics.enabled():
+        return
+    metrics.get().counter(
+        EVOLVE_REQUESTS, "evolve requests by the scorer branch of the "
+                         "compiled step", ("scorer",),
+    ).labels(scorer=scorer).inc()
 
 
 def search_device_trace(path: str) -> None:

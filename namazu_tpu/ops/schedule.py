@@ -226,8 +226,7 @@ def _genome_features(
     on static shape, so each jit specialization compiles exactly one
     branch."""
     H = delays.shape[0]
-    L = trace.hint_ids.shape[-1]
-    if not order_mode and L > LONG_TRACE_THRESHOLD:
+    if scorer_branch(trace.hint_ids.shape[-1], order_mode) == "blockwise":
         first, ndrop = first_occurrence_blockwise(
             delays, trace.hint_ids, trace.arrival, trace.mask,
             faults=faults, coin=coin, faultable=trace.faultable,
@@ -533,6 +532,16 @@ LONG_TRACE_THRESHOLD = 1024
 LONG_TRACE_CHUNK = 512
 
 
+def scorer_branch(L: int, order_mode: bool = False) -> str:
+    """The first-occurrence branch a step compiled for padded trace
+    length ``L`` takes: ``"blockwise"`` | ``"dense"``. One home for the
+    rule, so that what the search counts per evolve
+    (``nmz_evolve_requests_total{scorer}``) is what was compiled."""
+    return ("blockwise" if not order_mode and L > LONG_TRACE_THRESHOLD
+            else "dense")
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
 def first_occurrence_blockwise(
     delays: jax.Array,  # [H]
     hint_ids: jax.Array,  # [L], any length (padded internally)
@@ -552,6 +561,14 @@ def first_occurrence_blockwise(
     (SURVEY.md section 5.7: schedule genomes over long event traces are
     this framework's long sequences). Fault drops are applied per chunk so
     a vmapped population never materialises a [P, L] drop mask.
+
+    Jitted in its own right: the reply's re-rank calls the scorer
+    eagerly (``models/search.py::_surrogate_pick``), and a bare
+    ``lax.scan`` dispatched eagerly is lowered anew at every call — its
+    body is a fresh closure, so no cache holds it — which a warm
+    sidecar pays per request. Under a jit of its own the scan is traced
+    once per shape, eager ``vmap``s included; inside the fused island
+    step the jit is inlined.
     """
     H = delays.shape[0]
     L = hint_ids.shape[0]

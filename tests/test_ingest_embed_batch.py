@@ -460,3 +460,53 @@ def test_surrogate_examples_come_from_one_batched_call(fresh_obs):
         assert e["digest"] == trace_digest(enc_rt)
         assert np.abs(np.asarray(e["feats"], np.float32) - one_run_row(
             enc_rt, s.pairs, s.cfg.weights.tau)).max() <= ROW_TOL
+
+
+# -- (h) events, L groups and the scorer branch (zk2212-zab5) ----------------
+
+
+@pytest.mark.parametrize("short, long, groups", [
+    (10, 0, 1), (7, 3, 2)])
+def test_ingest_counts_events_and_length_groups(short, long, groups,
+                                                fresh_obs):
+    """``nmz_ingest_events_total`` and the ``ingest_encode`` row's
+    ``events=`` count the events of the stored runs a request encoded;
+    the ``ingest_embed`` row's ``groups=`` the padded lengths among
+    them, each a compiled embed of its own."""
+    s = MCTSSearch(cfg(archive_size=256), n_devices=1)
+    st = history(short + long, long_from=short)
+    events = 17 * short + 140 * long
+    for request in (1, 2):
+        ingest_history(s, st, IngestParams(H=H))
+        assert obs.metrics.registry().value(spans.INGEST_EVENTS) \
+            == request * events
+    rows = fresh_obs.since(0)["rows"]
+    encode = [r[7] for r in rows if r[1] == "ingest_encode"]
+    assert encode == [{"pieces": short + long, "events": events}] * 2
+    embed = [r[7] for r in rows if r[1] == "ingest_embed"]
+    assert [e["groups"] for e in embed] == [groups, groups]
+    assert [e["pieces"] for e in embed] == [groups, groups]
+
+
+@pytest.mark.parametrize("n_events, scorer", [
+    (17, "dense"), (sch.LONG_TRACE_THRESHOLD, "dense"),
+    (sch.LONG_TRACE_THRESHOLD + 1, "blockwise")])
+@pytest.mark.parametrize("fused", [True, False])
+def test_an_evolve_is_counted_under_the_branch_its_step_compiled(
+        n_events, scorer, fused, fresh_obs):
+    """``nmz_evolve_requests_total{scorer}``: one per completed evolve,
+    by ``scorer_branch`` of the references' padded length — the rule
+    ``_genome_features`` itself dispatches on."""
+    s = ScheduleSearch(cfg(fused=fused), n_devices=1)
+    refs = [enc_of(n_events, 1), enc_of(17, 2)]
+    L = max(e.hint_ids.shape[0] for e in refs)
+    assert sch.scorer_branch(L) == scorer
+    assert sch.scorer_branch(L, order_mode=True) == "dense"
+    for _ in range(2):
+        s.run(refs, generations=2)
+    reg = obs.metrics.registry()
+    other = ({"dense", "blockwise"} - {scorer}).pop()
+    assert reg.value(spans.EVOLVE_REQUESTS, scorer=scorer) == 2
+    assert not reg.value(spans.EVOLVE_REQUESTS, scorer=other)
+    evolves = [r for r in fresh_obs.since(0)["rows"] if r[1] == "evolve"]
+    assert len(evolves) == 2

@@ -237,7 +237,7 @@ def test_quarantined_runs_invisible_to_history_ingest(tmp_path):
         def embed_batch(self):
             # ingest wraps its adds in the search's batch; a stub has
             # nothing to defer
-            return contextlib.nullcontext(types.SimpleNamespace(calls=0))
+            return contextlib.nullcontext(types.SimpleNamespace(calls=0, groups=0))
 
     path = _storage_with_crash(tmp_path)
     st = load_storage(path)

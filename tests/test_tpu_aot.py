@@ -151,3 +151,12 @@ def test_batched_embed_and_ring_scatter_compile_for_v5e(v5e, L):
     search._rows_scatter_jit.lower(
         _on(mesh, (A, K)), _on(mesh, (F, K)), _on(mesh, (C, K)),
         _on(mesh, (C,), jnp.int32), _on(mesh, (C,), jnp.int32)).compile()
+
+
+def test_fused_island_step_compiles_at_the_zab5_shape(v5e, tpu_paths):
+    """``zk2212-zab5``'s step: 4 reference traces of L 1536, so the
+    blockwise scan (3 chunks of 512) under the trace and population
+    vmaps, with the Pallas pair kernel over 4 x 4096 feature rows."""
+    text = _compile_fused(v5e[:1], L=1536, T=4)
+    assert MOSAIC in text
+    assert "while" in text  # the scan survived as a loop

@@ -155,7 +155,7 @@ class _RecordingSearch:
     def embed_batch(self):
         # ingest wraps its adds in the search's batch; a stub has
         # nothing to defer
-        return contextlib.nullcontext(types.SimpleNamespace(calls=0))
+        return contextlib.nullcontext(types.SimpleNamespace(calls=0, groups=0))
 
 
 def _policy_with_storage(storage):

@@ -1,0 +1,137 @@
+"""Acceptance test over the zk-zab example: the ZOOKEEPER-2212 hunt as
+the upstream inspector sees it — five servers on ZooKeeper's election,
+quorum and client wire formats, one client session, 41 proxied links in
+one ethernet-inspector process with a stream parser per protocol, REST
+endpoint, policy deferrals, validate-as-oracle.
+
+One worker runs this file (the testee binds ZooKeeper's own ports on
+127.0.0.1-5, so two ensembles cannot share a host)."""
+
+import collections
+import json
+import os
+
+import pytest
+
+from namazu_tpu.cli import cli_main
+from namazu_tpu.storage import load_storage
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+EXAMPLE = os.path.join(REPO, "examples", "zk-zab")
+
+
+def init_storage(tmp_path, config_name, name):
+    storage = str(tmp_path / name)
+    assert cli_main([
+        "init", os.path.join(EXAMPLE, config_name),
+        os.path.join(EXAMPLE, "materials"), storage,
+    ]) == 0
+    return storage
+
+
+def hints_of(storage, i):
+    with open(os.path.join(storage, f"{i:08x}", "trace.json")) as f:
+        trace = json.load(f)
+    actions = trace["actions"] if isinstance(trace, dict) else trace
+    return [a["event_hint"] for a in actions]
+
+
+def lines_of(storage, i, name):
+    with open(os.path.join(storage, f"{i:08x}", name)) as f:
+        return f.read().split()
+
+
+@pytest.fixture(scope="module")
+def recorded(tmp_path_factory):
+    """One run under the recording config (random 0-100 ms)."""
+    storage = init_storage(tmp_path_factory.mktemp("zab"), "config.toml",
+                           "fuzz")
+    assert cli_main(["run", storage]) == 0
+    return storage
+
+
+def test_a_recorded_run_holds_fle_zab_and_client_events_and_no_pings(
+        recorded):
+    hints = hints_of(recorded, 0)
+    # the count the benchmark's configuration states under `assumed`
+    assert 1024 < len(hints) < 1664, len(hints)
+    kinds = collections.Counter(
+        ":".join(h.split(":")[1:3]) for h in hints)
+    assert kinds["fle:init"] == 20  # every directed server pair
+    assert kinds["fle:notif"] >= 20
+    for name in ("followerinfo", "leaderinfo", "ackepoch", "diff",
+                 "newleader"):
+        assert kinds[f"zab:{name}"] == 4, (name, kinds)  # 4 followers
+    assert kinds["zab:uptodate"] == 4
+    # three followers see every write; the rejoining one only those
+    # after its synchronisation (all 100 unless the rejoin slid late)
+    for name in ("proposal", "ack", "commit"):
+        assert 300 < kinds[f"zab:{name}"] <= 400 + 8, (name, kinds)
+    assert kinds["cm:create"] == kinds["sm:reply"] == 100
+    assert kinds["cm:connect"] == kinds["sm:connect"] == 1
+    # the parser's hint forms, flow-qualified by the event
+    assert "zk4->zk1:zab:proposal:zxid=0x200000001:dlen=9" in hints
+    assert "client->zk4:cm:create:/nmz/n000" in hints
+    assert "zk4->client:sm:reply:zxid=0x200000001:err=0" in hints
+    # pings were sent — every server answered some, the followers the
+    # leader's and the leader the session's — and none was deferred
+    for n in range(1, 6):
+        with open(os.path.join(recorded, f"{0:08x}", f"server{n}.log")) as f:
+            answered = [line for line in f if "pings answered" in line]
+        assert answered and int(answered[0].split()[-1]) >= 1, (n, answered)
+    assert not [h for h in hints if "ping" in h]
+    assert lines_of(recorded, 0, "acked") == [
+        f"/nmz/n{i:03d}" for i in range(100)]
+
+
+def test_baseline_is_healthy(tmp_path):
+    storage = init_storage(tmp_path, "config_baseline.toml", "base")
+    assert cli_main(["run", storage]) == 0
+    st = load_storage(storage)
+    assert st.nr_stored_histories() == 1
+    assert st.is_successful(0)
+    for n in range(1, 6):
+        assert lines_of(storage, 0, f"leader{n}") == ["4"]
+        tree = lines_of(storage, 0, f"data{n}")
+        assert tree[:2] == ["/nmz/pre1", "/nmz/pre2"]  # 5 got its DIFF
+        assert len(tree) == 102
+
+
+def test_a_rejoin_that_slides_into_the_writes_loses_a_commit(
+        tmp_path, monkeypatch):
+    """The oracle the other way, without waiting for the policy to find
+    it: the rejoining server restarts 1.5 s late (an explicit knob in
+    the environment wins over the calibrated one), half a second after
+    the client's session starts, so the writes are under way when it
+    synchronises, a proposal is in flight, and the planted bug drops it
+    on server 5 only."""
+    monkeypatch.setenv("NMZ_CALIB_REJOIN_DELAY_MS", "1500")
+    storage = init_storage(tmp_path, "config_baseline.toml", "late")
+    assert cli_main(["run", storage]) == 0
+    st = load_storage(storage)
+    assert not st.is_successful(0)
+    acked = lines_of(storage, 0, "acked")
+    assert len(acked) == 100
+    for n in range(1, 5):
+        assert set(acked) <= set(lines_of(storage, 0, f"data{n}"))
+    missing = set(acked) - set(lines_of(storage, 0, "data5"))
+    assert 1 <= len(missing) <= 8  # at most the client's window
+    with open(os.path.join(storage, f"{0:08x}", "server4.log")) as f:
+        assert "proposal(s) in flight" in f.read()
+    with open(os.path.join(storage, f"{0:08x}", "server5.log")) as f:
+        assert "for a proposal never seen" in f.read()
+
+
+def test_the_example_declares_its_calibration():
+    from namazu_tpu.calibrate.harness import parse_calibration
+    from namazu_tpu.utils.config import Config
+
+    spec = parse_calibration(
+        Config.from_file(os.path.join(EXAMPLE, "config.toml")))
+    assert [k.name for k in spec.knobs] == ["rejoin_delay_ms"]
+    assert spec.band == (0.02, 0.10)
+    with open(os.path.join(EXAMPLE, "calibration.json")) as f:
+        art = json.load(f)
+    assert art["status"] == "calibrated" and art["verdict"] == "in_band"
+    assert 0.02 <= art["rate"] <= 0.10
+    assert set(art["knobs"]) == {"rejoin_delay_ms"}
