@@ -9,8 +9,11 @@ a schedule trained in one home would not replay in the other.
 
 from __future__ import annotations
 
+import os
+import threading
 import time
-from typing import List, NamedTuple, Optional
+from collections import OrderedDict
+from typing import Hashable, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -25,6 +28,13 @@ log = get_logger("models.ingest")
 #: per ingest — bounds the extra featurize cost (and the wire payload)
 #: on long histories; older runs were already pushed by earlier ingests
 MAX_EXAMPLE_PUSH = 64
+
+#: byte bound of the encoded-run records this process keeps (a module
+#: constant, as models/search.py's EMBED_CHUNK is): a record's arrays
+#: are 27.6 KB at L 1536 and 2.3 KB at L 128 (plus 1 KB for a failure's
+#: seed at H 256), so this holds a 5,000-run hunt of 1,500-event traces
+#: (140 MB) or some 75,000 18-event runs
+RUN_CACHE_BYTES = 256 << 20
 
 
 def _push_surrogate_examples(client, search, encoded) -> None:
@@ -126,6 +136,118 @@ def failure_seed(trace, H: int, max_interval: float):
     return seed if got else None
 
 
+class _RunRecord(NamedTuple):
+    """What ``_ingest_history`` keeps of one stored run: both encoded
+    views, the verdict, the failure's demonstration seed, the events it
+    recorded and the hint space it was stamped with. A run of another
+    hint space is never encoded: its views are None."""
+
+    enc: Optional[te.EncodedTrace]
+    enc_rt: Optional[te.EncodedTrace]
+    ok: bool
+    seed: Optional[np.ndarray]
+    n_events: int
+    stamp: str
+
+    def arrays(self) -> List[np.ndarray]:
+        if self.enc is None:
+            return []
+        # the two views share every array but the times
+        held = [self.enc.hint_ids, self.enc.entity_ids, self.enc.mask,
+                self.enc.faultable, self.enc.arrival, self.enc_rt.arrival]
+        return held if self.seed is None else held + [self.seed]
+
+
+class RunRecordCache:
+    """Encoded-run records, least recently used first, at most
+    ``max_bytes`` of arrays. One per process (``_RUN_RECORDS``), not per
+    storage object: the sidecar loads its storage anew for every request
+    and serves several keys from several workers at once, so the lock
+    covers the dict and never a read or an encode (two workers that miss
+    the same run both encode it; the later ``put`` wins, with an equal
+    record).
+
+    A record is looked up by what it was computed under and handed out
+    only while the run's signature still equals the one it was stored
+    with; the signature is taken BEFORE the files are read, so a record
+    is never older than its signature, and a rewritten run replaces its
+    record at the next request. The arrays of a stored record are
+    read-only: every request shares them.
+
+    A history larger than the bound, walked in stored order as ingest
+    walks it, evicts each record before the walk comes round to it
+    again: every run misses, and a request costs what it cost without
+    the cache plus the bookkeeping."""
+
+    #: what a record weighs beyond its arrays (objects, key, dict slot)
+    RECORD_OVERHEAD = 1024
+
+    def __init__(self, max_bytes: int) -> None:
+        self.max_bytes = max_bytes
+        self._lock = threading.Lock()
+        self._records: "OrderedDict[tuple, tuple]" = OrderedDict()
+        self._bytes = 0
+
+    def __len__(self) -> int:
+        return len(self._records)
+
+    def get(self, key: tuple, signature: Hashable) -> Optional[_RunRecord]:
+        with self._lock:
+            held = self._records.get(key)
+            if held is None or held[0] != signature:
+                return None
+            self._records.move_to_end(key)
+            return held[1]
+
+    def put(self, key: tuple, signature: Hashable,
+            rec: _RunRecord) -> None:
+        arrays = rec.arrays()
+        for a in arrays:
+            a.setflags(write=False)
+        size = self.RECORD_OVERHEAD + sum(a.nbytes for a in arrays)
+        with self._lock:
+            old = self._records.pop(key, None)
+            if old is not None:
+                self._bytes -= old[2]
+            if size > self.max_bytes:
+                return
+            self._records[key] = (signature, rec, size)
+            self._bytes += size
+            while self._bytes > self.max_bytes:
+                _, (_, _, dropped) = self._records.popitem(last=False)
+                self._bytes -= dropped
+
+
+_RUN_RECORDS = RunRecordCache(RUN_CACHE_BYTES)
+
+
+def _read_run(storage, i: int):
+    """``(trace, successful, hint-space stamp)`` of stored run ``i``;
+    raises what the storage raises for a run it will not serve. Absent
+    stamps default to "content-v1", the same convention the checkpoint
+    loader uses (te.checkpoint_hint_space): every recording made by a
+    stamping build carries the tag (cli/run_cmd.py)."""
+    trace = storage.get_stored_history(i)
+    ok = storage.is_successful(i)
+    try:
+        stamp = ((storage.get_metadata(i) or {})
+                 .get("hint_space", "content-v1"))
+    except Exception:
+        stamp = "content-v1"
+    return trace, ok, stamp
+
+
+def _encode_run(trace, ok: bool, stamp: str, cap: Optional[int],
+                p: IngestParams) -> _RunRecord:
+    if stamp != HINT_SPACE:
+        return _RunRecord(None, None, ok, None, len(trace), stamp)
+    # two views of every run, one encode pass: arrival-anchored =
+    # counterfactual reference; realized = archive embedding
+    enc, enc_rt = te.encode_trace_views(trace, L=cap, H=p.H)
+    seed = None if ok else failure_seed(trace, p.H, p.max_interval)
+    return _RunRecord(enc, enc_rt, ok, seed, len(trace), stamp)
+
+
 class _Stages:
     """Wall time accumulated per ingest stage over the per-run loops:
     one ``obs.search_phase_observed`` each per ingest (a span per
@@ -161,7 +283,13 @@ def ingest_history(search, storage, p: IngestParams) -> List:
     stage hands the whole history to the device at once
     (``SearchBase.embed_batch``); its row's ``pieces`` = device calls and
     ``groups`` = padded trace lengths among the runs (each a compiled
-    embed of its own); the encode row's ``events`` = events encoded.
+    embed of its own); the encode row's ``events`` = events of the runs
+    ingested and ``cached`` = how many of those runs came from the
+    encoded-run records (``RunRecordCache``) instead of the storage:
+    every stored run charges ``ingest_read`` its signature (and its
+    read on a miss) and ``ingest_encode`` the rest (the encode on a
+    miss), so both stages stay per stored run of the history INGESTED,
+    parsed or not.
 
     References are the most recent SUCCESSFUL runs (padded with failures
     only when no success exists yet): the counterfactual asks "what
@@ -202,51 +330,56 @@ def _ingest_history(search, storage, p: IngestParams, stages: _Stages,
         gmap = search.enable_guidance(p.guidance_width or None,
                                       p.guidance_window or None,
                                       fresh=True)
+    if p.L > 0:
+        cap: Optional[int] = p.L
+    elif p.release_mode == "reorder":
+        cap = p.order_mode_max_l
+    else:
+        cap = None  # delay mode scores long traces blockwise
     encoded = []
-    skipped_unstamped = events = 0
+    skipped_unstamped = events = cached = 0
     for i in range(n):
         t = time.monotonic()
-        try:
-            trace = storage.get_stored_history(i)
-            ok = storage.is_successful(i)
-        except Exception:
-            stages.add("ingest_read", t)
-            continue
+        # what the loop needs of a stored run is a pure function of its
+        # two files and (cap, H, max_interval, HINT_SPACE): a run whose
+        # signature has not changed since a request encoded it is taken
+        # from the record kept then, and nothing of it is opened
+        signature = storage.run_signature(i)
+        key = rec = None
+        if signature is not None:
+            key = (os.path.abspath(storage.run_dir(i)), cap, p.H,
+                   p.max_interval, HINT_SPACE)
+            rec = _RUN_RECORDS.get(key, signature)
+        hit = rec is not None
+        run = None
+        if not hit:
+            try:
+                run = _read_run(storage, i)
+            except Exception:
+                stages.add("ingest_read", t)
+                continue
+        t = stages.add("ingest_read", t)
+        if run is not None:
+            rec = _encode_run(*run, cap, p)
+            if key is not None:
+                _RUN_RECORDS.put(key, signature, rec)
         # runs recorded under a different replay-hint format hash into a
         # different bucket space — training on them would deliver
-        # arbitrary delays under a "searched schedule" log. Absent
-        # stamps default to "content-v1", the same convention the
-        # checkpoint loader uses (te.checkpoint_hint_space): every
-        # recording made by a stamping build carries the tag
-        # (cli/run_cmd.py).
-        try:
-            stamp = ((storage.get_metadata(i) or {})
-                     .get("hint_space", "content-v1"))
-        except Exception:
-            stamp = "content-v1"
-        t = stages.add("ingest_read", t)
-        if stamp != HINT_SPACE:
+        # arbitrary delays under a "searched schedule" log
+        if rec.stamp != HINT_SPACE:
             skipped_unstamped += 1
             continue
-        if p.L > 0:
-            cap: Optional[int] = p.L
-        elif p.release_mode == "reorder":
-            cap = p.order_mode_max_l
-        else:
-            cap = None  # delay mode scores long traces blockwise
-        # two views of every run, one encode pass: arrival-anchored =
-        # counterfactual reference; realized = archive embedding
-        enc, enc_rt = te.encode_trace_views(trace, L=cap, H=p.H)
-        if enc.truncated:
+        if rec.enc.truncated:
             log.warning(
                 "trace %d truncated: %d events beyond the L=%d cap were "
-                "dropped from scoring (%s)", i, enc.truncated, cap,
+                "dropped from scoring (%s)", i, rec.enc.truncated, cap,
                 "configured trace_length" if p.L > 0
                 else "order-mode memory bound")
-        seed = None if ok else failure_seed(trace, p.H, p.max_interval)
-        encoded.append((enc, enc_rt, ok, seed))
-        events += len(trace)
-        stages.add("ingest_encode", t, events=len(trace))
+        encoded.append((rec.enc, rec.enc_rt, rec.ok, rec.seed))
+        events += rec.n_events
+        cached += hit
+        stages.add("ingest_encode", t, events=rec.n_events, cached=int(hit))
+    obs.ingest_cached_runs(cached)
     obs.ingest_events(events)
     if skipped_unstamped:
         log.warning(

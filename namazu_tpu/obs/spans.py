@@ -68,6 +68,7 @@ SPAN_ROWS_DROPPED = "nmz_span_rows_dropped_total"
 INGEST_RUNS = "nmz_ingest_runs_total"
 INGEST_EMBED_CALLS = "nmz_ingest_embed_calls_total"
 INGEST_EVENTS = "nmz_ingest_events_total"
+INGEST_CACHED_RUNS = "nmz_ingest_cached_runs_total"
 EVOLVE_REQUESTS = "nmz_evolve_requests_total"
 COMPILES = "nmz_compiles_total"
 COMPILE_SECONDS = "nmz_compile_seconds"
@@ -1371,6 +1372,18 @@ def ingest_events(n: int) -> None:
     metrics.get().counter(
         INGEST_EVENTS, "events of the stored runs history ingests "
                        "encoded").inc(n)
+
+
+def ingest_cached_runs(n: int) -> None:
+    """Stored runs one ingest took from the encoded-run records it keeps
+    (``models/ingest.py`` ``RunRecordCache``) in place of reading and
+    encoding them; over ``nmz_ingest_runs_total``: the share of a
+    request's history that was not new to this process."""
+    if not metrics.enabled():
+        return
+    metrics.get().counter(
+        INGEST_CACHED_RUNS, "stored runs history ingests took from "
+                            "their encoded-run records").inc(n)
 
 
 def evolve_request(scorer: str) -> None:

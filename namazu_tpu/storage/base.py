@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Dict, Hashable, Iterable, List, Optional
 
 from namazu_tpu.utils.trace import SingleTrace
 
@@ -79,6 +79,15 @@ class HistoryStorage:
     def quarantined_runs(self) -> List[int]:
         return [i for i in range(self.nr_stored_histories())
                 if self.is_quarantined(i)]
+
+    def run_signature(self, i: int) -> Optional[Hashable]:
+        """A hashable token that changes whenever run ``i``'s stored
+        content or visibility does, or None where the backend cannot say
+        (this default). A reader that kept something derived from the
+        run under ``(run_dir(i), signature)`` may reuse it while the
+        signature compares equal (models/ingest.py's encoded-run
+        records); None means "never cached": every query re-read."""
+        return None
 
     def get_stored_history(self, i: int) -> SingleTrace:
         raise NotImplementedError

@@ -57,6 +57,11 @@ class MongoDBStorage(NaiveStorage):
             "metadata": metadata or {},
         })
 
+    def run_signature(self, i: int) -> None:
+        """None ("never cached"): the collections are written beside
+        the files, and nothing here can say whether they changed."""
+        return None
+
     def close(self) -> None:
         self._client.close()
 

@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Dict, Hashable, Iterable, List, Optional
 
 from namazu_tpu.storage.base import HistoryStorage, StorageError, register_storage
 from namazu_tpu.utils.atomic import atomic_write_json, atomic_write_text, is_tmp_artifact
@@ -42,6 +42,14 @@ log = get_logger("storage.naive")
 INCOMPLETE_MARKER = "INCOMPLETE"
 
 
+def _file_signature(path: str) -> tuple:
+    """What tells one content of ``path`` from the next without opening
+    it: every write here is tmp + rename, so a rewritten file is a new
+    inode (size and mtime catch an edit made in place from outside)."""
+    st = os.stat(path)
+    return st.st_ino, st.st_size, st.st_mtime_ns
+
+
 @register_storage
 class NaiveStorage(HistoryStorage):
     NAME = "naive"
@@ -50,6 +58,7 @@ class NaiveStorage(HistoryStorage):
         self.dir = os.path.abspath(dir_path)
         self._next_run = 0
         self._current_run_dir: Optional[str] = None
+        self._last_result: tuple = (None, None)  # see _result
 
     # -- layout helpers --------------------------------------------------
 
@@ -98,9 +107,9 @@ class NaiveStorage(HistoryStorage):
         quiescent storage, marks those too."""
         for i in range(self._next_run):
             run_dir = self.run_dir(i)
-            if (os.path.exists(os.path.join(run_dir, "trace.json"))
-                    and not os.path.exists(
-                        os.path.join(run_dir, "result.json"))
+            # the result first: a completed run costs one ``stat``
+            if (not os.path.exists(os.path.join(run_dir, "result.json"))
+                    and os.path.exists(os.path.join(run_dir, "trace.json"))
                     and not os.path.exists(self._marker_path(i))):
                 atomic_write_text(
                     self._marker_path(i),
@@ -164,6 +173,21 @@ class NaiveStorage(HistoryStorage):
     def quarantined_runs(self) -> List[int]:
         return [i for i in range(self._next_run) if self.is_quarantined(i)]
 
+    def run_signature(self, i: int) -> Optional[Hashable]:
+        """``_file_signature`` of ``trace.json`` and of ``result.json``
+        and no quarantine marker: three ``stat``s, nothing opened. None
+        for a quarantined run, a missing file or a ``stat`` that raises:
+        such a run has no signature and takes the ordinary queries,
+        which raise what they always raised."""
+        if self.is_quarantined(i):
+            return None
+        run_dir = self.run_dir(i)
+        try:
+            return (_file_signature(os.path.join(run_dir, "trace.json")),
+                    _file_signature(os.path.join(run_dir, "result.json")))
+        except OSError:
+            return None
+
     def fsck(self, repair: bool = False) -> Dict[str, Any]:
         """Integrity report over every allocated run dir; with
         ``repair``, quarantine incomplete runs (including trace-less
@@ -222,21 +246,30 @@ class NaiveStorage(HistoryStorage):
     # -- queries ---------------------------------------------------------
 
     def nr_stored_histories(self) -> int:
-        # count only runs that completed (have a result)
-        n = 0
-        for i in range(self._next_run):
+        # up to the last run that completed (has a result), found from
+        # the newest run down: one ``stat`` where every run completed
+        for i in reversed(range(self._next_run)):
             if os.path.exists(os.path.join(self.run_dir(i), "result.json")):
-                n = i + 1
-        return n
+                return i + 1
+        return 0
 
     def _result(self, i: int) -> Dict[str, Any]:
+        """Run ``i``'s ``result.json``, parsed once for the queries that
+        follow one another on the same run (``is_successful`` then
+        ``get_metadata``): the last document is kept and revalidated by
+        ``stat`` — a rewritten file is a new inode."""
         if self.is_quarantined(i):
             raise StorageError(f"run {i:08x} is quarantined (INCOMPLETE)")
         path = os.path.join(self.run_dir(i), "result.json")
-        if not os.path.exists(path):
-            raise StorageError(f"run {i:08x} has no result")
-        with open(path) as f:
-            return json.load(f)
+        try:
+            key = (path, _file_signature(path))
+        except OSError:
+            raise StorageError(f"run {i:08x} has no result") from None
+        last = self._last_result
+        if last[0] != key:
+            with open(path) as f:
+                last = self._last_result = (key, json.load(f))
+        return last[1]
 
     def get_stored_history(self, i: int) -> SingleTrace:
         # quarantined runs ARE likely to have a trace — refusing to

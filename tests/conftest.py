@@ -15,3 +15,17 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _obs_switch_put_back():
+    """The observability switch is the process's (obs/metrics.py): a
+    test that turns it off must not decide what the next file on the
+    same worker records."""
+    from namazu_tpu.obs import metrics
+
+    was_on = metrics.enabled()
+    yield
+    metrics.configure(was_on)

@@ -19,6 +19,7 @@ from namazu_tpu.models.failure_pool import (
 from namazu_tpu.models.ingest import IngestParams, ingest_history
 from namazu_tpu.models.search import ScheduleSearch, SearchConfig
 from namazu_tpu.ops import trace_encoding as te
+from namazu_tpu.storage.base import HistoryStorage
 
 H, K = 32, 32
 
@@ -248,7 +249,7 @@ def test_run_with_anneal_executes():
 # -- ingest integration --------------------------------------------------
 
 
-class _FakeStorage:
+class _FakeStorage(HistoryStorage):
     """Minimal storage: list of (trace, successful)."""
 
     def __init__(self, runs):

@@ -31,6 +31,7 @@ from namazu_tpu.obs.metrics import MetricsRegistry
 from namazu_tpu.ops import trace_encoding as te
 from namazu_tpu.signal import PacketEvent
 from namazu_tpu.signal.action import EventAcceptanceAction
+from namazu_tpu.storage.base import HistoryStorage
 from namazu_tpu.utils.trace import SingleTrace
 
 
@@ -48,7 +49,7 @@ def fresh_obs():
 H = K = 16
 
 
-class FakeStorage:
+class FakeStorage(HistoryStorage):
     def __init__(self, runs):
         self.runs = runs
 

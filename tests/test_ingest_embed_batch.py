@@ -27,6 +27,7 @@ from namazu_tpu.ops import schedule as sch
 from namazu_tpu.ops import trace_encoding as te
 from namazu_tpu.signal import PacketEvent
 from namazu_tpu.signal.base import HINT_SPACE
+from namazu_tpu.storage.base import HistoryStorage
 from namazu_tpu.utils.trace import SingleTrace
 
 from tests.test_request_spans import isolated_obs
@@ -76,8 +77,9 @@ def make_run(seed, n_events=17):
     return t
 
 
-class ListStorage:
-    """Stored runs in memory: ``(trace, successful)`` in order."""
+class ListStorage(HistoryStorage):
+    """Stored runs in memory: ``(trace, successful)`` in order (no
+    ``run_signature``: nothing of it is ever cached)."""
 
     def __init__(self, runs):
         self.runs = list(runs)
@@ -482,7 +484,8 @@ def test_ingest_counts_events_and_length_groups(short, long, groups,
             == request * events
     rows = fresh_obs.since(0)["rows"]
     encode = [r[7] for r in rows if r[1] == "ingest_encode"]
-    assert encode == [{"pieces": short + long, "events": events}] * 2
+    assert encode == [{"pieces": short + long, "events": events,
+                       "cached": 0}] * 2
     embed = [r[7] for r in rows if r[1] == "ingest_embed"]
     assert [e["groups"] for e in embed] == [groups, groups]
     assert [e["pieces"] for e in embed] == [groups, groups]
