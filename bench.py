@@ -9,10 +9,10 @@ schedules-tried-per-hour. The benchmark times the jitted population scorer
 (counterfactual release times -> precedence features -> archive-distance
 matmul) at production sizes on the TPU and compares against a
 single-thread numpy implementation of the same math (the CPU-python
-baseline a reference-style policy could at best use). The two device
-modes (default and ``--fused``) exit non-zero when JAX's device is not a
-TPU — there is no CPU fallback; ``--smoke`` (tiny CI sizes, no history)
-is the one shape that runs on any backend.
+baseline a reference-style policy could at best use). The device mode
+exits non-zero when JAX's device is not a TPU — there is no CPU
+fallback; ``--smoke`` (tiny CI sizes, no history) is the one shape that
+runs on any backend.
 
 ``--pipeline`` measures the OTHER half of the serving path: a loopback
 inspector -> REST endpoint -> orchestrator -> policy -> action poll ->
@@ -256,10 +256,6 @@ def gate_record(current: dict, history: list,
     # one — they are different machines
     # "runs" joined in round 10 (tenancy plane): an 8-tenant aggregate
     # figure must never baseline against a single-run one
-    # "fused" joined in round 11: the device-resident fused generation
-    # loop (score+select+mutate in one scan'd dispatch) and the plain
-    # scorer chain time DIFFERENT work per schedule — a fused figure
-    # must never baseline an unfused one, in either direction
     # "profile" joined with the profiling plane: the sampling profiler
     # rides the pipeline bench by default (budgeted <=2%), and the
     # --no-profile A/B figure must never cross-gate a profiled one
@@ -271,7 +267,7 @@ def gate_record(current: dict, history: list,
     CONFIG_KEYS = ("n_events", "n_entities", "batch_max",
                    "flush_window", "poll_linger", "gc_disabled",
                    "telemetry", "codec", "edge_shards", "edge_events",
-                   "runs", "fused", "profile", "virtual_clock",
+                   "runs", "profile", "virtual_clock",
                    "delay_scale")
 
     def _mode(rec):
@@ -1354,15 +1350,6 @@ def parse_args(argv=None) -> argparse.Namespace:
                          "unique-interleaving fraction from `nmz-tpu "
                          "tools report`) folded into the history record "
                          "and gated alongside schedules/s")
-    ap.add_argument("--fused", action="store_true",
-                    help="measure the device-resident FUSED generation "
-                         "loop (score->select->mutate->migrate in one "
-                         "lax.scan'd, buffer-donated dispatch; "
-                         "doc/performance.md \"Fused search loop\") at "
-                         "the scorer bench's population, against the "
-                         "pre-fusion per-generation dispatch loop in "
-                         "the same process; --smoke = tiny CI sizes, "
-                         "no history append")
     ap.add_argument("--pipeline", action="store_true",
                     help="measure the event plane instead of the "
                          "scorer: a loopback inspector -> orchestrator "
@@ -1479,149 +1466,6 @@ def parse_args(argv=None) -> argparse.Namespace:
     return ap.parse_args(argv)
 
 
-def fused_main(args) -> None:
-    """``--fused``: schedules/s/chip of the device-resident fused
-    generation loop vs the pre-fusion per-generation dispatch loop,
-    measured back to back in one process (same mesh, same population,
-    same jit cache). The fused figure is the serving number; the
-    unfused one is the per-generation dispatch shape, so ``vs_unfused``
-    is the same-device fusion speedup.
-    """
-    device = _require_tpu(args.smoke)
-    import jax
-    import jax.numpy as jnp
-
-    from namazu_tpu.models.ga import GAConfig
-    from namazu_tpu.ops import trace_encoding as te
-    from namazu_tpu.ops.schedule import ScoreWeights, TraceArrays
-    from namazu_tpu.parallel.islands import (
-        init_island_state,
-        make_fused_island_step,
-        make_multiaxis_island_step,
-    )
-    from namazu_tpu.parallel.mesh import make_mesh
-
-    if args.smoke:
-        P, H, L, K, A, F, iters, reps = 256, 64, 128, 64, 64, 16, 8, 2
-    else:
-        # the scorer bench's population: 8192 genomes, 50 generations
-        # per timed dispatch, production archive sizes
-        P, H, L, K, A, F, iters, reps = 8192, 256, 256, 256, 1024, 64, 50, 5
-
-    n_ev = min(240, L - 16)
-    enc = te.encode_event_stream(
-        [f"hint:{i % 96}" for i in range(n_ev)],
-        arrivals=[i * 1e-3 for i in range(n_ev)],
-        L=L, H=H,
-    )
-    trace = TraceArrays(
-        jnp.asarray(enc.hint_ids), jnp.asarray(enc.arrival),
-        jnp.asarray(enc.mask),
-    )
-    pairs = jnp.asarray(te.sample_pairs(K, H, 0))
-    archive = jnp.asarray(
-        np.random.RandomState(0).rand(A, K).astype(np.float32))
-    failures = jnp.asarray(
-        np.random.RandomState(1).rand(F, K).astype(np.float32))
-    mesh = make_mesh(1)
-    cfg = GAConfig(max_delay=0.1)
-    rings = (("i", 8),)
-    key = jax.random.PRNGKey(1)
-
-    gc.disable()
-    try:
-        # fused: ONE donated dispatch per iters generations; the timing
-        # loop chains states exactly like a campaign's run() does
-        fused = make_fused_island_step(mesh, cfg, ScoreWeights(),
-                                       rings=rings, generations=iters)
-        state = init_island_state(jax.random.PRNGKey(0), P, H, cfg)
-        state, hist = fused(state, key, trace, pairs, archive, failures)
-        hist.block_until_ready()  # warmup/compile
-        best_dt = float("inf")
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            state, hist = fused(state, key, trace, pairs, archive,
-                                failures)
-            hist.block_until_ready()
-            best_dt = min(best_dt, time.perf_counter() - t0)
-        fused_rate = P * iters / best_dt
-
-        # pre-fusion shape: one jitted dispatch per generation, host
-        # loop in between (models/search.py _run_stepwise)
-        step = make_multiaxis_island_step(mesh, cfg, ScoreWeights(),
-                                          rings=rings)
-        s2 = init_island_state(jax.random.PRNGKey(0), P, H, cfg)
-        s2 = step(s2, key, trace, pairs, archive, failures)
-        s2.best_fitness.block_until_ready()  # warmup/compile
-        best_un = float("inf")
-        for _ in range(reps):
-            s = s2
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                s = step(s, key, trace, pairs, archive, failures)
-            s.best_fitness.block_until_ready()
-            best_un = min(best_un, time.perf_counter() - t0)
-        unfused_rate = P * iters / best_un
-    finally:
-        gc.enable()
-
-    # one source of truth with live telemetry: the JSON line reads the
-    # figure back from the fused-labeled scorer gauge
-    from namazu_tpu import obs
-
-    obs.configure(True)
-    obs.scorer_throughput("fused", fused_rate)
-    device_rate = obs.scorer_throughput_value("fused")
-
-    platform = device["platform"]
-    out = {
-        "metric": SCORER_METRIC,
-        "value": round(device_rate, 1),
-        "unit": "schedules/s",
-        "fused": True,
-        "generations_per_dispatch": iters,
-        "population": P,
-        "unfused_schedules_per_sec": round(unfused_rate, 1),
-        "vs_unfused": round(device_rate / unfused_rate, 2),
-        **device,
-        "scorer_source": "fused",
-        "smoke": bool(args.smoke),
-    }
-    if args.smoke:
-        # tiny CI workload: validate the machinery + artifact shape,
-        # never a history point
-        print(json.dumps(out))
-        return
-    prior = load_history(args.history)
-    record = {
-        "timestamp": datetime.datetime.now(
-            datetime.timezone.utc).isoformat(timespec="seconds"),
-        "revision": _code_revision(),
-        "metric": SCORER_METRIC,
-        "schedules_per_sec": out["value"],
-        "unit": out["unit"],
-        "fused": True,
-        "vs_unfused": out["vs_unfused"],
-        "platform": platform,
-    }
-    try:
-        append_history(record, args.history)
-    except OSError as e:
-        print(f"# could not append bench history: {e}", file=sys.stderr)
-    if args.gate:
-        ok, reasons, baseline = gate_record(
-            record, prior, threshold_pct=args.gate_threshold)
-        out["gate"] = {"ok": ok, "threshold_pct": args.gate_threshold,
-                       "baseline": baseline, "reasons": reasons}
-        print(json.dumps(out))
-        if not ok:
-            for reason in reasons:
-                print(f"# GATE FAILED: {reason}", file=sys.stderr)
-            raise SystemExit(1)
-        return
-    print(json.dumps(out))
-
-
 def main(argv=None) -> None:
     args = parse_args(argv)
     if args.campaign:
@@ -1632,8 +1476,6 @@ def main(argv=None) -> None:
         # pure control plane: no jax import — the event plane runs the
         # same everywhere
         return pipeline_main(args)
-    if args.fused:
-        return fused_main(args)
 
     device = _require_tpu(args.smoke)
     import jax

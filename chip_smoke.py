@@ -16,8 +16,9 @@ on — ``examples/zk-election``, a 3-node FLE ensemble:
 and, in a process of its own before that, checks what the entry points
 cannot show: the Mosaic custom call in the compiled fused step, chip
 fitness against the independent numpy scorer (``bench.numpy_score``),
-fused == stepwise, cold vs warm compile, and — on a multi-chip host —
-one population shard per device and the migration ring.
+chunks of 16 == one generation a dispatch, cold vs warm compile, and
+— on a multi-chip host — one population shard per device and the
+migration ring.
 
 Ownership rule, by construction: this parent never imports jax; every
 phase is a child process started after the previous one has exited, so
@@ -25,8 +26,8 @@ exactly one process holds the chip at any time.
 
 Last line of stdout: ``{"ok": true, "device": {"platform": "tpu",
 "kind": ..., "count": N}}`` — exactly those keys. What the run
-established besides (compile seconds, numpy agreement, fused ==
-stepwise, mesh, searched runs, phase seconds) is the ``chip_smoke
+established besides (compile seconds, numpy agreement, chunk
+independence, mesh, searched runs, phase seconds) is the ``chip_smoke
 facts:`` line before it and ``chiprun_out/chip_smoke/facts.json``. Any
 other outcome exits non-zero and prints no result line — including "JAX
 found no TPU": there is no CPU fallback. ``--cpu [N]`` is the explicit
@@ -451,7 +452,7 @@ def parent_main(args) -> int:
                                         cache_entries()]},
         "mosaic_custom_call": cold["mosaic_custom_call"],
         "numpy_agreement": cold["numpy_agreement"],
-        "fused_equals_stepwise": cold["fused_equals_stepwise"],
+        "chunk16_equals_chunk1": cold["chunk16_equals_chunk1"],
         "kernels": cold["kernels"],
         "mesh": cold["mesh"],
         "searched_runs": searched,
@@ -505,7 +506,7 @@ def _synthetic_history(search, n_refs: int = 4, seed: int = 0):
 
 
 def _lower_fused(search, refs):
-    """The fused island step exactly as ``ScheduleSearch._run_fused``
+    """The fused island step exactly as ``ScheduleSearch.run``
     dispatches its first chunk, lowered (not run): the smoke reads the
     compiled program and times its compile."""
     import jax.numpy as jnp
@@ -534,7 +535,7 @@ def _check_mesh(search, before, refs, workdir: str) -> dict:
     import jax
     import numpy as np
 
-    from namazu_tpu.sidecar import build_search_from_params
+    from namazu_tpu.models.search import build_search_from_params
 
     n = len(jax.devices())
     mesh_n = int(np.prod(list(search.mesh.shape.values())))
@@ -698,21 +699,22 @@ def _pallas_vs_xla() -> dict:
     return out
 
 
-def _fused_vs_stepwise(refs_seed: int = 0) -> dict:
+def _chunk16_vs_chunk1(refs_seed: int = 0) -> dict:
     """tests/test_fused_loop.py's contract, at the shipped width, on
-    this device: two searches from one seed, one fused and one
-    per-generation, must hold the same population and best."""
+    this device: two searches from one seed, one in fused chunks of 16
+    generations and one a generation a dispatch, must hold the same
+    population and best."""
     import numpy as np
 
-    from namazu_tpu.sidecar import build_search_from_params
+    from namazu_tpu.models.search import build_search_from_params
 
     out = {}
     states = []
-    for fused in (True, False):
-        s = build_search_from_params({"fused": fused,
+    for chunk in (16, 1):
+        s = build_search_from_params({"fused_chunk": chunk,
                                       "surrogate_topk": 0})
         refs = _synthetic_history(s, seed=refs_seed)
-        s.run(refs, generations=2 * s.cfg.fused_chunk)
+        s.run(refs, generations=32)
         states.append((np.asarray(s._state.pop.delays),
                        np.asarray(s._state.best_delays),
                        float(s._state.best_fitness)))
@@ -737,7 +739,7 @@ def child_device(args) -> int:
         return 3
     on_tpu = device["platform"] == "tpu"
 
-    from namazu_tpu.sidecar import build_search_from_params
+    from namazu_tpu.models.search import build_search_from_params
 
     # the shipped width: an empty params dict is the policy's defaults
     search = build_search_from_params({})
@@ -789,13 +791,13 @@ def child_device(args) -> int:
             f"chip fitness disagrees with bench.numpy_score beyond the "
             f"stated tolerance: {out['numpy_agreement']}")
 
-    out["fused_equals_stepwise"] = _fused_vs_stepwise()
-    print(f"fused vs stepwise: {out['fused_equals_stepwise']}",
+    out["chunk16_equals_chunk1"] = _chunk16_vs_chunk1()
+    print(f"chunks of 16 vs 1: {out['chunk16_equals_chunk1']}",
           flush=True)
-    if not on_tpu and not out["fused_equals_stepwise"]["bit_exact"]:
+    if not on_tpu and not out["chunk16_equals_chunk1"]["bit_exact"]:
         raise AssertionError(
-            "fused != stepwise off the TPU, where the tier-1 test pins "
-            f"bit-exactness: {out['fused_equals_stepwise']}")
+            "chunks of 16 != chunks of 1 off the TPU, where the tier-1 "
+            f"test pins bit-exactness: {out['chunk16_equals_chunk1']}")
 
     with open(args.out, "w") as f:
         json.dump(out, f)

@@ -90,9 +90,9 @@ def ga_generation(key: jax.Array, pop: Population, fitness: jax.Array,
     ``ScheduledQueue.put_many``'s): one generation consumes exactly the
     splits/draws derived from its ``key``, and the per-generation key is
     always ``fold_in(base_key, gen)`` — whether generations run one
-    jitted dispatch at a time or fused in a ``lax.scan``
-    (parallel/islands.py). That is what makes the fused loop bit-exact
-    with the stepwise loop (tests/test_fused_loop.py)."""
+    jitted dispatch at a time or many in one ``lax.scan``
+    (parallel/islands.py). That is what makes a search's result
+    independent of its chunk length (tests/test_fused_loop.py)."""
     P, H = pop.delays.shape
     n_elite = max(1, int(P * cfg.elite_frac))
     ks = jax.random.split(key, 6)

@@ -319,8 +319,7 @@ def make_parallel_mcts(mesh, H: int, cfg: MCTSConfig = MCTSConfig(),
     independent tree from a folded key (rollout batches keep the MXU busy
     per device), then the per-device bests are ``all_gather``-ed and the
     argmax is replicated — same collective shape as the GA islands'
-    global-best agreement (parallel/islands.py). Works on flat (``i``) and
-    hybrid host x chip (``h x i``) meshes alike: the key is folded with
+    global-best agreement (parallel/islands.py). The key is folded with
     every mesh axis and the gather runs axis by axis."""
     from jax.sharding import PartitionSpec as P
 

@@ -17,6 +17,7 @@ from typing import NamedTuple, Optional
 import numpy as np
 
 from namazu_tpu import obs
+from namazu_tpu.models import SEARCH_DEFAULTS
 from namazu_tpu.models.ga import GAConfig
 from namazu_tpu.ops import trace_encoding as te
 from namazu_tpu.ops.schedule import ScoreWeights, scorer_branch
@@ -26,15 +27,19 @@ log = get_logger("models.search")
 
 
 class SearchConfig(NamedTuple):
-    H: int = te.DEFAULT_H  # hint buckets (genome length)
-    L: int = te.DEFAULT_L  # encode-length cap hint; 0 = uncapped (the
-    # driver encodes before calling run(), so this field is informational)
-    K: int = te.DEFAULT_K  # feature pairs
+    """What a search is built from. The fields that are knobs of the
+    ``tpu_search`` policy take their defaults from ``SEARCH_DEFAULTS``
+    (namazu_tpu/models/__init__.py), where each is described."""
+
+    H: int = SEARCH_DEFAULTS["H"]  # hint buckets (genome length)
+    L: int = SEARCH_DEFAULTS["L"]  # encode-length cap hint; 0 = uncapped
+    # (the driver encodes before calling run(): informational)
+    K: int = SEARCH_DEFAULTS["K"]  # feature pairs
     archive_size: int = 512  # novelty archive capacity
     failure_size: int = 64  # failure archive capacity
-    population: int = 4096  # total genomes across all islands
-    migrate_k: int = 8
-    seed: int = 0
+    population: int = SEARCH_DEFAULTS["population"]
+    migrate_k: int = SEARCH_DEFAULTS["migrate_k"]
+    seed: int = SEARCH_DEFAULTS["seed"]
     ga: GAConfig = GAConfig()
     weights: ScoreWeights = ScoreWeights()
     # learned surrogate (BASELINE config 5): when > 0, an online MLP
@@ -42,7 +47,7 @@ class SearchConfig(NamedTuple):
     # genomes of the evolved population, and run() returns the candidate
     # with the highest predicted repro instead of the raw fitness argmax.
     # 0 disables (fitness argmax, the pre-surrogate behavior).
-    surrogate_topk: int = 0
+    surrogate_topk: int = SEARCH_DEFAULTS["surrogate_topk"]
     # novelty anneal (GA backend): with fewer than this many DISTINCT
     # failure signatures in the archive the search keeps its full
     # configured novelty weight (keep exploring — exploiting 1-2
@@ -51,30 +56,24 @@ class SearchConfig(NamedTuple):
     # scaled by min_failure_signatures / n_signatures (never below
     # novelty_floor) so a rich archive shifts the search toward
     # exploitation. 0 disables (static weights).
-    min_failure_signatures: int = 0
-    novelty_floor: float = 0.25
+    min_failure_signatures: int = SEARCH_DEFAULTS["min_failure_signatures"]
+    novelty_floor: float = SEARCH_DEFAULTS["novelty_floor"]
     # causality guidance (doc/search.md): weight of the predicted
     # relation-coverage gain in the final candidate pick, added on top
     # of the surrogate probability (or the normalized fitness when no
     # surrogate has trained). Only consulted once a CoverageMap is
     # wired via enable_guidance(); with none wired the search is
     # bit-identical to pre-guidance behavior.
-    guidance_bonus: float = 0.5
-    # fused search loop (doc/performance.md "Fused search loop"): run
-    # the whole generation loop device-side — lax.scan over fused_chunk
-    # generations per dispatch with the island state DONATED, traces
+    guidance_bonus: float = SEARCH_DEFAULTS["guidance_bonus"]
+    # generations per dispatch of the fused island step
+    # (doc/performance.md "Fused search loop"): lax.scan over
+    # fused_chunk generations with the island state DONATED, traces
     # and archives device-resident across run() calls, host I/O
-    # double-buffered against the next chunk's compute. Bit-exact with
-    # the per-generation path by construction (same key fold order;
-    # pinned by tests/test_fused_loop.py), so this is purely a
-    # dispatch-shape choice. False = the pre-fusion per-generation loop.
-    fused: bool = True
-    fused_chunk: int = 16  # generations per fused dispatch
-    # migration cadence, decoupled from the generation count: the ICI
-    # ring permutes every migrate_every generations, a hybrid mesh's
-    # DCN ring every dcn_migrate_every (1 = the pre-cadence behavior)
-    migrate_every: int = 1
-    dcn_migrate_every: int = 1
+    # double-buffered against the next chunk's compute. A dispatch-shape
+    # choice only: results do not depend on it (same key fold order;
+    # pinned by tests/test_fused_loop.py), and 1 is one dispatch per
+    # generation.
+    fused_chunk: int = SEARCH_DEFAULTS["fused_chunk"]
 
 
 class BestSchedule(NamedTuple):
@@ -251,24 +250,25 @@ class _ResidentTraces:
 
 
 def make_score_weights(
-    release_mode: str = "delay",
-    w_novelty: float = 1.0,
-    w_bug: float = 1.0,
-    w_delay_cost: float = 0.01,
-    w_fault_cost: float = 0.05,
-    tau: float = 0.005,
-    reorder_gap: float = 0.002,
-    reorder_window: float = 0.05,
+    *,
+    release_mode: str,
+    w_novelty: float,
+    w_bug: float,
+    w_delay_cost: float,
+    w_fault_cost: float,
+    tau: float,
+    reorder_gap: float,
+    reorder_window: float,
 ) -> ScoreWeights:
-    """ScoreWeights for a release mode — one home for the subtle part
-    (shared by policy/tpu.py and the sidecar): scoring must model the
-    same realization the control plane uses. Order mode permutes within
-    reorder_window batches by the table's priorities; delay mode adds
-    the table to arrivals. delay_cost=0 in order mode: uniform priority
-    shifts don't change the permutation, so penalizing the table's mean
-    would only drive priorities onto the 0 clip boundary (collapsing to
-    arrival order via the tie-break); tau of the order of the gap keeps
-    adjacent ranks' precedence features saturated."""
+    """ScoreWeights for a release mode — one home for the subtle part:
+    scoring must model the same realization the control plane uses.
+    Order mode permutes within reorder_window batches by the table's
+    priorities; delay mode adds the table to arrivals. delay_cost=0 in
+    order mode: uniform priority shifts don't change the permutation,
+    so penalizing the table's mean would only drive priorities onto the
+    0 clip boundary (collapsing to arrival order via the tie-break);
+    tau of the order of the gap keeps adjacent ranks' precedence
+    features saturated."""
     if release_mode == "reorder":
         gap = max(reorder_gap, 1e-4)
         return ScoreWeights(
@@ -281,6 +281,66 @@ def make_score_weights(
         novelty=w_novelty, bug=w_bug, delay_cost=w_delay_cost,
         fault_cost=w_fault_cost, tau=tau,
     )
+
+
+def build_search_from_params(params: dict, mesh=None):
+    """A search backend from the flat JSON-able knobs the policy states
+    (``TPUSearchPolicy._search_params``, in-process or over the
+    sidecar's wire): the ONE place that turns knobs into a
+    ``SearchConfig``, weights, a backend and its guidance wiring. A
+    knob ``params`` leaves out takes its ``SEARCH_DEFAULTS`` value."""
+    p = {**SEARCH_DEFAULTS, **params}
+    cfg = SearchConfig(
+        H=p["H"], L=p["L"], K=p["K"],
+        population=p["population"],
+        migrate_k=p["migrate_k"],
+        seed=p["seed"],
+        ga=GAConfig(max_delay=p["max_interval"],
+                    max_fault=p["max_fault"]),
+        weights=make_score_weights(
+            release_mode=p["release_mode"],
+            w_novelty=p["w_novelty"], w_bug=p["w_bug"],
+            w_delay_cost=p["w_delay_cost"],
+            w_fault_cost=p["w_fault_cost"], tau=p["tau"],
+            reorder_gap=p["reorder_gap"],
+            reorder_window=p["reorder_window"]),
+        surrogate_topk=p["surrogate_topk"],
+        min_failure_signatures=p["min_failure_signatures"],
+        novelty_floor=p["novelty_floor"],
+        guidance_bonus=p["guidance_bonus"],
+        fused_chunk=p["fused_chunk"],
+    )
+    if p["search_backend"] == "mcts":
+        if cfg.surrogate_topk > 0:
+            log.warning(
+                "surrogate re-ranking (surrogate_topk=%d) applies to "
+                "the GA backend only; the mcts backend returns its "
+                "fitness argmax", cfg.surrogate_topk)
+        if p["guidance"]:
+            log.warning(
+                "causality guidance (guidance=true) biases the GA "
+                "backend's pick/mutation only; the mcts backend "
+                "still feeds the coverage map and metrics")
+        from namazu_tpu.models.mcts import MCTSConfig
+
+        mcts_cfg = MCTSConfig(
+            tree_depth=p["mcts_tree_depth"],
+            n_levels=p["mcts_levels"],
+            simulations=p["mcts_simulations"],
+            rollouts=p["mcts_rollouts"],
+            max_delay=p["max_interval"],
+            max_fault=p["max_fault"],
+        )
+        search = MCTSSearch(cfg, mcts_cfg=mcts_cfg, mesh=mesh,
+                            n_devices=p["devices"])
+    else:
+        search = ScheduleSearch(cfg, mesh=mesh, n_devices=p["devices"])
+    if p["guidance"]:
+        # wired BEFORE any checkpoint load/ingest so the archive's
+        # DAG-shape feature fragments stay slot-aligned
+        search.enable_guidance(p["guidance_width"] or None,
+                               p["guidance_window"] or None)
+    return search
 
 
 #: where the persistent compile cache lives when the caller placed none:
@@ -689,8 +749,10 @@ class SearchBase:
         return feats[known], labels[known]
 
     def _device_inputs(self, encoded):
-        """(traces, pairs, archive, failures) as device arrays, from one
-        encoded trace or a list of them."""
+        """(encs, traces, pairs, archive, failures) as device arrays
+        staged from the host, from one encoded trace or a list of them.
+        ``MCTSSearch.run`` is its one caller: the GA keeps its inputs
+        resident (``ScheduleSearch._device_inputs_fused``)."""
         import jax.numpy as jnp
 
         from namazu_tpu.ops.schedule import TraceArrays
@@ -829,32 +891,12 @@ class ScheduleSearch(SearchBase):
 
         super().__init__(cfg)
         self.mesh = mesh if mesh is not None else make_mesh(n_devices)
-        n_islands = 1
-        for s in self.mesh.shape.values():
-            n_islands *= s
+        n_islands = self.mesh.size
         # population must divide evenly across islands
         per_island = max(1, cfg.population // n_islands)
         self.population = per_island * n_islands
 
         self._key = jax.random.PRNGKey(cfg.seed)
-        if "h" in self.mesh.axis_names:
-            # hybrid host x chip mesh -> hierarchical ICI/DCN migration,
-            # each ring on its own cadence (dcn_migrate_every decouples
-            # the thin DCN exchange from the generation count)
-            from namazu_tpu.parallel.distributed import hier_rings
-
-            self._rings = hier_rings(
-                migrate_k=cfg.migrate_k,
-                migrate_every=cfg.migrate_every,
-                dcn_every=cfg.dcn_migrate_every,
-            )
-        else:
-            self._rings = (("i", cfg.migrate_k, cfg.migrate_every),)
-        from namazu_tpu.parallel.islands import make_multiaxis_island_step
-
-        self._step = make_multiaxis_island_step(
-            self.mesh, cfg.ga, cfg.weights, rings=self._rings
-        )
         self._state = init_island_state(
             jax.random.PRNGKey(cfg.seed + 1), self.population, cfg.H, cfg.ga
         )
@@ -907,13 +949,12 @@ class ScheduleSearch(SearchBase):
             self._dev_pairs_src = None
 
     def _device_inputs_fused(self, encoded):
-        """The fused-run analogue of ``_device_inputs``: the ordered
-        trace view comes from the resident store (only missing rows
-        upload), pairs/archive/failure buffers from the device mirrors
-        (synced by ``_mirror_rows``; staged whole only after a bulk
-        invalidation). Array VALUES are identical to ``_device_inputs``
-        for the same references — the property the fused-vs-unfused
-        bit-exactness test leans on."""
+        """``(encs, traces, pairs, archive, failures)`` for the island
+        step, device-resident: the ordered trace view comes from the
+        resident store (only missing rows upload), pairs/archive/failure
+        buffers from the device mirrors (synced by ``_mirror_rows``;
+        staged whole only after a bulk invalidation). Array VALUES are
+        those of ``_device_inputs`` for the same references."""
         import jax.numpy as jnp
 
         from namazu_tpu.ops.schedule import TraceArrays
@@ -974,7 +1015,7 @@ class ScheduleSearch(SearchBase):
 
             fn = make_fused_island_step(
                 self.mesh, self.cfg.ga, self.cfg.weights,
-                rings=self._rings, generations=generations)
+                migrate_k=self.cfg.migrate_k, generations=generations)
             self._fused_steps[generations] = fn
         return fn
 
@@ -996,10 +1037,6 @@ class ScheduleSearch(SearchBase):
         import jax.numpy as jnp
 
         if len(delay_tables) == 0:
-            return
-        if jax.process_count() > 1:  # pragma: no cover - DCN runs
-            # per-process seeding would diverge island contents between
-            # hosts; skip rather than corrupt the sharded population
             return
         seeds = np.clip(
             np.stack([np.asarray(t, np.float32) for t in delay_tables]),
@@ -1026,60 +1063,7 @@ class ScheduleSearch(SearchBase):
         fitness are re-ranked by predicted P(reproduce) and the winner is
         returned (the candidate worth the next wall-clock replay).
 
-        ``cfg.fused`` (default) runs the device-side fused loop; both
-        paths produce bit-identical populations and best tables
-        (tests/test_fused_loop.py), the fused one just stops paying a
-        host round trip per generation and a full re-staging per run."""
-        if self.cfg.fused:
-            return self._run_fused(encoded, generations)
-        return self._run_stepwise(encoded, generations)
-
-    def _run_stepwise(self, encoded, generations: int) -> BestSchedule:
-        """The pre-fusion loop: one jitted dispatch per generation.
-        Kept callable (cfg.fused=False) as the fused path's bit-exact
-        reference and for debugging single generations."""
-        # per-phase wall-time breakdown (nmz_search_phase_seconds +
-        # jax.profiler.TraceAnnotation when a profiler session is live):
-        # "encode" = host->device staging, "evolve" = the fused
-        # mutate/score/select/migrate loop (its in-step phases are
-        # jax.named_scope-annotated in parallel/islands.py, visible in a
-        # device profile), "extract"/"surrogate" = best extraction
-        with obs.search_phase("encode"):
-            _encs, trace, pairs, archive, failures = \
-                self._device_inputs(encoded)
-        import jax.numpy as jnp
-
-        coin = None if self._coin is None else jnp.asarray(self._coin)
-        nov_scale = jnp.asarray(self.novelty_scale(), jnp.float32)
-        # guided mutation (doc/search.md): buckets participating in
-        # one-sided/uncovered ordering relations mutate more often —
-        # None (no map) keeps the unbiased kernel bit-for-bit
-        bias = (None if self.guidance is None
-                else jnp.asarray(self.guidance.mutation_bias()))
-        state = self._state
-        t0 = time.perf_counter()
-        with obs.search_phase("evolve"):
-            for _ in range(generations):
-                state = self._step(state, self._key, trace, pairs, archive,
-                                   failures, coin, nov_scale, bias)
-            state.best_fitness.block_until_ready()
-        self._count_evolve(trace)
-        elapsed = time.perf_counter() - t0
-        self._state = state
-        self.generations_run += generations
-        self._record_progress(generations, elapsed,
-                              generations * self.population,
-                              float(state.best_fitness))
-        with obs.search_phase("surrogate"):
-            picked = self._surrogate_pick(trace, pairs, archive, failures,
-                                          nov_scale, encs=_encs)
-        if picked is not None:
-            return picked
-        with obs.search_phase("extract"):
-            return self.best()
-
-    def _run_fused(self, encoded, generations: int) -> BestSchedule:
-        """The device-resident loop (doc/performance.md "Fused search
+        The device-resident loop (doc/performance.md "Fused search
         loop"): generations run in fused_chunk-sized scans — one jitted
         dispatch each, island state donated — while the host lane drains
         the PREVIOUS chunk's per-generation best-fitness history
@@ -1089,7 +1073,9 @@ class ScheduleSearch(SearchBase):
         generation record's ``host_io_s``. Inside ``evolve``: ``place``
         (re-sharding the state), ``dispatch`` (time inside the fused
         calls, accumulated: the host's share of an async dispatch) and
-        ``wait`` (the final block on the device)."""
+        ``wait`` (the final block on the device). The in-step phases
+        are ``jax.named_scope``-annotated in parallel/islands.py,
+        visible in a device profile."""
         with obs.search_phase("encode"):
             encs, trace, pairs, archive, failures = \
                 self._device_inputs_fused(encoded)
@@ -1154,7 +1140,11 @@ class ScheduleSearch(SearchBase):
             except Exception:
                 self._recover_state()
                 raise
-        self._count_evolve(trace)
+        # one completed evolve, counted where its ``evolve`` span ends
+        # (the two agree over any window), under the scorer branch the
+        # island step takes for these reference traces' padded length
+        obs.evolve_request(scorer_branch(trace.hint_ids.shape[-1],
+                                         self.cfg.weights.order_mode))
         elapsed = time.perf_counter() - t0
         self.generations_run += generations
         # recovery snapshot (tiny: two [H] rows + a scalar): the newest
@@ -1181,14 +1171,6 @@ class ScheduleSearch(SearchBase):
             return picked
         with obs.search_phase("extract"):
             return self.best()
-
-    def _count_evolve(self, trace) -> None:
-        """One completed evolve (counted where its ``evolve`` span
-        ends, so the two agree over any window), under the scorer
-        branch the island step takes for these reference traces'
-        padded length."""
-        obs.evolve_request(scorer_branch(trace.hint_ids.shape[-1],
-                                         self.cfg.weights.order_mode))
 
     def _drain_host_lane(self, fit_hist, fit_curve: list) -> None:
         """The overlapped host-I/O work for one completed chunk: fetch
@@ -1254,24 +1236,8 @@ class ScheduleSearch(SearchBase):
         return max(self.cfg.novelty_floor, ms / n)
 
     def _fetch_population(self):
-        """Population as host numpy arrays (delays, faults).
-
-        On a multi-process mesh the population is sharded across hosts
-        and ``np.asarray`` on it raises "non-addressable devices"; gather
-        it explicitly so surrogate re-ranking and checkpointing work in
-        real DCN runs, not just virtual-host meshes."""
-        import jax
-
+        """Population as host numpy arrays (delays, faults)."""
         pop = self._state.pop
-        if jax.process_count() > 1:
-            from jax.experimental import multihost_utils
-
-            return (
-                np.asarray(multihost_utils.process_allgather(
-                    pop.delays, tiled=True)),
-                np.asarray(multihost_utils.process_allgather(
-                    pop.faults, tiled=True)),
-            )
         return np.asarray(pop.delays), np.asarray(pop.faults)
 
     # -- surrogate (BASELINE config 5) ------------------------------------
@@ -1366,7 +1332,8 @@ class ScheduleSearch(SearchBase):
         k = min(self.cfg.surrogate_topk, self.population)
         # de-shard the island population (a few MB) — this re-score runs
         # outside shard_map, where scatter on an @i-sharded operand is
-        # ambiguous; trace arrives stacked [T, L] from _device_inputs
+        # ambiguous; trace arrives stacked [T, L] from
+        # _device_inputs_fused
         delays_np, faults = self._fetch_population()
         delays = jnp.asarray(delays_np)
         fitness, feats = score_population_multi(

@@ -350,8 +350,8 @@ def pad_trace_row(enc: EncodedTrace, L: int) -> Dict[str, np.ndarray]:
     pad fills, shared by :func:`stack_traces` and the fused loop's
     device-resident trace store (models/search.py ``_ResidentTraces``):
     a resident row sliced back to a batch's length must be
-    value-identical to the host stacker's padding, or fused and
-    stepwise scoring would diverge on the pad region."""
+    value-identical to the host stacker's padding, or the island step
+    and the host-staged scorers would diverge on the pad region."""
     def pad(a, fill):
         n = L - a.shape[0]
         if n <= 0:

@@ -28,6 +28,7 @@ import time
 from typing import Optional
 
 from namazu_tpu import obs
+from namazu_tpu.models import SEARCH_DEFAULTS
 from namazu_tpu.policy.base import QueueBackedPolicy, register_policy
 from namazu_tpu.policy.edge_table import TablePublisher
 from namazu_tpu.policy.replayable import (
@@ -45,6 +46,20 @@ from namazu_tpu.utils.log import get_logger
 log = get_logger("policy.tpu")
 
 
+#: ``tpu_search`` keys that went with the code they selected, and what a
+#: config that still sets one gets instead (``load_config`` says so once
+#: per key: a removed key must not fail silently)
+REMOVED_KEYS = {
+    "fused": "the search always runs the fused generation loop "
+             "(fused_chunk = 1 is one dispatch per generation)",
+    "migrate_every": "the island ring migrates every generation",
+    "dcn_migrate_every": "there is no cross-host ring; the island ring "
+                         "migrates every generation",
+    "dcn_hosts": "the search runs on a flat mesh over this process's "
+                 "chips (devices = N takes the first N)",
+}
+
+
 def _device_str(device) -> str:
     """``parallel.mesh.device_summary()`` (or a sidecar reply's
     ``device`` field) as the log's "which device searched" suffix."""
@@ -58,34 +73,23 @@ class TPUSearchPolicy(QueueBackedPolicy):
 
     def __init__(self) -> None:
         super().__init__()
-        self.seed = 0
-        self.max_interval = 0.1
+        # the search knobs start from the one table of their defaults
+        # (namazu_tpu/models/__init__.py, where each is described);
+        # _search_params() states them back under the table's names
+        d = SEARCH_DEFAULTS
+        self.seed = d["seed"]
+        self.max_interval = d["max_interval"]
         self.generations = 64
-        self.population = 4096
-        self.H = 256
-        self.L = 0  # trace-length cap; 0 = encode full traces (no drop)
-        self.K = 256
-        self.migrate_k = 8
-        # fused search loop (doc/performance.md "Fused search loop"):
-        # the whole generation loop runs device-side in fused_chunk-
-        # generation scans with donated buffers and device-resident
-        # traces/archives — bit-exact with the per-generation path
-        # (pinned by test), so the knob is a dispatch-shape choice, not
-        # a semantics one. fused = false restores the pre-fusion loop.
-        self.fused = True
-        self.fused_chunk = 16
-        # migration cadence, decoupled from the generation count: the
-        # intra-host ICI ring permutes every migrate_every generations;
-        # on a hybrid host x chip mesh (dcn_hosts > 1) the cross-host
-        # ring only every dcn_migrate_every. Both default 1 — the
-        # pre-cadence behavior bit-for-bit, and the same default the
-        # sidecar's params builder uses — so an upgrade never silently
-        # changes a multi-host search; set dcn_migrate_every = 4 on a
-        # DCN mesh to keep the slow fabric off the critical path
-        # (parallel/distributed.py hier_rings, doc/performance.md)
-        self.migrate_every = 1
-        self.dcn_migrate_every = 1
-        self.n_devices: Optional[int] = None
+        self.population = d["population"]
+        self.H = d["H"]
+        self.L = d["L"]
+        self.K = d["K"]
+        self.migrate_k = d["migrate_k"]
+        # generations per dispatch of the island step (doc/performance.md
+        # "Fused search loop"): a dispatch-shape choice, results do not
+        # depend on it (pinned by test)
+        self.fused_chunk = d["fused_chunk"]
+        self.n_devices: Optional[int] = d["devices"]
         self.checkpoint_path = ""
         self.search_on_start = True
         self.search_join_timeout = 120.0  # shutdown waits this long
@@ -102,17 +106,16 @@ class TPUSearchPolicy(QueueBackedPolicy):
         # halves repros/hour. N>1 amortizes it: N-1 install-only runs,
         # then one evolution over the batch of new outcomes.
         self.search_every = 1
-        self.max_fault = 0.0
-        self.search_backend = "ga"  # "ga" (island GA) | "mcts" (config 5)
-        self.dcn_hosts = 0  # >1: hybrid host x chip mesh (multi-host DCN)
+        self.max_fault = d["max_fault"]
+        self.search_backend = d["search_backend"]
         # release modes (BASELINE config 3): "delay" replays the table as
         # literal per-hint delays; "reorder" treats it as per-hint
         # *priorities* — events buffered for reorder_window seconds are
         # released in priority order, a true permutation even when delays
         # could not invert the arrivals
-        self.release_mode = "delay"
-        self.reorder_window = 0.05
-        self.reorder_gap = 0.002
+        self.release_mode = d["release_mode"]
+        self.reorder_window = d["reorder_window"]
+        self.reorder_gap = d["reorder_gap"]
         # (prio, seq, t_arrive, event) under _pending_lock
         self._pending: list = []
         self._pending_lock = threading.Lock()
@@ -131,11 +134,11 @@ class TPUSearchPolicy(QueueBackedPolicy):
         # invariant is testable with a scripted clock and zero real
         # sleeps instead of margin-widened wall-clock waits
         self._now = time.monotonic
-        self.mcts_simulations = 256
-        self.mcts_tree_depth = 24
-        self.mcts_levels = 8
-        self.mcts_rollouts = 64
-        self.surrogate_topk = 16  # 0 = fitness argmax only (no surrogate)
+        self.mcts_simulations = d["mcts_simulations"]
+        self.mcts_tree_depth = d["mcts_tree_depth"]
+        self.mcts_levels = d["mcts_levels"]
+        self.mcts_rollouts = d["mcts_rollouts"]
+        self.surrogate_topk = d["surrogate_topk"]
         # cross-batch failure-signature pool directory ("" = off); see
         # models/failure_pool.py. Relative paths anchor to the PARENT of
         # the storage dir (sibling experiments share one pool; anchoring
@@ -159,8 +162,8 @@ class TPUSearchPolicy(QueueBackedPolicy):
         # the failure archive holds this many DISTINCT signatures, then
         # scale novelty down as the archive grows (SearchConfig docs).
         # 0 = static weights (pre-anneal behavior).
-        self.min_failure_signatures = 0
-        self.novelty_floor = 0.25
+        self.min_failure_signatures = d["min_failure_signatures"]
+        self.novelty_floor = d["novelty_floor"]
         # causality guidance (doc/search.md): make relation coverage —
         # which happens-before orderings the campaign has exercised —
         # a search objective. Off by default, and active only while the
@@ -168,24 +171,24 @@ class TPUSearchPolicy(QueueBackedPolicy):
         # pre-guidance blind search — the guidance plane consumes
         # recorded structure, and with recording off it must cost and
         # change nothing).
-        self.guidance_enabled = False
-        self.guidance_bonus = 0.5
-        self.guidance_width = 0  # 0 = guidance.DEFAULT_WIDTH
-        self.guidance_window = 0  # 0 = guidance.DEFAULT_WINDOW
+        self.guidance_enabled = d["guidance"]
+        self.guidance_bonus = d["guidance_bonus"]
+        self.guidance_width = d["guidance_width"]
+        self.guidance_window = d["guidance_window"]
         # fitness weights (ops/schedule.py ScoreWeights). For pure
         # repro-rate maximization set w_novelty=0 so the search chases
         # the failure signature alone; the defaults balance exploration
         # (novel interleavings) against exploitation (bug affinity).
-        self.w_novelty = 1.0
-        self.w_bug = 1.0
-        self.w_delay_cost = 0.01
-        self.w_fault_cost = 0.05
+        self.w_novelty = d["w_novelty"]
+        self.w_bug = d["w_bug"]
+        self.w_delay_cost = d["w_delay_cost"]
+        self.w_fault_cost = d["w_fault_cost"]
         # precedence smoothing (seconds): the temporal resolution of the
         # feature embedding. Match it to the bug class's timing scale —
         # ms-level tau saturates on any ordering match, so the search
         # feels no pressure to reproduce the failure's timing MAGNITUDES
         # (a leader-election window is hundreds of ms, not an RTT)
-        self.tau = 0.005
+        self.tau = d["tau"]
         # counterfactual anchor: "recent" = most recent success traces
         # (multi-trace averaging, good for novelty search); "envelope" =
         # per-bucket min-arrival envelope over successes. Traces now
@@ -225,22 +228,22 @@ class TPUSearchPolicy(QueueBackedPolicy):
 
     def load_config(self, config) -> None:
         p = config.policy_param
-        self.seed = int(p("seed", 0))
+        self.seed = int(p("seed", self.seed))
         self._rng.seed(self.seed)
         self._fault_coin = None  # seed/H may change below
-        self.max_interval = parse_duration(p("max_interval", 100))
+        self.max_interval = parse_duration(
+            p("max_interval", self.max_interval * 1000))
         self.generations = int(p("generations", self.generations))
         self.population = int(p("population", self.population))
         self.H = int(p("hint_buckets", self.H))
         self.L = int(p("trace_length", self.L))
         self.K = int(p("feature_pairs", self.K))
         self.migrate_k = int(p("migrate_k", self.migrate_k))
-        self.fused = bool(p("fused", self.fused))
         self.fused_chunk = max(1, int(p("fused_chunk", self.fused_chunk)))
-        self.migrate_every = max(1, int(p("migrate_every",
-                                          self.migrate_every)))
-        self.dcn_migrate_every = max(1, int(p("dcn_migrate_every",
-                                              self.dcn_migrate_every)))
+        for key, instead in REMOVED_KEYS.items():
+            if p(key, None) is not None:
+                log.warning("tpu_search key %r was removed and is "
+                            "ignored: %s", key, instead)
         nd = p("devices", None)
         self.n_devices = int(nd) if nd is not None else None
         self.checkpoint_path = str(p("checkpoint", "") or "")
@@ -259,7 +262,7 @@ class TPUSearchPolicy(QueueBackedPolicy):
                 "reaches the next run through it); set checkpoint = "
                 "\"search.npz\""
             )
-        self.max_fault = float(p("max_fault", 0.0))
+        self.max_fault = float(p("max_fault", self.max_fault))
         self.search_backend = str(p("search_backend", self.search_backend))
         if self.search_backend not in ("ga", "mcts"):
             # fail fast: an exception inside the background search thread
@@ -297,7 +300,6 @@ class TPUSearchPolicy(QueueBackedPolicy):
                 "novelty anneal (min_failure_signatures=%d) applies to "
                 "the GA backend only; the mcts backend scores with "
                 "static weights", self.min_failure_signatures)
-        self.dcn_hosts = int(p("dcn_hosts", self.dcn_hosts))
         self.w_novelty = float(p("w_novelty", self.w_novelty))
         self.w_bug = float(p("w_bug", self.w_bug))
         self.w_delay_cost = float(p("w_delay_cost", self.w_delay_cost))
@@ -648,116 +650,9 @@ class TPUSearchPolicy(QueueBackedPolicy):
                                 boundary=anchor + k * w)
 
     def _build_search(self):
-        from namazu_tpu.models.ga import GAConfig
-        from namazu_tpu.models.search import (
-            MCTSSearch,
-            ScheduleSearch,
-            SearchConfig,
-            make_score_weights,
-        )
+        from namazu_tpu.models.search import build_search_from_params
 
-        # one home for the subtle mode-dependent weight construction,
-        # shared with the sidecar (models/search.py make_score_weights)
-        weights = make_score_weights(
-            release_mode=self.release_mode,
-            w_novelty=self.w_novelty, w_bug=self.w_bug,
-            w_delay_cost=self.w_delay_cost,
-            w_fault_cost=self.w_fault_cost, tau=self.tau,
-            reorder_gap=self.reorder_gap,
-            reorder_window=self.reorder_window,
-        )
-        cfg = SearchConfig(
-            H=self.H, L=self.L, K=self.K,
-            population=self.population,
-            migrate_k=self.migrate_k,
-            seed=self.seed,
-            ga=GAConfig(max_delay=self.max_interval,
-                        max_fault=self.max_fault),
-            weights=weights,
-            surrogate_topk=self.surrogate_topk,
-            min_failure_signatures=self.min_failure_signatures,
-            novelty_floor=self.novelty_floor,
-            guidance_bonus=self.guidance_bonus,
-            fused=self.fused,
-            fused_chunk=self.fused_chunk,
-            migrate_every=self.migrate_every,
-            dcn_migrate_every=self.dcn_migrate_every,
-        )
-        mesh = None
-        if self.dcn_hosts > 1:
-            # multi-host: join the jax.distributed ring (no-op when the
-            # NMZ_TPU_COORDINATOR env triple is absent, e.g. virtual-host
-            # dry runs) and shard over a hybrid host x chip mesh
-            from namazu_tpu.parallel.distributed import (
-                initialize_from_env,
-                make_hybrid_mesh,
-            )
-
-            import jax
-
-            initialize_from_env()
-            # honor the `devices` knob (same subset the flat path uses);
-            # in a multi-process run slice per process — a flat
-            # jax.devices()[:n] can take 4 chips from host 0 and 2 from
-            # host 1, which make_hybrid_mesh would (rightly) reject
-            devs = None
-            if self.n_devices is not None:
-                pc = jax.process_count()
-                if pc > 1:
-                    if self.n_devices % pc != 0:
-                        raise ValueError(
-                            f"devices={self.n_devices} must divide evenly "
-                            f"across {pc} processes"
-                        )
-                    per = self.n_devices // pc
-                    by_proc: dict = {}
-                    for d in sorted(jax.devices(),
-                                    key=lambda d: (d.process_index, d.id)):
-                        by_proc.setdefault(d.process_index, []).append(d)
-                    short = {p: len(ds) for p, ds in by_proc.items()
-                             if len(ds) < per}
-                    if short:
-                        raise ValueError(
-                            f"devices={self.n_devices} needs {per} chips "
-                            f"per process but some have fewer: {short}"
-                        )
-                    devs = [d for p in sorted(by_proc)
-                            for d in by_proc[p][:per]]
-                else:
-                    devs = jax.devices()[: self.n_devices]
-            mesh = make_hybrid_mesh(n_hosts=self.dcn_hosts, devices=devs)
-        if self.search_backend == "mcts":
-            if self.surrogate_topk > 0:
-                log.warning(
-                    "surrogate re-ranking (surrogate_topk=%d) applies to "
-                    "the GA backend only; the mcts backend returns its "
-                    "fitness argmax", self.surrogate_topk)
-            if self._guidance_active():
-                log.warning(
-                    "causality guidance (guidance=true) biases the GA "
-                    "backend's pick/mutation only; the mcts backend "
-                    "still feeds the coverage map and metrics")
-            from namazu_tpu.models.mcts import MCTSConfig
-
-            mcts_cfg = MCTSConfig(
-                tree_depth=self.mcts_tree_depth,
-                n_levels=self.mcts_levels,
-                simulations=self.mcts_simulations,
-                rollouts=self.mcts_rollouts,
-                max_delay=self.max_interval,
-                max_fault=self.max_fault,
-            )
-            search = MCTSSearch(cfg, mcts_cfg=mcts_cfg, mesh=mesh,
-                                n_devices=self.n_devices)
-        else:
-            search = ScheduleSearch(cfg, mesh=mesh,
-                                    n_devices=self.n_devices)
-        if self._guidance_active():
-            # wired BEFORE any checkpoint load/ingest so the archive's
-            # DAG-shape feature fragments stay slot-aligned
-            search.enable_guidance(self.guidance_width or None,
-                                   self.guidance_window or None)
-        return search
+        return build_search_from_params(self._search_params())
 
     def _guidance_active(self) -> bool:
         """Guidance runs only when asked for AND the obs plane is on:
@@ -931,16 +826,14 @@ class TPUSearchPolicy(QueueBackedPolicy):
         return failure_seed(trace, self.H, self.max_interval)
 
     def _search_params(self) -> dict:
-        """Flat JSON-able search knobs — what the sidecar needs to build
-        an equivalent backend (sidecar.build_search_from_params)."""
+        """Flat JSON-able search knobs, under ``SEARCH_DEFAULTS``' names
+        — what ``models.search.build_search_from_params`` builds the
+        backend from, here or in the sidecar."""
         return {
             "H": self.H, "L": self.L, "K": self.K,
             "population": self.population,
             "migrate_k": self.migrate_k,
-            "fused": self.fused,
             "fused_chunk": self.fused_chunk,
-            "migrate_every": self.migrate_every,
-            "dcn_migrate_every": self.dcn_migrate_every,
             "seed": self.seed,
             "max_interval": self.max_interval,
             "max_fault": self.max_fault,

@@ -64,68 +64,6 @@ from namazu_tpu.utils.log import get_logger
 log = get_logger("sidecar")
 
 
-def build_search_from_params(p: dict):
-    """Construct a search backend from a flat JSON-able params dict (the
-    policy's knobs, TPUSearchPolicy._search_params)."""
-    from namazu_tpu.models.ga import GAConfig
-    from namazu_tpu.models.search import (
-        MCTSSearch,
-        ScheduleSearch,
-        SearchConfig,
-        make_score_weights,
-    )
-
-    weights = make_score_weights(
-        release_mode=p.get("release_mode", "delay"),
-        w_novelty=p.get("w_novelty", 1.0),
-        w_bug=p.get("w_bug", 1.0),
-        w_delay_cost=p.get("w_delay_cost", 0.01),
-        w_fault_cost=p.get("w_fault_cost", 0.05),
-        tau=p.get("tau", 0.005),
-        reorder_gap=p.get("reorder_gap", 0.002),
-        reorder_window=p.get("reorder_window", 0.05),
-    )
-    cfg = SearchConfig(
-        H=p.get("H", 256), L=p.get("L", 0), K=p.get("K", 256),
-        population=p.get("population", 4096),
-        migrate_k=p.get("migrate_k", 8),
-        seed=p.get("seed", 0),
-        ga=GAConfig(max_delay=p.get("max_interval", 0.1),
-                    max_fault=p.get("max_fault", 0.0)),
-        weights=weights,
-        surrogate_topk=p.get("surrogate_topk", 16),
-        min_failure_signatures=p.get("min_failure_signatures", 0),
-        novelty_floor=p.get("novelty_floor", 0.25),
-        guidance_bonus=p.get("guidance_bonus", 0.5),
-        fused=bool(p.get("fused", True)),
-        fused_chunk=int(p.get("fused_chunk", 16)),
-        migrate_every=int(p.get("migrate_every", 1)),
-        dcn_migrate_every=int(p.get("dcn_migrate_every", 1)),
-    )
-    n_devices = p.get("devices")
-    if p.get("search_backend", "ga") == "mcts":
-        from namazu_tpu.models.mcts import MCTSConfig
-
-        mcts_cfg = MCTSConfig(
-            tree_depth=p.get("mcts_tree_depth", 24),
-            n_levels=p.get("mcts_levels", 8),
-            simulations=p.get("mcts_simulations", 256),
-            rollouts=p.get("mcts_rollouts", 64),
-            max_delay=p.get("max_interval", 0.1),
-            max_fault=p.get("max_fault", 0.0),
-        )
-        search = MCTSSearch(cfg, mcts_cfg=mcts_cfg, n_devices=n_devices)
-    else:
-        search = ScheduleSearch(cfg, n_devices=n_devices)
-    if p.get("guidance"):
-        # wired before any checkpoint load (SearchService._get_search)
-        # so archive rows and DAG-shape fragments stay slot-aligned —
-        # same ordering contract as policy/tpu.py _build_search
-        search.enable_guidance(p.get("guidance_width") or None,
-                               p.get("guidance_window") or None)
-    return search
-
-
 class DeviceTraceCapture:
     """The device trace on demand: one ``jax.profiler`` capture at a
     time, started by the ``device_trace`` op and stopped by a timer, so
@@ -243,6 +181,8 @@ class SearchService:
         # seconds and must not block ping or other keys' requests — the
         # caller already holds this key's lock, which serializes
         # same-key requests (ADVICE r4)
+        from namazu_tpu.models.search import build_search_from_params
+
         search = build_search_from_params(params)
         if self._device is None:
             from namazu_tpu.parallel.mesh import device_summary

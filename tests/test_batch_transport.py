@@ -20,7 +20,7 @@ from namazu_tpu.endpoint.hub import EndpointHub
 from namazu_tpu.endpoint.local import LocalEndpoint
 from namazu_tpu.endpoint.rest import ActionQueue, RestEndpoint
 from namazu_tpu.inspector.rest_transceiver import RestTransceiver
-from namazu_tpu.obs import metrics, recorder
+from namazu_tpu.obs import federation, metrics, recorder
 from namazu_tpu.obs.metrics import MetricsRegistry
 from namazu_tpu.orchestrator import Orchestrator
 from namazu_tpu.policy import create_policy
@@ -36,6 +36,9 @@ def fresh_obs():
     metrics.configure(True)
     old_rec = recorder.set_recorder(recorder.FlightRecorder())
     yield
+    # an Orchestrator.start() wires the process's self-relay and
+    # aggregator (obs/federation.py) and its shutdown leaves them
+    federation.reset()
     metrics.set_registry(old_reg)
     metrics.configure(True)
     recorder.set_recorder(old_rec)

@@ -64,10 +64,9 @@ def test_compile_cache_defaults_into_the_checkout(monkeypatch,
 # -- bench.py: no CPU number under a device metric's name -------------------
 
 
-@pytest.mark.parametrize("mode", [[], ["--fused"]])
-def test_bench_device_modes_refuse_a_non_tpu_backend(mode):
+def test_bench_device_mode_refuses_a_non_tpu_backend():
     proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py"), *mode],
+        [sys.executable, os.path.join(ROOT, "bench.py")],
         env=_child_env(), capture_output=True, text=True, timeout=120)
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""  # no JSON line, no number
@@ -84,7 +83,7 @@ profiling.ensure_profiler("test", interval_s=0.001)
 
 def work():
     from namazu_tpu.ops import trace_encoding as te
-    from namazu_tpu.sidecar import build_search_from_params
+    from namazu_tpu.models.search import build_search_from_params
     search = build_search_from_params({
         "H": 32, "K": 32, "population": 64, "migrate_k": 2,
         "surrogate_topk": 0, "fused_chunk": 2})

@@ -15,7 +15,7 @@ import pytest
 from namazu_tpu import chaos
 from namazu_tpu.chaos import FaultPlan
 from namazu_tpu.chaos.journal import EventJournal
-from namazu_tpu.obs import metrics
+from namazu_tpu.obs import federation, metrics
 from namazu_tpu.obs.metrics import MetricsRegistry
 from namazu_tpu.signal import PacketEvent
 from namazu_tpu.utils import atomic, retry
@@ -24,12 +24,16 @@ from namazu_tpu.utils.sched_queue import ScheduledQueue
 
 @pytest.fixture(autouse=True)
 def fresh_state():
-    """Isolated metrics + NO leftover fault plan, whatever a test did."""
+    """Isolated metrics + NO leftover fault plan, whatever a test did;
+    the telemetry wiring an orchestrator or harness scenario started
+    (self-relay, aggregator: the process's, obs/federation.py) stops
+    with the test that started it."""
     old = metrics.set_registry(MetricsRegistry())
     metrics.configure(True)
     chaos.clear()
     yield
     chaos.clear()
+    federation.reset()
     metrics.set_registry(old)
     metrics.configure(True)
 

@@ -5,6 +5,7 @@ journal recovery, pool-level admission control, and pool-state fsck.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
@@ -23,7 +24,7 @@ from namazu_tpu.fleet.service import (
     MANIFEST_SCHEMA,
     PlacementService,
 )
-from namazu_tpu.obs import metrics, recorder as recorder_mod
+from namazu_tpu.obs import federation, metrics, recorder as recorder_mod
 from namazu_tpu.obs.recorder import FlightRecorder
 from namazu_tpu.policy import create_policy
 from namazu_tpu.signal import PacketEvent
@@ -31,14 +32,31 @@ from namazu_tpu.tenancy.host import TenantOrchestrator
 from namazu_tpu.utils.config import Config
 
 
-@pytest.fixture(autouse=True)
-def fresh_obs():
+@contextlib.contextmanager
+def _fresh_obs():
+    """A registry, a recorder AND federation wiring of this test's own.
+    The wiring (aggregator, self-relay, collectors) is the process's:
+    every ``Orchestrator.start()`` wires a relay that outlives it, and
+    a fleet host serves its aggregator's SLO burn on ``/fleet`` — an
+    earlier file's burn >= 1.0 makes every host here ineligible
+    (fleet/placement.py), which is what kept tier-1 red from PR 23 to
+    PR 27."""
+    federation.reset()
     old_reg = metrics.set_registry(metrics.MetricsRegistry())
     metrics.configure(True)
     old_rec = recorder_mod.set_recorder(FlightRecorder(max_runs=32))
-    yield
-    metrics.set_registry(old_reg)
-    recorder_mod.set_recorder(old_rec)
+    try:
+        yield
+    finally:
+        metrics.set_registry(old_reg)
+        recorder_mod.set_recorder(old_rec)
+        federation.reset()  # the relays this test's hosts wired
+
+
+@pytest.fixture(autouse=True)
+def fresh_obs():
+    with _fresh_obs():
+        yield
 
 
 def _policy_param(seed=7, interval="0ms"):
@@ -222,6 +240,47 @@ def test_drain_migrates_leases_exactly_once(tmp_path):
         svc.shutdown()
         for h in hosts:
             h.shutdown()
+
+
+# -- the process's telemetry wiring --------------------------------------
+
+
+def test_a_lease_is_granted_after_an_earlier_files_slo_burn(tmp_path):
+    """The wiring an earlier file on this xdist worker leaves behind —
+    an aggregator whose ``dispatch_p99`` burns because a chaos scenario
+    held events past a second — must not reach this file's hosts: they
+    would serve that burn on ``/fleet`` and refuse every lease."""
+    def e2e(seq, counts):
+        return {"schema": federation.SCHEMA, "job": "orchestrator",
+                "instance": "earlier-file", "seq": seq, "families": [
+                    {"name": "nmz_event_e2e_seconds",
+                     "type": "histogram", "labelnames": [],
+                     "uppers": [0.1, 1.0, 10.0],
+                     "samples": [{"labels": {}, "counts": counts,
+                                  "sum": 40.0, "count": sum(counts)}]}]}
+
+    dirty = federation.FleetAggregator()
+    dirty.note_push(e2e(1, [1, 0, 0, 0]))
+    dirty.note_push(e2e(2, [1, 0, 8, 0]))  # 8 dispatches took > 1 s
+    federation.set_aggregator(dirty)
+    left_behind = placement.summarize_fleet_doc(federation.aggregator()
+                                                .payload())
+    assert left_behind["max_burn"] >= 1.0  # no host would be eligible
+    with _fresh_obs():
+        hosts = [_host(tmp_path, "clean-host0")]
+        svc = _service(tmp_path, hosts)
+        try:
+            lease = svc.handle_wire({
+                "op": "lease", "run": "clean-a", "ttl_s": 600.0,
+                "policy": "random", "policy_param": _policy_param()})
+            assert lease["ok"], lease
+            svc.handle_wire({"op": "release",
+                             "lease_id": lease["lease_id"],
+                             "trace": False})
+        finally:
+            svc.shutdown()
+            for h in hosts:
+                h.shutdown()
 
 
 # -- admission control ---------------------------------------------------

@@ -2,22 +2,22 @@
 
 The contracts this file pins:
 
-* bit-exactness — N fused (lax.scan'd, donated) generations produce the
-  SAME populations/fitness/best tables as N per-generation steps from
-  the same state, because both fold the PRNG key as
-  ``fold_in(base_key, gen)`` (the same-draw-order rule
-  ``ScheduledQueue.put_many`` documents for the control plane);
+* chunk independence — G generations in ONE fused (lax.scan'd, donated)
+  dispatch produce the SAME populations/fitness/best tables/history as
+  G dispatches of one generation from the same state, because the PRNG
+  key of a generation is ``fold_in(base_key, gen)`` whatever the chunk
+  length (the same-draw-order rule ``ScheduledQueue.put_many``
+  documents for the control plane); end to end, two searches that
+  differ only in ``fused_chunk`` agree to the bit;
 * no mid-run recompiles — fixed-capacity archive buffers with traced
   occupancy scalars hit ONE compiled scorer for every occupancy, and
   the surrogate's padded minibatches hit one compiled train step;
 * device-resident ingest — re-running against an overlapping reference
   window appends only the new trace rows (dynamic_update_slice) instead
   of re-staging the stack;
-* checkpoint compatibility — pre-fusion (per-generation) checkpoints
-  load into the fused loop and vice versa; a population-shape mismatch
+* checkpoint compatibility — a checkpoint written at one chunk length
+  continues at another to the same result; a population-shape mismatch
   retrains instead of crashing (the PR 11 width rule extended);
-* migration cadence — a ring's ppermute only runs on generations where
-  ``gen % every == 0``;
 * observability — the fused run publishes the host_io phase span, the
   fused-labeled scorer gauge, and a generation record whose host_io_s
   feeds the analytics host-gap share.
@@ -43,9 +43,8 @@ from namazu_tpu.ops.schedule import (
 from namazu_tpu.parallel.islands import (
     init_island_state,
     make_fused_island_step,
-    make_multiaxis_island_step,
 )
-from namazu_tpu.parallel.mesh import make_mesh, make_topology_mesh
+from namazu_tpu.parallel.mesh import make_mesh
 
 H, L, K = 32, 64, 32
 
@@ -89,33 +88,36 @@ def enc_of(n, seed):
 # -- bit-exactness ----------------------------------------------------------
 
 
-@pytest.mark.parametrize("gens", [1, 5])
-def test_fused_scan_bit_exact_vs_per_generation_steps(gens):
+@pytest.mark.parametrize("gens", [2, 5])
+def test_fused_scan_bit_exact_vs_one_generation_per_call(gens):
     mesh = make_mesh(8)
     cfg = GAConfig(max_delay=0.05)
     trace, pairs, archive, failures = inputs()
     key = jax.random.PRNGKey(1)
 
-    step = make_multiaxis_island_step(mesh, cfg, ScoreWeights(),
-                                      rings=(("i", 2),))
-    s_un = init_island_state(jax.random.PRNGKey(0), 64, H, cfg)
+    step = make_fused_island_step(mesh, cfg, ScoreWeights(), migrate_k=2,
+                                  generations=1)
+    s_one = init_island_state(jax.random.PRNGKey(0), 64, H, cfg)
+    hist_one = []
     for _ in range(gens):
-        s_un = step(s_un, key, trace, pairs, archive, failures)
+        s_one, h = step(s_one, key, trace, pairs, archive, failures)
+        hist_one.append(np.asarray(h))
 
-    fused = make_fused_island_step(mesh, cfg, ScoreWeights(),
-                                   rings=(("i", 2),), generations=gens)
+    fused = make_fused_island_step(mesh, cfg, ScoreWeights(), migrate_k=2,
+                                   generations=gens)
     s_fu, hist = fused(init_island_state(jax.random.PRNGKey(0), 64, H, cfg),
                        key, trace, pairs, archive, failures)
 
     assert int(s_fu.gen) == gens
     assert hist.shape == (gens,)
-    assert np.array_equal(np.asarray(s_un.pop.delays),
+    assert np.array_equal(np.concatenate(hist_one), np.asarray(hist))
+    assert np.array_equal(np.asarray(s_one.pop.delays),
                           np.asarray(s_fu.pop.delays))
-    assert np.array_equal(np.asarray(s_un.pop.faults),
+    assert np.array_equal(np.asarray(s_one.pop.faults),
                           np.asarray(s_fu.pop.faults))
-    assert np.array_equal(np.asarray(s_un.best_fitness),
+    assert np.array_equal(np.asarray(s_one.best_fitness),
                           np.asarray(s_fu.best_fitness))
-    assert np.array_equal(np.asarray(s_un.best_delays),
+    assert np.array_equal(np.asarray(s_one.best_delays),
                           np.asarray(s_fu.best_delays))
     # the history's last entry is that generation's global best, and the
     # carried best is the running max of the history (monotone contract)
@@ -128,7 +130,7 @@ def test_fused_state_is_donated():
     cfg = GAConfig(max_delay=0.05)
     trace, pairs, archive, failures = inputs()
     fused = make_fused_island_step(mesh, cfg, ScoreWeights(),
-                                   rings=(("i", 2),), generations=2)
+                                   migrate_k=2, generations=2)
     state = init_island_state(jax.random.PRNGKey(0), 64, H, cfg)
     state, _ = fused(state, jax.random.PRNGKey(1), trace, pairs,
                      archive, failures)
@@ -145,60 +147,21 @@ def test_fused_state_is_donated():
     assert not new_state.pop.delays.is_deleted()
 
 
-# -- migration cadence ------------------------------------------------------
+# -- the migration ring -----------------------------------------------------
 
 
-def test_migration_cadence_skips_off_generations():
-    """A ring with every=2 migrates on gen 0, skips gen 1: after two
-    steps the population matches a manual replay that applies the
-    migration landing only on the even generation."""
+def test_migration_k_clamped_to_island_population():
+    """migrate_k larger than the per-island population must clamp, not
+    crash (regression: top_k(k=10) on an 8-row island)."""
     mesh = make_mesh(8)
     cfg = GAConfig(max_delay=0.05)
+    step = make_fused_island_step(mesh, cfg, ScoreWeights(), migrate_k=10,
+                                  generations=1)
     trace, pairs, archive, failures = inputs()
-    key = jax.random.PRNGKey(1)
-
-    every2 = make_multiaxis_island_step(mesh, cfg, ScoreWeights(),
-                                        rings=(("i", 2, 2),))
-    always = make_multiaxis_island_step(mesh, cfg, ScoreWeights(),
-                                        rings=(("i", 2),))
-
-    s_a = init_island_state(jax.random.PRNGKey(0), 64, H, cfg)
-    s_b = init_island_state(jax.random.PRNGKey(0), 64, H, cfg)
-    # gen 0: 0 % 2 == 0 -> both migrate identically
-    s_a = every2(s_a, key, trace, pairs, archive, failures)
-    s_b = always(s_b, key, trace, pairs, archive, failures)
-    assert np.array_equal(np.asarray(s_a.pop.delays),
-                          np.asarray(s_b.pop.delays))
-    # gen 1: cadence skips, always-ring migrates -> tails diverge
-    s_a = every2(s_a, key, trace, pairs, archive, failures)
-    s_b = always(s_b, key, trace, pairs, archive, failures)
-    assert not np.array_equal(np.asarray(s_a.pop.delays),
-                              np.asarray(s_b.pop.delays))
-    # ... and ONLY the migration landing region differs: the leading
-    # rows (elites + offspring) of every island shard are identical
-    per_island = 64 // 8
-    a = np.asarray(s_a.pop.delays).reshape(8, per_island, H)
-    b = np.asarray(s_b.pop.delays).reshape(8, per_island, H)
-    assert np.array_equal(a[:, : per_island - 2], b[:, : per_island - 2])
-
-
-def test_fused_and_stepwise_agree_under_cadence():
-    mesh = make_mesh(8)
-    cfg = GAConfig(max_delay=0.05)
-    trace, pairs, archive, failures = inputs()
-    key = jax.random.PRNGKey(2)
-    rings = (("i", 2, 2),)
-    step = make_multiaxis_island_step(mesh, cfg, ScoreWeights(),
-                                      rings=rings)
-    s_un = init_island_state(jax.random.PRNGKey(0), 64, H, cfg)
-    for _ in range(4):
-        s_un = step(s_un, key, trace, pairs, archive, failures)
-    fused = make_fused_island_step(mesh, cfg, ScoreWeights(), rings=rings,
-                                   generations=4)
-    s_fu, _ = fused(init_island_state(jax.random.PRNGKey(0), 64, H, cfg),
-                    key, trace, pairs, archive, failures)
-    assert np.array_equal(np.asarray(s_un.pop.delays),
-                          np.asarray(s_fu.pop.delays))
+    state = init_island_state(jax.random.PRNGKey(0), 64, H, cfg)  # 8/island
+    state, _ = step(state, jax.random.PRNGKey(1), trace, pairs, archive,
+                    failures)
+    assert np.isfinite(float(state.best_fitness))
 
 
 # -- no mid-run recompiles --------------------------------------------------
@@ -294,9 +257,9 @@ def test_surrogate_train_compiles_once_across_occupancy():
 # -- device-resident end-to-end --------------------------------------------
 
 
-def test_schedule_search_fused_bit_exact_with_stepwise_across_runs():
-    a = ScheduleSearch(search_cfg(fused=False))
-    b = ScheduleSearch(search_cfg(fused=True, fused_chunk=7))
+def test_schedule_search_bit_exact_across_chunk_lengths_and_runs():
+    a = ScheduleSearch(search_cfg(fused_chunk=1))
+    b = ScheduleSearch(search_cfg(fused_chunk=16))
     refs = [enc_of(40, 1), enc_of(48, 2)]
     for s in (a, b):
         s.add_executed_trace(enc_of(40, 5))
@@ -351,33 +314,35 @@ def test_resident_store_evicts_stale_rows_and_rebuilds_on_growth():
 # -- checkpoint compatibility ----------------------------------------------
 
 
-def test_checkpoint_round_trips_between_fused_and_stepwise(tmp_path):
+def test_checkpoint_round_trips_between_chunk_lengths(tmp_path):
     ck = str(tmp_path / "search.npz")
-    pre = ScheduleSearch(search_cfg(fused=False))
+    pre = ScheduleSearch(search_cfg(fused_chunk=1))
     pre.add_executed_trace(enc_of(40, 5))
     pre.add_failure_trace(enc_of(44, 6))
     pre.run([enc_of(40, 1)], generations=5)
     pre.save(ck)
 
-    # pre-fusion checkpoint -> device-resident loop
-    fused = ScheduleSearch(search_cfg(fused=True, fused_chunk=4))
+    # a checkpoint written a generation a dispatch -> chunks of 16
+    fused = ScheduleSearch(search_cfg(fused_chunk=16))
     fused.load(ck)
     assert fused.generations_run == pre.generations_run
     r_f = fused.run([enc_of(40, 1)], generations=6)
 
-    # the same continuation on the stepwise loop is bit-identical
-    cont = ScheduleSearch(search_cfg(fused=False))
+    # the same continuation a generation a dispatch is bit-identical
+    cont = ScheduleSearch(search_cfg(fused_chunk=1))
     cont.load(ck)
     r_s = cont.run([enc_of(40, 1)], generations=6)
     assert np.array_equal(r_f.delays, r_s.delays)
     assert r_f.fitness == r_s.fitness
 
-    # ... and a fused-written checkpoint loads back into the stepwise
+    # ... and a checkpoint written in chunks loads back the other way
     ck2 = str(tmp_path / "search2.npz")
     fused.save(ck2)
-    back = ScheduleSearch(search_cfg(fused=False))
+    back = ScheduleSearch(search_cfg(fused_chunk=1))
     back.load(ck2)
     assert back.generations_run == fused.generations_run
+    assert np.array_equal(np.asarray(back._state.pop.delays),
+                          np.asarray(fused._state.pop.delays))
 
 
 def test_checkpoint_population_mismatch_keeps_fresh_population(tmp_path):
@@ -405,7 +370,7 @@ def test_failed_fused_dispatch_does_not_brick_the_search(monkeypatch):
     sidecar contract): population restarts, best-so-far restores from
     the last completed round's host snapshot, and the next run()
     succeeds."""
-    s = ScheduleSearch(search_cfg(fused=True, fused_chunk=4))
+    s = ScheduleSearch(search_cfg(fused_chunk=4))
     s.add_failure_trace(enc_of(44, 6))
     r1 = s.run([enc_of(40, 1)], generations=4)
     assert np.isfinite(r1.fitness)
@@ -434,7 +399,7 @@ def test_host_lane_gauge_never_regresses_best(tmp_path):
     metrics.configure(True)
     metrics.reset()
     try:
-        s = ScheduleSearch(search_cfg(fused=True, fused_chunk=2))
+        s = ScheduleSearch(search_cfg(fused_chunk=2))
         s.add_failure_trace(enc_of(44, 6))
         best_seen = -np.inf
         for seed in (1, 2, 3):
@@ -453,47 +418,6 @@ def test_host_lane_gauge_never_regresses_best(tmp_path):
         metrics.configure(False)
 
 
-# -- topology-aware meshes --------------------------------------------------
-
-
-def test_topology_mesh_groups_hosts():
-    mesh = make_topology_mesh(8, host_size=4)
-    assert mesh.shape == {"h": 2, "i": 4}
-    flat = make_topology_mesh(4, host_size=4)  # one host's worth: flat
-    assert tuple(flat.axis_names) == ("i",)
-    with pytest.raises(ValueError):
-        make_topology_mesh(6, host_size=4)
-
-
-def test_fused_step_on_topology_mesh_with_dcn_cadence():
-    from namazu_tpu.parallel.distributed import hier_rings
-
-    mesh = make_topology_mesh(8, host_size=4)
-    cfg = GAConfig(max_delay=0.05)
-    trace, pairs, archive, failures = inputs()
-    fused = make_fused_island_step(
-        mesh, cfg, ScoreWeights(),
-        rings=hier_rings(migrate_k=2, dcn_migrate_k=1, dcn_every=4),
-        generations=5)
-    state = init_island_state(jax.random.PRNGKey(0), 64, H, cfg)
-    state, hist = fused(state, jax.random.PRNGKey(1), trace, pairs,
-                        archive, failures)
-    assert int(state.gen) == 5
-    assert np.all(np.isfinite(np.asarray(hist)))
-
-
-def test_hybrid_mesh_search_runs_fused(tmp_path):
-    from namazu_tpu.parallel.distributed import make_hybrid_mesh
-
-    mesh = make_hybrid_mesh(n_hosts=2)
-    s = ScheduleSearch(search_cfg(fused=True, fused_chunk=3,
-                                  dcn_migrate_every=2), mesh=mesh)
-    s.add_failure_trace(enc_of(44, 6))
-    r = s.run([enc_of(40, 1)], generations=7)
-    assert np.isfinite(r.fitness)
-    assert s._rings[1][2] == 2  # DCN ring carries its own cadence
-
-
 # -- observability ----------------------------------------------------------
 
 
@@ -507,7 +431,7 @@ def test_fused_run_publishes_host_io_span_and_fused_source(tmp_path):
     rec = recorder()
     rec.begin_run("fused-test")
     try:
-        s = ScheduleSearch(search_cfg(fused=True, fused_chunk=4))
+        s = ScheduleSearch(search_cfg(fused_chunk=4))
         s.add_failure_trace(enc_of(44, 6))
         s.run([enc_of(40, 1)], generations=9)
         reg = metrics.registry()

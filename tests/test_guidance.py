@@ -275,25 +275,28 @@ def test_island_step_threads_mutation_bias():
     from namazu_tpu.ops.schedule import ScoreWeights, TraceArrays
     from namazu_tpu.parallel.islands import (
         init_island_state,
-        make_island_step,
+        make_fused_island_step,
     )
     from namazu_tpu.parallel.mesh import make_mesh
 
     cfg = GAConfig()
-    step = make_island_step(make_mesh(1), cfg, ScoreWeights(),
-                            migrate_k=2)
-    state = init_island_state(jax.random.PRNGKey(2), 8, 8, cfg)
+    fused = make_fused_island_step(make_mesh(1), cfg, ScoreWeights(),
+                                   migrate_k=2, generations=1)
+
+    def step(*args):
+        # the step donates its state: a fresh one (same key) per call
+        state = init_island_state(jax.random.PRNGKey(2), 8, 8, cfg)
+        return fused(state, *args)[0]
+
     trace = TraceArrays(jnp.zeros((4,), jnp.int32), jnp.arange(4.0),
                         jnp.ones((4,), bool))
     args = (jax.random.PRNGKey(0), trace, jnp.zeros((4, 2), jnp.int32),
             jnp.full((4, 4), 0.5), jnp.full((4, 4), 0.5))
-    s_none = step(state, args[0], *args[1:])
-    s_ones = step(state, args[0], *args[1:], None, None,
-                  jnp.ones((8,)))
+    s_none = step(*args)
+    s_ones = step(*args, None, None, jnp.ones((8,)))
     assert np.array_equal(np.asarray(s_none.pop.delays),
                           np.asarray(s_ones.pop.delays))
-    s_hot = step(state, args[0], *args[1:], None, None,
-                 jnp.full((8,), 4.0))
+    s_hot = step(*args, None, None, jnp.full((8,), 4.0))
     assert not np.array_equal(np.asarray(s_none.pop.delays),
                               np.asarray(s_hot.pop.delays))
 
@@ -489,7 +492,7 @@ def test_policy_guidance_default_off(tmp_path):
 
 
 def test_sidecar_builder_wires_guidance():
-    from namazu_tpu.sidecar import build_search_from_params
+    from namazu_tpu.models.search import build_search_from_params
 
     base = {"H": H, "K": K, "population": 16, "seed": 1}
     s = build_search_from_params(dict(base, guidance=True,

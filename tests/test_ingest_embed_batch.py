@@ -494,13 +494,14 @@ def test_ingest_counts_events_and_length_groups(short, long, groups,
 @pytest.mark.parametrize("n_events, scorer", [
     (17, "dense"), (sch.LONG_TRACE_THRESHOLD, "dense"),
     (sch.LONG_TRACE_THRESHOLD + 1, "blockwise")])
-@pytest.mark.parametrize("fused", [True, False])
+@pytest.mark.parametrize("fused_chunk", [16, 1])
 def test_an_evolve_is_counted_under_the_branch_its_step_compiled(
-        n_events, scorer, fused, fresh_obs):
-    """``nmz_evolve_requests_total{scorer}``: one per completed evolve,
-    by ``scorer_branch`` of the references' padded length — the rule
-    ``_genome_features`` itself dispatches on."""
-    s = ScheduleSearch(cfg(fused=fused), n_devices=1)
+        n_events, scorer, fused_chunk, fresh_obs):
+    """``nmz_evolve_requests_total{scorer}``: one per completed evolve
+    — however many dispatches it took — by ``scorer_branch`` of the
+    references' padded length, the rule ``_genome_features`` itself
+    dispatches on."""
+    s = ScheduleSearch(cfg(fused_chunk=fused_chunk), n_devices=1)
     refs = [enc_of(n_events, 1), enc_of(17, 2)]
     L = max(e.hint_ids.shape[0] for e in refs)
     assert sch.scorer_branch(L) == scorer

@@ -18,7 +18,10 @@ from namazu_tpu.ops.schedule import (
     score_population,
     score_population_multi,
 )
-from namazu_tpu.parallel.islands import init_island_state, make_island_step
+from namazu_tpu.parallel.islands import (
+    init_island_state,
+    make_fused_island_step,
+)
 from namazu_tpu.parallel.mesh import make_mesh
 
 H, L, K = 32, 64, 64
@@ -88,7 +91,8 @@ def test_long_trace_features_match_dense_and_scale():
 def test_island_step_accepts_trace_batch():
     mesh = make_mesh(8)
     cfg = GAConfig(max_delay=0.05)
-    step = make_island_step(mesh, cfg, ScoreWeights(), migrate_k=2)
+    step = make_fused_island_step(mesh, cfg, ScoreWeights(), migrate_k=2,
+                                  generations=1)
     t1 = enc([f"a{i % 7}" for i in range(40)])
     t2 = enc([f"b{i % 5}" for i in range(30)])
     h, _, a, m, _fb = te.stack_traces([t1, t2])
@@ -97,7 +101,8 @@ def test_island_step_accepts_trace_batch():
     archive = jnp.full((8, K), 0.5)
     fails = jnp.full((2, K), 0.5)
     state = init_island_state(jax.random.PRNGKey(0), 256, H, cfg)
-    state = step(state, jax.random.PRNGKey(1), batch, pairs, archive, fails)
+    state, _ = step(state, jax.random.PRNGKey(1), batch, pairs, archive,
+                    fails)
     assert int(state.gen) == 1
     assert np.isfinite(float(state.best_fitness))
 

@@ -299,7 +299,7 @@ def test_chrome_trace_takes_span_rows(searched):
 
 def test_a_recompile_inside_evolve_is_counted_there(fresh_obs, tmp_path):
     from namazu_tpu.models.ingest import IngestParams, ingest_history
-    from namazu_tpu.sidecar import build_search_from_params
+    from namazu_tpu.models.search import build_search_from_params
 
     st = make_history(tmp_path / "st")
     search = build_search_from_params(SEARCH_PARAMS)

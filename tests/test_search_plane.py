@@ -25,7 +25,10 @@ from namazu_tpu.ops.schedule import (
     score_population,
     trace_features,
 )
-from namazu_tpu.parallel.islands import init_island_state, make_island_step
+from namazu_tpu.parallel.islands import (
+    init_island_state,
+    make_fused_island_step,
+)
 from namazu_tpu.parallel.mesh import make_mesh
 
 H, L, K = 32, 64, 64
@@ -163,12 +166,13 @@ def test_island_step_on_8_device_mesh():
     fails = jnp.full((4, K), 0.5)
     mesh = make_mesh(8)
     cfg = GAConfig(max_delay=0.05)
-    step = make_island_step(mesh, cfg, ScoreWeights(), migrate_k=4)
+    step = make_fused_island_step(mesh, cfg, ScoreWeights(), migrate_k=4,
+                                  generations=1)
     state = init_island_state(jax.random.PRNGKey(0), 512, H, cfg)
     key = jax.random.PRNGKey(3)
     f0 = None
     for _ in range(8):
-        state = step(state, key, trace, pairs, archive, fails)
+        state, _ = step(state, key, trace, pairs, archive, fails)
         if f0 is None:
             f0 = float(state.best_fitness)
     assert int(state.gen) == 8
@@ -188,11 +192,12 @@ def test_island_determinism_same_seed():
     cfg = GAConfig(max_delay=0.05)
 
     def run():
-        step = make_island_step(mesh, cfg, ScoreWeights(), migrate_k=2)
+        step = make_fused_island_step(mesh, cfg, ScoreWeights(),
+                                      migrate_k=2, generations=1)
         state = init_island_state(jax.random.PRNGKey(5), 256, H, cfg)
         for _ in range(4):
-            state = step(state, jax.random.PRNGKey(6), trace, pairs,
-                         archive, fails)
+            state, _ = step(state, jax.random.PRNGKey(6), trace, pairs,
+                            archive, fails)
         return np.asarray(state.best_delays)
 
     assert np.allclose(run(), run())

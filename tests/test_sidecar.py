@@ -5,7 +5,6 @@ policy's sidecar delegation — which never searches in-process, because
 the sidecar is the one process that owns the chip.
 """
 
-import time
 
 import numpy as np
 import pytest
@@ -76,21 +75,25 @@ def test_replies_state_the_device(server, history):
 def test_search_and_warm_amortization(server, history, tmp_path):
     addr = f"127.0.0.1:{server.port}"
     ckpt = str(tmp_path / "side.npz")
-    t0 = time.monotonic()
     r1 = request(addr, search_req(history, ckpt))
-    cold = time.monotonic() - t0
     assert r1["ok"] and np.isfinite(r1["fitness"])
     assert len(r1["delays"]) == 32
     assert (tmp_path / "side.npz").exists()
+    _fp, held = server.service._searches[history.dir]
+    steps = dict(held._fused_steps)
+    assert steps and all(f._cache_size() == 1 for f in steps.values())
 
-    t0 = time.monotonic()
     r2 = request(addr, search_req(history, ckpt))
-    warm = time.monotonic() - t0
     assert r2["ok"]
     assert r2["generations_run"] > r1["generations_run"]
     # the whole point of the sidecar: the compiled search is held, so a
-    # follow-up request skips construction + jit warm-up
-    assert warm < cold / 2, (cold, warm)
+    # follow-up request skips construction + jit warm-up. Held to what
+    # is held, not to the two requests' wall times: on an xdist worker
+    # whose earlier files had warmed these shapes, under six loaded
+    # workers, "cold" read 0.38 s and "warm" 0.35 s (alone: 4.2 / 0.1)
+    assert server.service._searches[history.dir][1] is held
+    assert held._fused_steps == steps  # the same jitted steps,
+    assert all(f._cache_size() == 1 for f in steps.values())  # not retraced
 
 
 def test_checkpoint_interchangeable_with_in_process(server, history,
@@ -99,7 +102,7 @@ def test_checkpoint_interchangeable_with_in_process(server, history,
     ScheduleSearch built with the same params — the two homes are
     interchangeable mid-experiment."""
     from namazu_tpu.models.search import ScheduleSearch
-    from namazu_tpu.sidecar import build_search_from_params
+    from namazu_tpu.models.search import build_search_from_params
 
     addr = f"127.0.0.1:{server.port}"
     ckpt = str(tmp_path / "x.npz")
@@ -116,7 +119,7 @@ def test_cached_search_reloads_newer_checkpoint(server, history, tmp_path):
     reload the newer on-disk checkpoint instead of overwriting it with
     its stale cached state (lost update, ADVICE r4)."""
     from namazu_tpu.models.ingest import IngestParams, ingest_history
-    from namazu_tpu.sidecar import build_search_from_params
+    from namazu_tpu.models.search import build_search_from_params
 
     ckpt = str(tmp_path / "c.npz")
     addr = f"127.0.0.1:{server.port}"

@@ -80,7 +80,7 @@ def _compile_fused(devices, weights=ScoreWeights(), max_fault=0.0,
     mesh = Mesh(np.array(devices), ("i",))
     fused = make_fused_island_step(
         mesh, GAConfig(max_delay=0.1, max_fault=max_fault), weights,
-        rings=(("i", 8, 1),), generations=G)
+        migrate_k=8, generations=G)
     genomes = _on(mesh, (pop, H), spec=P("i"))
     state = IslandState(
         pop=Population(delays=genomes, faults=genomes),
