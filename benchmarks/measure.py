@@ -7,7 +7,8 @@ parent beside its change (put both checkouts in one call).
         [--seconds S] [--trace 0|1] [--sets 2]
 
 Each run is ``benchmarks/run.py`` in a process of its own; the result
-lines, the ``facts:`` and the ``checks:`` lines go to
+lines (the numbers compared are their ``checks``) and the ``facts:``
+lines go to
 ``chiprun_out/benchmarks/measure/<cell>.jsonl``. The spread printed per
 metric is the builder's: (Q3 - Q1) / median of a set, by
 ``statistics.quantiles(n=4)``; with two sets of the same seeds, the
@@ -38,9 +39,8 @@ def one_run(cell: str, seed: int, seconds: float, trace: int) -> dict:
     rec = {"seed": seed, "rc": proc.returncode}
     lines = proc.stdout.strip().splitlines()
     for line in lines:
-        for tag in ("facts", "checks"):
-            if line.startswith(tag + ": "):
-                rec[tag] = json.loads(line[len(tag) + 2:])
+        if line.startswith("facts: "):
+            rec["facts"] = json.loads(line[len("facts: "):])
     if proc.returncode == 0 and lines:
         rec["result"] = json.loads(lines[-1])
     else:
@@ -82,7 +82,7 @@ def main() -> int:
                 "metrics": {n: m["value"] for n, m in
                             (res.get("metrics") or {}).items()},
                 "checks": {n: c["value"] for n, c in
-                           (rec.get("checks") or {}).items()},
+                           (res.get("checks") or {}).items()},
                 "agreement": {
                     k: v for k, v in ((rec.get("facts") or {}).get(
                         "agreement") or {}).items()

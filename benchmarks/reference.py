@@ -25,6 +25,27 @@ novelty scale):
               - w_delay * mean(delays)
     d2(f, c) = |f|^2 + |c|^2 - 2 f.c,  clamped at 0 after the min
 
+**Release modes.** A search's table is read in one of two ways, and the
+request states which (``search_params.release_mode``; read in ONE place,
+``SearchState.__init__``, everything below asks the state):
+
+* ``delay``: per-bucket delays, ``t = arrival + table[bucket]``;
+* ``reorder``: per-bucket *priorities* in ``[0, max_interval]``, realized
+  by the policy's reorder buffer. Events of a reference trace are
+  batched into arrival windows of ``reorder_window`` seconds (index
+  ``floor(arrival / window)``, taken in float32 from the float32
+  arrivals the device holds; ``window = 0`` is one global window); a
+  batch is released in ``(priority[bucket], arrival)`` order, ``gap``
+  apart, starting at the window's end:
+  ``t = (win + 1) * window + rank_in_window * gap``; masked events are
+  absent. ``gap = max(reorder_gap, 1e-4)``, ``tau = gap / 2``, and the
+  delay-cost weight is 0 (a uniform shift of priorities permutes
+  nothing). Stored runs are embedded from their REALIZED releases in
+  both modes, at the mode's ``tau``.
+
+Not covered, and refused by name: a fault half (``max_fault > 0``),
+guidance, a failure pool, the knowledge service.
+
 The configuration states its precision: the f.c operands are rounded to
 bfloat16 on the TPU (float32 elsewhere), products and sums are float32.
 ``score(..., operand=...)`` rounds exactly those operands (round to
@@ -70,18 +91,70 @@ def _round_operand(x: np.ndarray, operand: str) -> np.ndarray:
         getattr(ml_dtypes, operand)).astype(np.float64)
 
 
-def features(delays, hint_ids, arrival, mask, pairs, tau):
-    """Precedence features f64[S, K] of ``S`` delay tables against one
-    encoded trace: first release per hint bucket (scatter-min, the
-    honest scalar way), then sigmoid((first[v] - first[u]) / tau)."""
+def window_index(arrival, window: float) -> np.ndarray:
+    """The arrival window of each event: ``floor(arrival / window)`` in
+    float32, as the float32 arrivals on the device are divided (float64
+    here would move an event that sits on a window's edge);
+    ``window = 0`` is one global window."""
+    arrival = np.asarray(arrival, np.float32)
+    if window <= 0:
+        return np.zeros(arrival.shape, np.int64)
+    return np.floor(arrival / np.float32(window)).astype(np.int64)
+
+
+def events_on_a_window_edge(arrival, mask, window: float) -> int:
+    """How many live events sit within 2 ulp of a window's edge: there a
+    division that is not correctly rounded may put the event in the
+    neighbouring window, which is no fault of the scorer's."""
+    a = np.asarray(arrival, np.float32)[np.asarray(mask, bool)]
+    if window <= 0 or not a.size:
+        return 0
+    q = a / np.float32(window)
+    return int(((q != 0) & (np.abs(q - np.rint(q))
+                            <= 2 * np.spacing(np.abs(q)))).sum())
+
+
+def ordered_release(prio, hint_ids, arrival, mask, gap, window):
+    """Release times f32[S, L] of one trace under ``S`` priority tables
+    (module docstring, ``reorder``). The release slots of a window are
+    the same under every table -- its end, then ``gap`` apart; a table
+    only decides which of the window's events takes which slot."""
+    prio = np.asarray(prio, np.float32)
+    hint_ids = np.asarray(hint_ids)
+    arrival = np.asarray(arrival, np.float32)
+    t = np.full((len(prio), len(hint_ids)), BIG, np.float32)
+    live = np.flatnonzero(np.asarray(mask, bool))
+    win = window_index(arrival[live], window)
+    for w in np.unique(win):
+        batch = live[win == w]
+        # by arrival (ties: position in the trace) once, then stably by
+        # each table's priorities: (priority, arrival) order
+        batch = batch[np.argsort(arrival[batch], kind="stable")]
+        slots = ((np.float32(w) + np.float32(1.0)) * np.float32(window)
+                 + np.arange(len(batch), dtype=np.float32)
+                 * np.float32(gap))
+        rank = np.argsort(prio[:, hint_ids[batch]], axis=-1, kind="stable")
+        np.put_along_axis(t, batch[rank], slots[None, :], axis=1)
+    return t
+
+
+def features(delays, hint_ids, arrival, mask, pairs, tau, order=None):
+    """Precedence features f64[S, K] of ``S`` tables against one
+    encoded trace: the release time of every event (``order`` None: the
+    table is delays; ``(gap, window)``: priorities), first release per
+    hint bucket (the least over that bucket's events, bucket by bucket),
+    then sigmoid((first[v] - first[u]) / tau)."""
     delays = np.asarray(delays, np.float32)
     S, H = delays.shape
-    t = (np.asarray(arrival, np.float32)[None, :]
-         + delays[:, hint_ids]).astype(np.float32)
-    t = np.where(np.asarray(mask, bool)[None, :], t, np.float32(BIG))
+    if order is None:
+        t = (np.asarray(arrival, np.float32)[None, :]
+             + delays[:, hint_ids]).astype(np.float32)
+        t = np.where(np.asarray(mask, bool)[None, :], t, np.float32(BIG))
+    else:
+        t = ordered_release(delays, hint_ids, arrival, mask, *order)
     first = np.full((S, H), BIG, np.float32)
-    for s in range(S):
-        np.minimum.at(first[s], hint_ids, t[s])
+    for b in np.unique(hint_ids):
+        first[:, b] = t[:, hint_ids == b].min(axis=1)
     du = first[:, pairs[:, 0]].astype(np.float64)
     dv = first[:, pairs[:, 1]].astype(np.float64)
     z = np.clip((dv - du) / tau, -30.0, 30.0)
@@ -98,14 +171,17 @@ def _min_d2(feats, centres, operand):
 
 def score(delays, traces, pairs, archive, failures, weights,
           novelty_scale=1.0, operand="float32"):
-    """Fitness f64[S] of ``S`` delay tables. ``traces`` is a list of
+    """Fitness f64[S] of ``S`` tables. ``traces`` is a list of
     ``(hint_ids, arrival, mask)``; ``weights`` has ``novelty``, ``bug``,
-    ``delay_cost`` and ``tau``. With a tuple of operand types, a dict of
-    one fitness vector each (the features are worked out once)."""
+    ``delay_cost``, ``tau`` and, where the tables are priorities,
+    ``order`` = ``(gap, window)``. With a tuple of operand types, a
+    dict of one fitness vector each (the features are worked out
+    once)."""
     delays = np.asarray(delays, np.float32)
     operands = (operand,) if isinstance(operand, str) else tuple(operand)
     feats = [features(delays, np.asarray(hint_ids), arrival, mask,
-                      np.asarray(pairs), weights["tau"])
+                      np.asarray(pairs), weights["tau"],
+                      weights.get("order"))
              for hint_ids, arrival, mask in traces]
     out = {}
     for op in operands:
@@ -226,26 +302,54 @@ def informative_pairs(occupied, K: int, H: int, seed: int) -> np.ndarray:
 # -- the state a request meets ------------------------------------------------
 
 
+class Refused(ValueError):
+    """A search the reference does not cover, or a history it may not
+    be held to: no comparison is made, the run gives no result."""
+
+
 class SearchState:
     """The search state of one campaign storage, request after request.
     ``params`` are the request's stated ``search_params`` and
-    ``ingest_params`` (K, H, seed, tau, weights, reference mode, anneal);
-    ``archive_rows`` / ``failure_rows`` the configuration's ring sizes.
-    Delay-mode, fault-free, pool-free searches only."""
+    ``ingest_params`` (K, H, seed, release mode, tau, weights, reference
+    mode, anneal, trace cap); ``archive_rows`` / ``failure_rows`` the
+    configuration's ring sizes. Delay- and reorder-mode searches,
+    fault-free, unguided and pool-free."""
 
     def __init__(self, search_params: dict, ingest_params: dict,
                  archive_rows: int, failure_rows: int) -> None:
         sp, ip = search_params, ingest_params
-        if (sp.get("release_mode", "delay") != "delay"
-                or sp.get("max_fault", 0.0) > 0 or sp.get("guidance")
-                or ip.get("failure_pool") or ip.get("knowledge")):
-            raise ValueError("the reference covers delay-mode, fault-free"
-                             ", pool-free searches only")
+        refused = [what for what, stated in (
+            ("a fault half (max_fault > 0)", sp.get("max_fault", 0.0) > 0),
+            ("guidance", sp.get("guidance") or ip.get("guidance")),
+            ("a failure pool (failure_pool)", ip.get("failure_pool")),
+            ("the knowledge service (knowledge)", ip.get("knowledge")),
+        ) if stated]
+        if refused:
+            raise Refused("the reference does not cover "
+                          + ", ".join(refused))
         self.K, self.H, self.seed = int(sp["K"]), int(sp["H"]), int(sp["seed"])
+        # the ONE place the release mode is read (module docstring)
+        self.release_mode = sp.get("release_mode", "delay")
+        #: events of a stored run past which ``ingest`` refuses the
+        #: history (0: never; a delay-mode run the program cut shows in
+        #: the numbers compared, as it always did)
+        self.trace_cap = 0
+        if self.release_mode == "delay":
+            self.order = None
+            tau, delay_cost = float(sp["tau"]), float(sp["w_delay_cost"])
+        elif self.release_mode == "reorder":
+            gap = max(float(sp["reorder_gap"]), 1e-4)
+            self.order = (gap, max(float(sp["reorder_window"]), 0.0))
+            tau, delay_cost = gap * 0.5, 0.0
+            self.trace_cap = int(ip.get("L", 0)
+                                 or ip["order_mode_max_l"])
+        else:
+            raise Refused("the reference does not cover release_mode "
+                          f"{self.release_mode!r}")
         self.weights = {"novelty": float(sp["w_novelty"]),
                         "bug": float(sp["w_bug"]),
-                        "delay_cost": float(sp["w_delay_cost"]),
-                        "tau": float(sp["tau"])}
+                        "delay_cost": delay_cost, "tau": tau,
+                        "order": self.order}
         self.max_interval = float(sp["max_interval"])
         self.min_signatures = int(sp.get("min_failure_signatures", 0))
         self.novelty_floor = float(sp.get("novelty_floor", 0.25))
@@ -278,6 +382,13 @@ class SearchState:
 
     def ingest(self, runs: list) -> None:
         """One request: the whole stored history is fed again."""
+        for run in runs:
+            if self.trace_cap and len(run.hint_ids) > self.trace_cap:
+                raise Refused(
+                    f"stored run {run.index} has {len(run.hint_ids)} "
+                    f"events, the request caps a trace at "
+                    f"{self.trace_cap}: the program would score a cut "
+                    "trace, and a cut hunt is not the hunt")
         occupied = {int(b) for r in runs for b in r.hint_ids}
         pairs = informative_pairs(occupied, self.K, self.H, self.seed)
         if not np.array_equal(pairs, self.pairs):
@@ -308,6 +419,16 @@ class SearchState:
         if self.min_signatures <= 0 or n < self.min_signatures:
             return 1.0
         return max(self.novelty_floor, self.min_signatures / n)
+
+    def held_mode(self) -> list:
+        """What a search built from this request has to hold."""
+        return [self.release_mode] + list(self.order or ())
+
+    def events_on_a_window_edge(self) -> int:
+        if self.order is None:
+            return 0
+        return sum(events_on_a_window_edge(arrival, mask, self.order[1])
+                   for _hint_ids, arrival, mask in self.traces)
 
     def score(self, delays, operand="float32"):
         return score(np.atleast_2d(np.asarray(delays, np.float32)),

@@ -14,7 +14,8 @@ and prints, as the last line of stdout, the result object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` and, traced,
 ``breakdown``). Facts that are not metrics go on the ``facts:`` line
 before it and into ``chiprun_out/benchmarks/<cell>/facts.json``; every
-number compared, beside its limit, on the ``checks:`` line.
+number compared, beside its limit, last in the result object (``checks``)
+and as the ``checks:`` line that ends stderr.
 
 Off a TPU (or with fewer chips than the cell asks for) it exits non-zero
 and prints no result. ``--cpu N`` is the tests' explicit dry run.
@@ -385,6 +386,22 @@ class Window:
         self.failures: list = []      # why a cycle failed
         self.facts: dict = {}
         self.requests: dict = {}      # key -> the search request sent
+        self.states: dict = {}        # key -> its reference.SearchState
+        # key -> (first, count): which of the sidecar's replies for that
+        # key, in the order it gave them, the clients got in the window
+        self.replies: dict = {}
+
+    def state_requests(self, requests: list, width: dict) -> None:
+        """The requests this run will send, and the plain reference's
+        state for each, built BEFORE anything warms: a search it does
+        not cover is refused here (``reference.Refused``), not after a
+        whole window."""
+        self.requests = {r["key"]: r for r in requests}
+        self.states = {
+            key: reference.SearchState(
+                r["search_params"], r["ingest_params"],
+                width["archive_rows"], width["failure_rows"])
+            for key, r in self.requests.items()}
 
 
 def drive_live(ctx, win: Window) -> None:
@@ -403,6 +420,8 @@ def drive_live(ctx, win: Window) -> None:
     ctx["closers"].append(relay.close)
     doc = search_config(ctx["root"], ctx["config"], relay.addr, ctx["seed"])
     write_config(storage, doc)
+    request = policy_request(ctx["root"], doc, storage)
+    win.state_requests([request], ctx["config"]["shipped_width"])
     sidecar.wait_ready()
     # the first request builds the search and compiles (or loads) its
     # programs; sent from here so that no run child waits on a compile
@@ -410,11 +429,10 @@ def drive_live(ctx, win: Window) -> None:
     # failure signature and a moved reference envelope: the row updates
     # of the device mirrors, which a window would otherwise compile at
     # its first reproduction
-    request = policy_request(ctx["root"], doc, storage)
-    win.requests = {request["key"]: request}
     sidecar.call(request)
     history.reveal_held_back(storage)
     first = sidecar.call(request)
+    direct = 2  # requests sent from here, not through the relay
     note(f"warm requests: gen {first.get('generations_run')}")
     guard.release()
 
@@ -483,6 +501,9 @@ def drive_live(ctx, win: Window) -> None:
     in_window = [r for r in relay.records
                  if win.t_open < r["t_end"] <= t_close]
     win.install_s = [r["t_end"] - r["t_start"] for r in in_window]
+    win.replies[request["key"]] = (
+        direct + sum(r["t_end"] <= win.t_open for r in relay.records),
+        len(in_window))
     repro = 0
     for k in range(first_in_window, i):
         with open(os.path.join(storage, f"{k:08x}", "result.json")) as f:
@@ -529,7 +550,7 @@ def drive_fleet(ctx, win: Window) -> None:
             mix["history_failures"], ctx["seed"] + 7919 * i)
         write_config(s, doc)
     requests = [policy_request(ctx["root"], doc, s) for s in storages]
-    win.requests = {r["key"]: r for r in requests}
+    win.state_requests(requests, ctx["config"]["shipped_width"])
     sidecar.wait_ready()
 
     records: list = []
@@ -587,48 +608,59 @@ def drive_fleet(ctx, win: Window) -> None:
             win.completions.append(r["t_end"])
             win.install_s.append(r["t_end"] - r["t_start"])
             win.attempted += 1
+    # a client's k-th reply is the sidecar's k-th for its storage
+    for i in range(n):
+        ends = [r["t_end"] for r in records if r["client"] == i]
+        win.replies[requests[i]["key"]] = (
+            sum(t <= win.t_open for t in ends),
+            sum(win.t_open < t <= t_close for t in ends))
     win.facts.update(depth_at_open=mix["history_depth"],
                      depth_at_close=mix["history_depth"],
                      requests_per_client=[
-                         sum(1 for r in records if r["client"] == i
-                             and win.t_open < r["t_end"] <= t_close)
-                         for i in range(n)])
+                         win.replies[r["key"]][1] for r in requests])
 
 
 DRIVERS = {"campaign": drive_live, "fleet": drive_fleet}
 
 
-def check_replies(rows: list, win: Window, generations: int,
-                  t_close: float) -> None:
-    """Every request the sidecar served up to the window's end:
+def replies_by_key(rows: list) -> dict:
+    """The sidecar's rows (indices into ``rows``) per key, in the order
+    it answered them: ``Window.replies`` counts along these."""
+    by_key: dict = {}
+    for i in sorted(range(len(rows)), key=lambda i: rows[i]["wall"]):
+        by_key.setdefault(rows[i]["key"], []).append(i)
+    return by_key
+
+
+def check_replies(rows: list, win: Window, generations: int) -> None:
+    """Every request the sidecar served up to the window's last reply:
     answered, from history, and the search's generation counter — the
     host's and the fused step's own on the device — advanced by exactly
     the stated number per request (a step that returns its state
-    unchanged, or a skipped request, breaks the chain)."""
-    last: dict = {}
-    for r in sorted(rows, key=lambda r: r["wall"]):
-        end = r["wall"] + r["seconds"]
-        if end > t_close:
-            continue
-        why = ""
-        if not r["ok"]:
-            why = f"refused: {r.get('error')}"
-        elif r["no_history"]:
-            why = "no_history"
-        else:
-            now = (r["generations_run"], r["fused_gen"])
-            prev = last.get(r["key"])
-            if None in now:
-                why = f"no generation counter: {now}"
-            elif prev is not None and now != (prev[0] + generations,
-                                              prev[1] + generations):
-                why = (f"generation counters {prev} -> {now}, wanted "
-                       f"+{generations}")
-            last[r["key"]] = now if None not in now else prev
-        if why and end > win.t_open:
-            win.failed += 1
-            win.failures.append(f"request for {os.path.basename(r['key'])}"
-                                f": {why}")
+    unchanged, or a skipped request, breaks the chain). A failure
+    counts where the reply is one of the window's."""
+    for key, mine in replies_by_key(rows).items():
+        first, count = win.replies.get(key, (0, 0))
+        prev = None
+        for k, r in enumerate(rows[i] for i in mine[:first + count]):
+            why = ""
+            if not r["ok"]:
+                why = f"refused: {r.get('error')}"
+            elif r["no_history"]:
+                why = "no_history"
+            else:
+                now = (r["generations_run"], r["fused_gen"])
+                if None in now:
+                    why = f"no generation counter: {now}"
+                elif prev is not None and now != (prev[0] + generations,
+                                                  prev[1] + generations):
+                    why = (f"generation counters {prev} -> {now}, wanted "
+                           f"+{generations}")
+                prev = now if None not in now else prev
+            if why and k >= first:
+                win.failed += 1
+                win.failures.append(f"request for {os.path.basename(key)}"
+                                    f": {why}")
 
 
 # -- one run ------------------------------------------------------------------
@@ -720,14 +752,15 @@ def run_cell(args) -> int:
         dump = sidecar.call({"op": "bench_state",
                              "out": os.path.join(work, "state.npz")})
         check_replies(dump["requests"], win,
-                      config["guarantees"]["generations_per_request"],
-                      t_close)
+                      config["guarantees"]["generations_per_request"])
         # the plain reference, from the storages (numpy, on the host);
         # the sidecar stays up to answer for the tables it names
+        t_compare = time.time()
         agree = compare_answers(
-            dump, win.requests, config["shipped_width"], win.t_open,
-            t_close, lambda key, rows: sidecar.call(
+            dump, win, lambda key, rows: sidecar.call(
                 {"op": "bench_probe", "key": key, "rows": rows})["fitness"])
+        # what every run pays after its window (a run has 360 s in all)
+        agree["comparison_s"] = time.time() - t_compare
         obs["compiles"] = [c for c in info["compiles"]
                            if win.t_open < c[0] <= t_close]
         obs["spans"] = {
@@ -764,7 +797,7 @@ def run_cell(args) -> int:
     }
     for name in ("pairs_differ", "labels_differ", "ring_counts_differ",
                  "reference_buckets_differ", "tables_out_of_range",
-                 "answers_missing"):
+                 "answers_missing", "release_mode_differs"):
         checks[name] = [agree[name], 0]
     correct = all(got <= limit for got, limit in checks.values())
 
@@ -847,19 +880,25 @@ def run_cell(args) -> int:
                     else os.remove(path)
     assert "jax" not in sys.modules, "the benchmark's parent imported jax"
     print("facts: " + json.dumps(facts), flush=True)
-    print("checks: " + json.dumps(
-        {k: {"value": v, "limit": lim} for k, (v, lim) in checks.items()}),
-        flush=True)
+    # every number compared beside its limit: last in the result's
+    # line, and as the last line of stderr
+    result["checks"] = {k: {"value": v, "limit": lim}
+                        for k, (v, lim) in checks.items()}
+    print("checks: " + json.dumps(result["checks"]), file=sys.stderr,
+          flush=True)
     print(json.dumps(result), flush=True)
     return 0
 
 
-def compare_answers(dump: dict, requests: dict, width: dict,
-                    t_open: float, t_close: float, probe) -> dict:
+def compare_answers(dump: dict, win: Window, probe) -> dict:
     """Every answer of the window against the plain reference
     (``benchmarks/reference.py``). Per search, its requests are
     replayed in order against the state worked out from the storage
-    files. Answers: ``reply`` — each reply's (table, fitness);
+    files (``win.states``: built from the request before the window,
+    the one place the mode is decided). A reply is the window's where
+    the CLIENT got it inside (``win.replies``): the clock ``attempted``
+    is counted on, so every cycle counted has its reply compared and
+    no other reply is. Answers: ``reply`` — each reply's (table, fitness);
     ``fused`` — the fused step's own best (table, fitness) where that
     request improved it and, after the last request, the best fitness
     it finds in the population as it stands and its fitness of the
@@ -881,9 +920,11 @@ def compare_answers(dump: dict, requests: dict, width: dict,
     got = {k: [] for k in kinds}
     ref = {k: {op: [] for op in operands} for k in kinds}
     out = {"tables_out_of_range": 0, "answers_missing": 0,
+           "release_mode_differs": 0, "events_on_a_window_edge": 0,
            "reference_traces": 0, "fused_bests_of_the_window": 0}
     gaps: dict = {}
     rows = dump["requests"]
+    by_key = replies_by_key(rows)
 
     def answer(kind, table, fitness, state):
         out["tables_out_of_range"] += int(
@@ -894,16 +935,21 @@ def compare_answers(dump: dict, requests: dict, width: dict,
             ref[kind][op].append(float(f[0]))
 
     for key, search in sorted(dump["searches"].items()):
-        if not search["delay_mode"]:
-            raise BenchFailure("the reference covers delay-mode, "
-                               "fault-free searches only")
-        req = requests[key]
-        state = reference.SearchState(
-            req["search_params"], req["ingest_params"],
-            width["archive_rows"], width["failure_rows"])
-        mine = sorted((i for i, r in enumerate(rows) if r["key"] == key
-                       and r["ok"] and not r["no_history"]),
-                      key=lambda i: rows[i]["wall"])
+        if search["fault_coin"]:
+            raise BenchFailure("the reference covers fault-free searches "
+                               "only: this search holds a fault coin")
+        # the mode is the one the REQUEST states; the search is held to it
+        req, state = win.requests[key], win.states[key]
+        held = [search["release_mode"]] + (
+            [search["order_gap"], search["order_window"]]
+            if search["release_mode"] == "reorder" else [])
+        out["release_mode_differs"] += int(held != state.held_mode())
+        first, count = win.replies.get(key, (0, 0))
+        of_window = set(by_key.get(key, [])[first:first + count])
+        # a reply a client counted that the sidecar has no row for
+        out["answers_missing"] += count - len(of_window)
+        mine = [i for i in by_key.get(key, [])
+                if rows[i]["ok"] and not rows[i]["no_history"]]
         runs = reference.read_runs(
             req["storage"], max([rows[i]["depth"] or 0 for i in mine]
                                 or [0]), state.H)
@@ -913,7 +959,7 @@ def compare_answers(dump: dict, requests: dict, width: dict,
             state.ingest([run for run in runs if run.index < r["depth"]])
             improved = r["fused_fitness"] != best
             best = r["fused_fitness"]
-            if not t_open < r["wall"] + r["seconds"] <= t_close:
+            if i not in of_window:
                 continue
             if r["fitness"] is None or r["fused_fitness"] is None:
                 out["answers_missing"] += 1
@@ -949,6 +995,7 @@ def compare_answers(dump: dict, requests: dict, width: dict,
             gaps[name] = max(gaps.get(name, 0), v)
         out["reference_traces"] = max(out["reference_traces"],
                                       len(state.traces))
+        out["events_on_a_window_edge"] += state.events_on_a_window_edge()
     out.update(gaps)
     for kind in kinds:
         out[f"{kind}_answers"] = len(got[kind])
@@ -989,6 +1036,9 @@ def main(argv=None) -> int:
     except BenchFailure as e:
         print(f"benchmarks/run.py: FAILED: {e}", file=sys.stderr)
         return e.code
+    except reference.Refused as e:
+        print(f"benchmarks/run.py: REFUSED: {e}", file=sys.stderr)
+        return 1
     except manifest_mod.ManifestError as e:
         print(f"benchmarks/run.py: {e}", file=sys.stderr)
         return 2

@@ -28,8 +28,11 @@ holds the chip can do:
   fitness the last reply's re-rank gave each of its tables, and the
   best fitness that one more dispatch of the window's own compiled
   fused step finds in it; ``bench_probe`` then reads the fused step's
-  fitness of single tables of that population the parent names; the parent holds all of it against the plain
-  reference, which it works out from the storage files alone.
+  fitness of single tables of that population the parent names; the
+  dump also says what each search HOLDS (release mode, order gap and
+  window, whether it has a fault coin), for the parent to hold against
+  what the request states; the parent holds all of it against the
+  plain reference, which it works out from the storage files alone.
 
 The program's own ``device_trace_dir`` is not used: it is a one-shot
 capture of the first evolve and changes the ``search_params``
@@ -252,8 +255,13 @@ def dump_state(service, book: Book, req: dict) -> dict:
                     int(s.data.shape[0]) for s in
                     search._state.pop.delays.addressable_shards),
                 "novelty_scale": float(search.novelty_scale()),
-                "delay_mode": bool(search._coin is None
-                                   and not search.cfg.weights.order_mode)}
+                # what the search holds, for the parent to hold against
+                # what the request states
+                "fault_coin": search._coin is not None,
+                "release_mode": ("reorder" if search.cfg.weights.order_mode
+                                 else "delay"),
+                "order_gap": float(search.cfg.weights.order_gap),
+                "order_window": float(search.cfg.weights.order_window)}
     np.savez(req["out"], **out)
     import jax
 

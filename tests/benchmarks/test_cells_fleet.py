@@ -20,12 +20,36 @@ def root(tmp_path_factory):
     return tiny_root.build(tmp_path_factory.mktemp("bench_fleet"))
 
 
+@pytest.fixture(scope="module")
+def reorder_root(tmp_path_factory):
+    return tiny_root.build(tmp_path_factory.mktemp("bench_fleet_reorder"),
+                           search=tiny_root.REORDER_SEARCH)
+
+
+#: every number a run compares, with its limit: what a delay-mode run
+#: printed before the order mode was opened, and the one exact check
+#: that came with it
+CHECKS = {
+    "reply_fitness_gap": 0.05, "fused_fitness_gap": 0.05,
+    "rerank_fitness_gap": 0.05, "archive_rows_gap": 1e-5,
+    "failure_rows_gap": 1e-5, "reference_times_gap": 0.0,
+    "failed_cycles": 0, "window_compiles": 0, "no_cycle_completed": 0,
+    "shard_rows_wrong": 0, "sidecar_unclean_stop": 0, "pairs_differ": 0,
+    "labels_differ": 0, "ring_counts_differ": 0,
+    "reference_buckets_differ": 0, "tables_out_of_range": 0,
+    "answers_missing": 0, "release_mode_differs": 0}
+
+
 @pytest.mark.parametrize("cell", FLEET, ids=[c["name"] for c in FLEET])
 def test_fleet_cell_rehearsal(root, cell):
     rc, result, out, err = tiny_root.run_cell(
         root, cell["name"], cell["chips"], trace=0)
     assert rc == 0, err[-3000:]
     assert result["correct"] is True, out[-3000:]
+    checks = result["checks"]
+    assert {k: c["limit"] for k, c in checks.items()} == CHECKS
+    exact = [k for k, limit in CHECKS.items() if limit == 0]
+    assert all(checks[k]["value"] == 0 for k in exact)
     assert result["device"]["count"] == cell["chips"]
     assert set(result["metrics"]) == {
         "searched_runs_per_hour", "install_p50_s", "setup_s"}
@@ -38,11 +62,37 @@ def test_fleet_cell_rehearsal(root, cell):
     # every reply of the window was held against the reference, and the
     # state worked out from the storages matched the resident rows
     agree = facts["agreement"]
-    assert agree["reply_answers"] == result["attempted"]
+    assert agree["reply_answers"] == result["attempted"] \
+        == sum(facts["requests_per_client"])
     assert agree["fused_answers"] >= len(facts["searches"])
     assert agree["archive_rows_gap"] < 1e-5
     assert facts["depth_at_open"] == facts["depth_at_close"] == 6
     assert min(facts["requests_per_client"]) >= 1
+
+
+def test_fleet_cell_rehearsal_in_reorder_mode(reorder_root):
+    """The same cell with the order half of the genome searched: the
+    request states the mode, the search holds it, and every answer is
+    held to the order-mode reference under the same limits."""
+    cell = FLEET[0]
+    rc, result, out, err = tiny_root.run_cell(
+        reorder_root, cell["name"], cell["chips"], trace=0)
+    assert rc == 0, err[-3000:]
+    assert result["correct"] is True, out[-3000:]
+    checks = result["checks"]
+    assert {k: c["limit"] for k, c in checks.items()} == CHECKS
+    facts = tiny_root.tagged(out, "facts: ")
+    for held in facts["searches"]:
+        assert (held["release_mode"], held["order_gap"],
+                held["order_window"]) == ("reorder", 0.01, 0.05)
+    agree = facts["agreement"]
+    assert agree["reply_answers"] == result["attempted"] >= 2
+    assert agree["rerank_answers"] == 2 * 64  # both populations, whole
+    assert agree["events_on_a_window_edge"] == 0
+    assert 0 < agree["comparison_s"] < 30
+    # priorities are clipped to the delay range; nothing else is read
+    # differently: the rings hold REALIZED releases in both modes
+    assert checks["archive_rows_gap"]["value"] < 1e-5
 
 
 def test_a_cell_a_mix_a_config_and_a_metric_from_new_files_only(
@@ -181,8 +231,7 @@ def test_broken_timed_path_is_not_correct(root, tmp_path, how):
         extra_env={"PYTHONPATH": str(site)})
     assert rc == 0, err[-3000:]
     assert result["correct"] is False, out[-2000:]
-    checks = json.loads(next(line for line in out.splitlines()
-                             if line.startswith("checks: "))[8:])
+    checks = result["checks"]
     caught = CAUGHT_BY[how]
     assert checks[caught]["value"] > checks[caught]["limit"], checks
     if how.endswith("scorer_altered"):

@@ -197,11 +197,7 @@ def root(tmp_path_factory):
 def run(root, **kw):
     rc, result, out, err = tiny_root.run_cell(root, CELL, 1, **kw)
     assert rc == 0, err[-3000:]
-    facts, checks = (json.loads(next(
-        line for line in out.splitlines()
-        if line.startswith(tag))[len(tag):]) for tag in ("facts: ",
-                                                         "checks: "))
-    return result, facts, checks
+    return result, tiny_root.tagged(out, "facts: "), result["checks"]
 
 
 def test_long_traces_agree_with_the_reference(root):

@@ -29,8 +29,15 @@ TINY_MIX = {
 }
 
 
-def build(tmp: str) -> str:
-    """Returns the root of a tiny checkout under ``tmp``."""
+#: the order half of the genome at the rehearsal testee's scale (12
+#: events over ~0.2 s, priorities in [0, 20 ms]): four arrival windows
+REORDER_SEARCH = {"release_mode": "reorder", "reorder_gap": 10,
+                  "reorder_window": 50}
+
+
+def build(tmp: str, search: dict = None) -> str:
+    """Returns the root of a tiny checkout under ``tmp``; ``search``
+    is set on top of every configuration's ``search.set``."""
     root = os.path.join(str(tmp), "root")
     os.makedirs(os.path.join(root, "benchmarks", "configs"))
     os.symlink(os.path.join(REPO, "namazu_tpu"),
@@ -54,7 +61,7 @@ def build(tmp: str) -> str:
                        "ports": [10967]}
         c["history"] = "examples/mini/history.json"
         c["search"]["drop"] = []
-        c["search"]["set"].update(TINY_SEARCH)
+        c["search"]["set"].update(TINY_SEARCH, **(search or {}))
         c["guarantees"]["generations_per_request"] = 4
         with open(os.path.join(root, cfg["file"]), "w") as f:
             json.dump(c, f)
@@ -70,6 +77,12 @@ def build(tmp: str) -> str:
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(doc, f)
     return root
+
+
+def tagged(out: str, tag: str) -> dict:
+    """The JSON of the ``facts: `` line of a run's stdout."""
+    return json.loads(next(line for line in out.splitlines()
+                           if line.startswith(tag))[len(tag):])
 
 
 def run_cell(root: str, cell: str, chips: int, trace: int = 0,
