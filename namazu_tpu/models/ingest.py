@@ -78,8 +78,10 @@ class IngestParams(NamedTuple):
     max_interval: float = 0.1  # seed-table clip (seconds)
     max_reference_traces: int = 4
     max_seed_genomes: int = 16
-    # order mode scores dense (whole-trace lexsort), so uncapped encodes
-    # would materialize [population, L] intermediates per generation
+    # order mode materializes [population, L] release times per
+    # reference trace and generation: a stored run LONGER than this is
+    # cut (and the benchmark's reference refuses such a history); a
+    # shorter one is encoded at its own length quantum, as in delay mode
     order_mode_max_l: int = 4096
     # shared failure-signature pool directory ("" = off): every ingested
     # failure is persisted there, and pooled signatures from OTHER runs/
@@ -238,12 +240,16 @@ def _read_run(storage, i: int):
 
 
 def _encode_run(trace, ok: bool, stamp: str, cap: Optional[int],
-                p: IngestParams) -> _RunRecord:
+                pads: bool, p: IngestParams) -> _RunRecord:
     if stamp != HINT_SPACE:
         return _RunRecord(None, None, ok, None, len(trace), stamp)
+    # a cap that ``pads`` (an explicit ``trace_length``) is every run's
+    # length; one that does not (the order mode's) only cuts: a run
+    # under it keeps its own length quantum
+    L = cap if cap is not None and (pads or len(trace) > cap) else None
     # two views of every run, one encode pass: arrival-anchored =
     # counterfactual reference; realized = archive embedding
-    enc, enc_rt = te.encode_trace_views(trace, L=cap, H=p.H)
+    enc, enc_rt = te.encode_trace_views(trace, L=L, H=p.H)
     seed = None if ok else failure_seed(trace, p.H, p.max_interval)
     return _RunRecord(enc, enc_rt, ok, seed, len(trace), stamp)
 
@@ -330,7 +336,8 @@ def _ingest_history(search, storage, p: IngestParams, stages: _Stages,
         gmap = search.enable_guidance(p.guidance_width or None,
                                       p.guidance_window or None,
                                       fresh=True)
-    if p.L > 0:
+    pads = p.L > 0
+    if pads:
         cap: Optional[int] = p.L
     elif p.release_mode == "reorder":
         cap = p.order_mode_max_l
@@ -341,13 +348,13 @@ def _ingest_history(search, storage, p: IngestParams, stages: _Stages,
     for i in range(n):
         t = time.monotonic()
         # what the loop needs of a stored run is a pure function of its
-        # two files and (cap, H, max_interval, HINT_SPACE): a run whose
-        # signature has not changed since a request encoded it is taken
-        # from the record kept then, and nothing of it is opened
+        # two files and (cap, pads, H, max_interval, HINT_SPACE): a run
+        # whose signature has not changed since a request encoded it is
+        # taken from the record kept then, and nothing of it is opened
         signature = storage.run_signature(i)
         key = rec = None
         if signature is not None:
-            key = (os.path.abspath(storage.run_dir(i)), cap, p.H,
+            key = (os.path.abspath(storage.run_dir(i)), cap, pads, p.H,
                    p.max_interval, HINT_SPACE)
             rec = _RUN_RECORDS.get(key, signature)
         hit = rec is not None
@@ -360,7 +367,7 @@ def _ingest_history(search, storage, p: IngestParams, stages: _Stages,
                 continue
         t = stages.add("ingest_read", t)
         if run is not None:
-            rec = _encode_run(*run, cap, p)
+            rec = _encode_run(*run, cap, pads, p)
             if key is not None:
                 _RUN_RECORDS.put(key, signature, rec)
         # runs recorded under a different replay-hint format hash into a

@@ -111,6 +111,7 @@ from namazu_tpu.obs.spans import (  # noqa: F401
     policy_decision,
     queue_dwell,
     relation_coverage,
+    reorder_window_drained,
     rest_ack,
     rest_request,
     sched_queue_depth,

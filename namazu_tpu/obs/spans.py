@@ -70,6 +70,13 @@ INGEST_EMBED_CALLS = "nmz_ingest_embed_calls_total"
 INGEST_EVENTS = "nmz_ingest_events_total"
 INGEST_CACHED_RUNS = "nmz_ingest_cached_runs_total"
 EVOLVE_REQUESTS = "nmz_evolve_requests_total"
+# the policy's reorder buffer (release_mode "reorder"): windows drained,
+# those whose paced drain ended after the NEXT window's boundary (the
+# scorer assumes a window's slots run from its own close), and how many
+# events a drained window held
+REORDER_WINDOWS = "nmz_reorder_windows_total"
+REORDER_WINDOW_OVERRUNS = "nmz_reorder_window_overruns_total"
+REORDER_WINDOW_EVENTS = "nmz_reorder_window_events"
 COMPILES = "nmz_compiles_total"
 COMPILE_SECONDS = "nmz_compile_seconds"
 #: the jax.monitoring event of one jaxpr->MLIR lowering
@@ -1386,10 +1393,33 @@ def ingest_cached_runs(n: int) -> None:
                             "their encoded-run records").inc(n)
 
 
+def reorder_window_drained(policy: str, events: int,
+                           overran: bool) -> None:
+    """One window of the policy's reorder buffer released (``events``
+    of it, paced ``reorder_gap`` apart). ``overran``: its last release
+    came after the next window's boundary, so the next window's first
+    slot is later than the scorer's ``close + gap * rank`` says."""
+    if not metrics.enabled():
+        return
+    reg = metrics.get()
+    reg.counter(REORDER_WINDOWS, "reorder windows drained",
+                ("policy",)).labels(policy=policy).inc()
+    if overran:
+        reg.counter(
+            REORDER_WINDOW_OVERRUNS,
+            "reorder windows whose paced drain ended after the next "
+            "window's boundary", ("policy",)).labels(policy=policy).inc()
+    reg.histogram(
+        REORDER_WINDOW_EVENTS, "events per drained reorder window",
+        ("policy",), buckets=BATCH_BUCKETS,
+    ).labels(policy=policy).observe(events)
+
+
 def evolve_request(scorer: str) -> None:
     """One evolve of a search backend, by the first-occurrence branch
     its compiled step took for the request's padded trace length
-    (``ops/schedule.py::scorer_branch``: ``dense`` | ``blockwise``)."""
+    (``ops/schedule.py::scorer_branch``: ``dense`` | ``blockwise`` |
+    ``order``)."""
     if not metrics.enabled():
         return
     metrics.get().counter(

@@ -150,47 +150,172 @@ def apply_faults(trace: TraceArrays, faults: Optional[jax.Array],
                        trace.mask & ~dropped, trace.faultable)
 
 
+# events per tile of the order scorer's counting scheme (see
+# :func:`order_release_times`): the pairwise part costs L * ORDER_TILE
+# comparisons a genome on the VPU, the table part H * H * L / ORDER_TILE
+# multiply-adds on the MXU. At most 256, so that a tile's count of one
+# bucket stays small.
+ORDER_TILE = 64
+_I32_MAX = 2 ** 31 - 1
+
+
+class _OrderTables(NamedTuple):
+    """What the order scorer needs of ONE trace and no table: its events
+    in the static order ``(window, arrival, position)``, cut into tiles
+    of :data:`ORDER_TILE`, and per tile the per-bucket counts of the
+    window's events that lie in OTHER tiles. Built from the trace alone,
+    so under a population ``vmap`` it is computed once, not per genome.
+    """
+
+    hs: jax.Array  # i32[Nt, B] bucket of the event at each sorted slot
+    same: jax.Array  # bool[Nt, B, B] [i, j]: j live, in i's window
+    before: jax.Array  # i32[B, B] [i, j]: 1 where j < i
+    head: jax.Array  # f32[Nt, B, H] one-hot of the event's bucket if it
+    tail: jax.Array  # is live and in the tile's first / last window
+    prev: jax.Array  # f32[Nt, H] earlier tiles' events of the head window
+    nxt: jax.Array  # f32[Nt, H] later tiles' events of the tail window
+    slot: jax.Array  # i32[L] sorted slot of each event of the trace
+    base: jax.Array  # f32[L] close of each event's window
+
+
+def _order_tables(trace: TraceArrays, window: float, H: int
+                  ) -> _OrderTables:
+    L = trace.hint_ids.shape[0]
+    B = min(ORDER_TILE, L)
+    nt = -(-L // B)
+    pad = nt * B - L
+    if window > 0:
+        win = jnp.floor(trace.arrival / window).astype(jnp.int32)
+    else:
+        win = jnp.zeros((L,), jnp.int32)
+    win = jnp.where(trace.mask, win, _I32_MAX)
+    base = (win.astype(jnp.float32) + 1.0) * window  # window close time
+    # window-major, then arrival, then position (the sort is stable):
+    # the order in which buckets of EQUAL priority are released
+    order = jnp.lexsort((trace.arrival, win)).astype(jnp.int32)
+    slot = jnp.zeros((L,), jnp.int32).at[order].set(
+        jnp.arange(L, dtype=jnp.int32))
+    hs = jnp.pad(trace.hint_ids[order], (0, pad)).reshape(nt, B)
+    ws = jnp.pad(win[order], (0, pad),
+                 constant_values=_I32_MAX).reshape(nt, B)
+    live = jnp.pad(trace.mask[order], (0, pad)).reshape(nt, B)
+    same = live[:, None, :] & (ws[:, :, None] == ws[:, None, :])
+    before = jnp.tril(jnp.ones((B, B), jnp.int32), -1)  # [i, j]: j < i
+    head_w, tail_w = ws[:, 0], ws[:, -1]
+    is_head = live & (ws == head_w[:, None])
+    is_tail = live & (ws == tail_w[:, None])
+    onehot = hs[:, :, None] == jnp.arange(H, dtype=jnp.int32)
+    c_head = jnp.sum(onehot & is_head[:, :, None], axis=1,
+                     dtype=jnp.int32)  # [Nt, H]
+    c_tail = jnp.sum(onehot & is_tail[:, :, None], axis=1,
+                     dtype=jnp.int32)
+    # a window that spans tiles: what tile t's head window holds in
+    # EARLIER tiles (every one of them ends in that window), and what
+    # its tail window holds in LATER tiles (each starts in it)
+    ti = jnp.arange(nt)
+    m_prev = (ti[None, :] < ti[:, None]) & (
+        tail_w[None, :] == head_w[:, None])
+    m_next = (ti[None, :] > ti[:, None]) & (
+        head_w[None, :] == tail_w[:, None])
+    prev = jnp.sum(jnp.where(m_prev[:, :, None], c_tail[None], 0), axis=1)
+    nxt = jnp.sum(jnp.where(m_next[:, :, None], c_head[None], 0), axis=1)
+    return _OrderTables(
+        hs, same, before,
+        (onehot & is_head[:, :, None]).astype(jnp.float32),
+        (onehot & is_tail[:, :, None]).astype(jnp.float32),
+        prev.astype(jnp.float32), nxt.astype(jnp.float32), slot, base)
+
+
+def _exact_dot(a: jax.Array, b: jax.Array, dims) -> jax.Array:
+    """A float32 contraction of small integers with a 0/1 operand that
+    is exact on the MXU: ``Precision.HIGH`` splits each float32 operand
+    into a high and a low bfloat16 part and keeps the three leading
+    products; an integer below 2**16 is the sum of its two parts
+    exactly, the 0/1 operand has no low part, and the float32
+    accumulation of integers is exact below 2**24. (One bfloat16 pass
+    would round a count over 256.)"""
+    return jax.lax.dot_general(
+        a, b, dims, precision=jax.lax.Precision.HIGH,
+        preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("gap", "window"))
 def order_release_times(prio: jax.Array, trace: TraceArrays,
                         gap: float, window: float = 0.0) -> jax.Array:
     """Counterfactual release times under *windowed permutation*
     scheduling — what the policy's reorder buffer (policy/tpu.py
     release_mode "reorder") actually realizes: events are batched into
     arrival windows of ``window`` seconds and each batch is released in
-    ``(prio[hint], arrival)`` order, ``gap`` seconds apart, starting at
-    the window's end. ``window=0`` scores one global window (the upper
-    bound of reachable permutations). Only co-pending events can be
-    permuted, so scored interleavings stay executable.
+    ``(prio[hint], arrival)`` order (ties by position in the trace),
+    ``gap`` seconds apart, starting at the window's end. ``window=0``
+    scores one global window (the upper bound of reachable
+    permutations). Only co-pending events can be permuted, so scored
+    interleavings stay executable.
+
+    An event's slot is a COUNT, not a position in a sort: the events of
+    its window whose ``(priority, arrival, position)`` is smaller. The
+    trace's events are put in their static order (window, arrival,
+    position) once and cut into tiles of :data:`ORDER_TILE`
+    (:func:`_order_tables`: nothing there depends on the table, so a
+    population ``vmap`` computes it once per trace). A genome then
+    pays (a) inside a tile, a pairwise comparison of the tile's
+    priorities, (b) across tiles, two contractions of its ``[H, H]``
+    priority comparisons (``<`` against events in later tiles, ``<=``
+    against events in earlier ones: equal priorities go by the static
+    order) with per-tile per-bucket counts, ``H * H * 2 L / ORDER_TILE``
+    multiply-adds that the MXU takes, and (c) one gather per event.
+    No sort, scan or scatter of L events per genome.
+
+    Exactness: the counts are built in int32 and enter the contraction
+    as float32 at ``Precision.HIGH`` (:func:`_exact_dot`: one
+    bfloat16 pass holds integers only to 256, and a window may hold
+    more events of one bucket), accumulate in float32, exact below
+    2**24, and come back as int32; the pairwise part is summed in
+    int32.
 
     1-D trace only (vmap over genomes; use score_population_multi for
-    stacked traces). Masked positions sort last and stay BIG.
+    stacked traces). Masked positions are absent and stay BIG.
+    Jitted in its own right, as :func:`first_occurrence_blockwise` is
+    and for the same reason: the reply's re-rank calls the scorer
+    eagerly; inside the fused island step the jit is inlined.
     """
     if trace.hint_ids.ndim != 1:
         raise ValueError(
             "order_release_times takes a single [L] trace; got shape "
             f"{trace.hint_ids.shape}"
         )
-    L = trace.hint_ids.shape[0]
-    if window > 0:
-        win = jnp.floor(trace.arrival / window).astype(jnp.int32)
-    else:
-        win = jnp.zeros((L,), jnp.int32)
-    win = jnp.where(trace.mask, win, jnp.iinfo(jnp.int32).max)
-    key = jnp.where(trace.mask, prio[trace.hint_ids], jnp.inf)
-    # window-major, then priority, then arrival (stable within window)
-    order = jnp.lexsort((trace.arrival, key, win))  # [L] ids by rank
-    idx = jnp.arange(L, dtype=jnp.int32)
-    # within-window rank, computed in sorted order: position minus the
-    # start index of the event's window segment (cummax of segment
-    # starts — no bound on the number of windows)
-    sw = win[order]
-    is_start = jnp.concatenate(
-        [jnp.ones((1,), bool), sw[1:] != sw[:-1]])
-    seg_start = jax.lax.cummax(jnp.where(is_start, idx, 0))
-    within_sorted = idx - seg_start
-    within = jnp.zeros((L,), jnp.int32).at[order].set(within_sorted)
-    base = (win.astype(jnp.float32) + 1.0) * window  # window close time
-    t = base + within.astype(jnp.float32) * gap
-    return jnp.where(trace.mask, t, BIG)
+    with jax.named_scope("nmz_score"):
+        H = prio.shape[0]
+        tb = _order_tables(trace, window, H)
+        nt, B = tb.hs.shape
+        # (a) inside the tile: j is released before i. The priorities
+        # go as int32 keys of the same order (the float's bits, the
+        # negative ones flipped; -0.0 first made +0.0), so that "<= for
+        # an earlier j, < for a later one" is ONE comparison, against
+        # the key plus one where j is earlier
+        bits = jax.lax.bitcast_convert_type(
+            jnp.where(prio == 0.0, 0.0, prio), jnp.int32)
+        key = (bits ^ ((bits >> 31) & 0x7FFFFFFF))[tb.hs]  # [Nt, B]
+        rank = jnp.sum(
+            tb.same & (key[:, None, :] < key[:, :, None] + tb.before),
+            axis=-1, dtype=jnp.int32)  # [Nt, B]
+        if nt > 1:
+            # (b) the window's events in other tiles, by bucket: two
+            # [Nt, H] tables per genome, then each event's entry by a
+            # one-hot contraction (a gather would have XLA transpose
+            # the whole table first)
+            lt = (prio[None, :] < prio[:, None]).astype(jnp.float32)
+            le = (prio[None, :] <= prio[:, None]).astype(jnp.float32)
+            table = (((1,), (1,)), ((), ()))  # [h, h']: h' before h
+            entry = (((2,), (1,)), ((0,), (0,)))  # tile by tile
+            other = (_exact_dot(tb.head, _exact_dot(tb.prev, le, table),
+                                entry)
+                     + _exact_dot(tb.tail, _exact_dot(tb.nxt, lt, table),
+                                  entry))  # [Nt, B]
+            rank = rank + other.astype(jnp.int32)
+        within = rank.reshape(nt * B)[tb.slot]  # (c) back to trace order
+        t = tb.base + within.astype(jnp.float32) * gap
+        return jnp.where(trace.mask, t, BIG)
 
 
 def first_occurrence(t: jax.Array, trace: TraceArrays, H: int) -> jax.Array:
@@ -222,9 +347,9 @@ def _genome_features(
 
     Delay-mode traces longer than ``LONG_TRACE_THRESHOLD`` take the
     blockwise scan (bounded memory under a population vmap — no [P, L]
-    intermediates); everything else takes the dense path. The dispatch is
-    on static shape, so each jit specialization compiles exactly one
-    branch."""
+    intermediates); shorter ones the dense path; order mode its own
+    (:func:`scorer_branch`). The dispatch is on static shape and mode,
+    so each jit specialization compiles exactly one branch."""
     H = delays.shape[0]
     if scorer_branch(trace.hint_ids.shape[-1], order_mode) == "blockwise":
         first, ndrop = first_occurrence_blockwise(
@@ -526,19 +651,21 @@ def score_population_multi(
 
 # delay-mode traces longer than this are scored blockwise; below it the
 # dense path is cheaper (one fused gather + scatter-min). Order mode
-# always scores dense: a windowed permutation needs the whole trace in
-# one lexsort.
+# has a branch of its own at every length: its release times come from
+# :func:`order_release_times` (counts over the whole trace), then one
+# dense scatter-min.
 LONG_TRACE_THRESHOLD = 1024
 LONG_TRACE_CHUNK = 512
 
 
 def scorer_branch(L: int, order_mode: bool = False) -> str:
     """The first-occurrence branch a step compiled for padded trace
-    length ``L`` takes: ``"blockwise"`` | ``"dense"``. One home for the
-    rule, so that what the search counts per evolve
+    length ``L`` takes: ``"order"`` | ``"blockwise"`` | ``"dense"``. One
+    home for the rule, so that what the search counts per evolve
     (``nmz_evolve_requests_total{scorer}``) is what was compiled."""
-    return ("blockwise" if not order_mode and L > LONG_TRACE_THRESHOLD
-            else "dense")
+    if order_mode:
+        return "order"
+    return "blockwise" if L > LONG_TRACE_THRESHOLD else "dense"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

@@ -619,7 +619,15 @@ class TPUSearchPolicy(QueueBackedPolicy):
             return int((t - anchor) // w)
 
         batch.sort(key=lambda p: (win(p[2]), p[0], p[1]))
-        for i, (_prio, _seq, _t, event) in enumerate(batch):
+        # a closed window, counted when its last event is out: how many
+        # events it held, and whether its paced drain ran past the NEXT
+        # boundary (anchor + (k + 2) w for window k, which closes at
+        # anchor + (k + 1) w) — from there on the realized slots are
+        # later than the scorer's ``close + gap * rank``. The shutdown
+        # flush (no boundary) is not a window's drain and counts nothing.
+        counted = boundary is not None and anchor is not None and w > 0
+        first = 0  # index of the current window's first event
+        for i, (_prio, _seq, t, event) in enumerate(batch):
             # during shutdown, stop pacing so a large in-flight batch
             # cannot outlive the join window and lose its tail
             if i and gap > 0 and not self._stop_reorder.is_set():
@@ -628,6 +636,13 @@ class TPUSearchPolicy(QueueBackedPolicy):
             obs.queue_dwell(self.name, event.entity_id,
                             obs.latency(event, "enqueued"))
             self._emit(self._action_for(event))
+            k = win(t)
+            if counted and (i + 1 == len(batch)
+                            or win(batch[i + 1][2]) != k):
+                obs.reorder_window_drained(
+                    self.name, i + 1 - first,
+                    overran=self._now() > anchor + (k + 2) * w)
+                first = i + 1
 
     def _reorder_loop(self) -> None:
         """Tick at absolute window boundaries ``anchor + k*window`` and
@@ -1030,10 +1045,10 @@ class TPUSearchPolicy(QueueBackedPolicy):
             guidance_width=self.guidance_width,
             guidance_window=self.guidance_window,
         )
-    # order mode scores dense (a windowed permutation needs the whole
-    # trace in one lexsort — ops/schedule.py), so uncapped encoding would
-    # materialize [population, L] intermediates per generation; cap the
-    # encoded length in reorder mode unless the user set one explicitly
+    # order mode materializes [population, L] release times per
+    # reference trace and generation (ops/schedule.py): a stored run
+    # over this many events is cut in reorder mode unless the user set a
+    # length explicitly; a shorter run keeps its own length quantum
     ORDER_MODE_MAX_L = 4096
 
     def _ingest_history(self, search):

@@ -505,7 +505,7 @@ def test_an_evolve_is_counted_under_the_branch_its_step_compiled(
     refs = [enc_of(n_events, 1), enc_of(17, 2)]
     L = max(e.hint_ids.shape[0] for e in refs)
     assert sch.scorer_branch(L) == scorer
-    assert sch.scorer_branch(L, order_mode=True) == "dense"
+    assert sch.scorer_branch(L, order_mode=True) == "order"
     for _ in range(2):
         s.run(refs, generations=2)
     reg = obs.metrics.registry()
@@ -514,3 +514,52 @@ def test_an_evolve_is_counted_under_the_branch_its_step_compiled(
     assert not reg.value(spans.EVOLVE_REQUESTS, scorer=other)
     evolves = [r for r in fresh_obs.since(0)["rows"] if r[1] == "evolve"]
     assert len(evolves) == 2
+
+
+# -- reorder mode (PR 32): own length quantum, a branch of its own ----------
+
+
+def order_weights():
+    from namazu_tpu.models.search import make_score_weights
+
+    return make_score_weights(
+        release_mode="reorder", w_novelty=0.3, w_bug=1.0,
+        w_delay_cost=0.0005, w_fault_cost=0.05, tau=0.005,
+        reorder_gap=0.002, reorder_window=0.01)
+
+
+@pytest.mark.parametrize("fused_chunk", [16, 1])
+def test_an_order_mode_evolve_is_counted_as_order(fused_chunk, fresh_obs):
+    """In reorder mode the step compiles the order branch at every
+    length, and ``nmz_evolve_requests_total{scorer}`` says so."""
+    s = ScheduleSearch(cfg(fused_chunk=fused_chunk,
+                           weights=order_weights()), n_devices=1)
+    refs = [enc_of(sch.LONG_TRACE_THRESHOLD + 1, 1), enc_of(17, 2)]
+    for _ in range(2):
+        s.run(refs, generations=2)
+    reg = obs.metrics.registry()
+    assert reg.value(spans.EVOLVE_REQUESTS, scorer="order") == 2
+    for other in ("dense", "blockwise"):
+        assert not reg.value(spans.EVOLVE_REQUESTS, scorer=other)
+
+
+@pytest.mark.parametrize("n_events, explicit_L, want_L, cut", [
+    (17, 0, 128, 0),       # its own quantum, not the cap
+    (140, 0, 256, 0),
+    (300, 0, 256, 44),     # over the cap: still cut at the cap
+    (17, 512, 512, 0),     # an explicit trace_length pads to itself
+    (600, 512, 512, 88)])
+def test_a_reorder_run_is_encoded_at_its_own_quantum(
+        n_events, explicit_L, want_L, cut, fresh_obs):
+    """``order_mode_max_l`` is the cap over which a run is cut (the
+    benchmark's reference refuses such a history), not the length every
+    stored run is padded to."""
+    s = ScheduleSearch(cfg(weights=order_weights()), n_devices=1)
+    storage = ListStorage([(make_run(1, n_events), True),
+                           (make_run(2, 17), False)])
+    refs = ingest_history(s, storage, IngestParams(
+        H=H, L=explicit_L, release_mode="reorder",
+        order_mode_max_l=256, reference_mode="recent"))
+    assert [r.hint_ids.shape[0] for r in refs] == [want_L]
+    assert refs[0].truncated == cut
+    assert int(refs[0].mask.sum()) == min(n_events, want_L)

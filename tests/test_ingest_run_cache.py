@@ -311,6 +311,26 @@ def test_records_of_other_parameters_are_not_taken(tmp_path, parsed, other):
     assert parsed == []
 
 
+def test_a_cap_that_pads_and_one_that_cuts_keep_records_apart(
+        tmp_path, parsed):
+    """An explicit ``trace_length`` equal to ``order_mode_max_l`` pads
+    every run to it; the reorder default holds the same cap and encodes
+    a run at its own quantum. One run directory, one process: a record
+    of each, and neither request is handed the other's length."""
+    st = make_storage(tmp_path / "st", 3)
+    cuts = PARAMS._replace(release_mode="reorder", order_mode_max_l=256,
+                           reference_mode="recent")
+    pads = cuts._replace(L=256)
+    for params, want_L, want_parsed in (
+            (cuts, 128, [0, 1, 2]), (pads, 256, [0, 1, 2]),
+            (cuts, 128, []), (pads, 256, [])):
+        del parsed[:]
+        got = ingested(st, params)
+        assert parsed == want_parsed
+        assert {r[0].shape[0] for r in got["references"]} == {want_L}
+        assert_same(got, ingested(unsigned(st), params))
+
+
 def test_a_backend_without_signatures_keeps_nothing(tmp_path, records,
                                                     parsed, fresh_obs):
     st = make_storage(tmp_path / "st", 5)

@@ -295,3 +295,216 @@ def test_policy_realized_order_equals_scored_order():
     scored = [hints[i] for i in np.argsort(t[:4], kind="stable")]
     assert realized == scored == ["pC", "pB", "pA", "pD"], (
         realized, scored)
+
+
+# -- the counting order scorer (PR 32) ---------------------------------------
+#
+# order_release_times assigns slots by COUNTING (tiles of ORDER_TILE
+# events, a pairwise part inside the tile, bucket-count contractions
+# across tiles); these cases hold it, event by event, to a sort written
+# out in numpy and to the benchmark's independent reference.
+
+
+def _sorted_release(prio, hint_ids, arrival, mask, gap, window):
+    """(window, priority, arrival, position) order by an explicit sort."""
+    prio = np.asarray(prio, np.float32)
+    arrival = np.asarray(arrival, np.float32)
+    t = np.full(len(hint_ids), BIG, np.float32)
+    if window > 0:
+        win = np.floor(arrival / np.float32(window)).astype(np.int64)
+    else:
+        win = np.zeros(len(hint_ids), np.int64)
+    live = [e for e in range(len(hint_ids)) if mask[e]]
+    live.sort(key=lambda e: (win[e], prio[hint_ids[e]], arrival[e], e))
+    seen: dict = {}
+    for e in live:
+        r = seen.get(win[e], 0)
+        seen[win[e]] = r + 1
+        t[e] = (np.float32(win[e]) + np.float32(1.0)) \
+            * np.float32(window) + np.float32(r) * np.float32(gap)
+    return t
+
+
+def _case(name):
+    """(hint_ids, arrival, mask, tables [S, H], H, window) by name."""
+    import zlib
+
+    rng = np.random.RandomState(zlib.crc32(name.encode()))
+    Hc = 16
+    n, Lc, window = 200, 256, 0.5
+    hints = rng.randint(0, Hc, n)
+    arrival = np.sort(rng.uniform(0, 3.0, n)).astype(np.float32)
+    tables = rng.uniform(0, 0.1, (6, Hc)).astype(np.float32)
+    if name == "one_window":
+        window = 0.0
+    elif name == "event_on_a_window_edge":
+        arrival[10], arrival[11], arrival[12] = 0.5, 0.5, 1.0
+        arrival = np.sort(arrival)
+    elif name == "equal_priorities_interleaved":
+        hints = np.tile([3, 7, 3, 7, 5], n // 5)
+        tables[:, 7] = tables[:, 3]  # two buckets tie in every table
+        tables[0, :] = 0.05  # and one table ties every bucket
+    elif name == "colliding_hints_one_bucket":
+        hints[:] = 4  # every hint collides into one bucket
+    elif name == "masked_padding":
+        n = 70  # Lc - n masked slots, some of them INSIDE the trace
+    elif name == "bucket_over_256_in_one_window":
+        n, Lc, window = 700, 768, 10.0
+        hints = np.where(rng.uniform(size=n) < 0.6, 2,
+                         rng.randint(0, Hc, n))
+        arrival = np.sort(rng.uniform(0, 9.0, n)).astype(np.float32)
+    elif name == "table_clipped_at_0_and_max":
+        tables = np.clip(rng.normal(0.05, 0.08, (6, Hc)), 0.0,
+                         0.1).astype(np.float32)
+    elif name == "negative_priorities_and_signed_zeros":
+        # the pairwise part compares int32 keys made of the floats' bits
+        tables = rng.uniform(-0.05, 0.05, (6, Hc)).astype(np.float32)
+        tables[:, 3], tables[:, 7], tables[:, 5] = -0.0, 0.0, -0.0
+    elif name == "burst_spanning_tiles":
+        arrival = np.sort(np.concatenate([
+            rng.uniform(0, 0.49, 150), rng.uniform(0.5, 3.0, n - 150)
+        ])).astype(np.float32)
+    elif name != "many_windows":
+        raise KeyError(name)
+    hint_ids = np.zeros(Lc, np.int32)
+    arr = np.zeros(Lc, np.float32)
+    mask = np.zeros(Lc, bool)
+    where = np.arange(n)
+    if name == "masked_padding":
+        where = np.sort(rng.choice(Lc, n, replace=False))
+    hint_ids[where] = hints[:n]
+    arr[where] = arrival[:n]
+    mask[where] = True
+    return hint_ids, arr, mask, tables, Hc, window
+
+
+ORDER_CASES = ["one_window", "many_windows", "event_on_a_window_edge",
+               "equal_priorities_interleaved",
+               "colliding_hints_one_bucket", "masked_padding",
+               "bucket_over_256_in_one_window",
+               "table_clipped_at_0_and_max", "burst_spanning_tiles",
+               "negative_priorities_and_signed_zeros"]
+
+
+@pytest.mark.parametrize("T", [1, 4])
+@pytest.mark.parametrize("name", ORDER_CASES)
+def test_counting_order_scorer_equals_a_sort(name, T):
+    """Every event's release time under every table, against the numpy
+    sort and against benchmarks/reference.py::ordered_release; T traces
+    go through the same nesting of vmaps as score_population_multi."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "benchmarks"))
+    import reference
+
+    hint_ids, arr, mask, tables, Hc, window = _case(name)
+    gap = 0.08
+    traces = []
+    for t in range(T):  # T > 1: the same events shifted, another order
+        traces.append((np.roll(hint_ids, 3 * t) if t else hint_ids,
+                       arr, np.roll(mask, 0)))
+    stacked = TraceArrays(
+        jnp.asarray(np.stack([h for h, _, _ in traces])),
+        jnp.asarray(np.stack([a for _, a, _ in traces])),
+        jnp.asarray(np.stack([m for _, _, m in traces])))
+    got = np.asarray(jax.vmap(lambda tr: jax.vmap(
+        lambda p: order_release_times(p, tr, gap, window))(
+            jnp.asarray(tables)))(stacked))  # [T, S, L]
+    for t, (h, a, m) in enumerate(traces):
+        ref = reference.ordered_release(tables, h, a, m, gap, window)
+        for s in range(len(tables)):
+            want = _sorted_release(tables[s], h, a, m, gap, window)
+            np.testing.assert_array_equal(got[t, s], want)
+            np.testing.assert_array_equal(got[t, s], ref[s])
+    if name == "bucket_over_256_in_one_window":
+        assert (hint_ids[mask] == 2).sum() > 256
+    if name == "equal_priorities_interleaved":
+        # table 0 ties EVERY bucket: the release order is the arrival
+        # order, so the buckets' slots interleave as their arrivals do
+        first = mask & (arr < window)
+        assert first.sum() > 10
+        assert (np.diff(got[0, 0][first]) > 0).all()
+
+
+def test_trace_tables_are_built_once_not_per_genome():
+    """What depends on the trace alone stays outside the population
+    vmap: the compiled scorer holds ONE sort, of [L] keys, whatever the
+    population."""
+    hint_ids, arr, mask, tables, Hc, window = _case("many_windows")
+    trace = TraceArrays(jnp.asarray(hint_ids), jnp.asarray(arr),
+                        jnp.asarray(mask))
+    pop = jnp.asarray(np.tile(tables, (8, 1)))
+    text = jax.jit(lambda pp, tr: jax.vmap(
+        lambda p: order_release_times(p, tr, 0.08, window))(pp)
+    ).lower(pop, trace).compile().as_text()
+    sorts = [ln for ln in text.splitlines()
+             if " sort(" in ln and "ENTRY" not in ln]
+    assert sorts, "the static order is a sort of the trace"
+    assert all(f"[{len(pop)}," not in ln.split(" sort(")[0]
+               for ln in sorts), sorts
+
+
+@pytest.mark.parametrize("gens", [2, 5])
+def test_fused_step_in_order_mode_is_chunk_independent(gens):
+    """G generations in one dispatch = G dispatches of one, to the bit,
+    with the counting order scorer inside the step (what
+    tests/test_fused_loop.py holds for delay mode), at the island level
+    and end to end across ``fused_chunk``."""
+    from namazu_tpu.models.ga import GAConfig
+    from namazu_tpu.models.search import (
+        ScheduleSearch, SearchConfig, make_score_weights)
+    from namazu_tpu.parallel.islands import (
+        init_island_state, make_fused_island_step)
+    from namazu_tpu.parallel.mesh import make_mesh
+
+    weights = make_score_weights(
+        release_mode="reorder", w_novelty=0.3, w_bug=1.0,
+        w_delay_cost=0.0005, w_fault_cost=0.05, tau=0.005,
+        reorder_gap=0.08, reorder_window=0.5)
+    assert weights.order_mode and weights.tau == pytest.approx(0.04)
+    hint_ids, arr, mask, _t, Hc, _w = _case("burst_spanning_tiles")
+    trace = TraceArrays(jnp.asarray(hint_ids), jnp.asarray(arr),
+                        jnp.asarray(mask))
+    pairs = jnp.asarray(te.sample_pairs(K, Hc, 0))
+    archive = jnp.full((16, K), 0.5, jnp.float32)
+    failures = jnp.full((4, K), 0.5, jnp.float32)
+    mesh, cfg, key = make_mesh(8), GAConfig(max_delay=0.1), \
+        jax.random.PRNGKey(1)
+    one = make_fused_island_step(mesh, cfg, weights, migrate_k=2,
+                                 generations=1)
+    s_one = init_island_state(jax.random.PRNGKey(0), 64, Hc, cfg)
+    hist_one = []
+    for _ in range(gens):
+        s_one, h = one(s_one, key, trace, pairs, archive, failures)
+        hist_one.append(np.asarray(h))
+    fused = make_fused_island_step(mesh, cfg, weights, migrate_k=2,
+                                   generations=gens)
+    s_fu, hist = fused(init_island_state(jax.random.PRNGKey(0), 64, Hc,
+                                         cfg),
+                       key, trace, pairs, archive, failures)
+    assert np.array_equal(np.concatenate(hist_one), np.asarray(hist))
+    for a, b in ((s_one.pop.delays, s_fu.pop.delays),
+                 (s_one.best_fitness, s_fu.best_fitness),
+                 (s_one.best_delays, s_fu.best_delays)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+    def enc_of(n, seed):
+        rng = np.random.RandomState(seed)
+        return te.encode_event_stream(
+            [f"h{rng.randint(12)}" for _ in range(n)],
+            arrivals=sorted((3 * rng.rand(n)).tolist()), H=Hc)
+
+    base = SearchConfig(H=Hc, K=K, archive_size=16, failure_size=8,
+                        population=64, migrate_k=2, seed=3,
+                        ga=GAConfig(max_delay=0.1), weights=weights)
+    a = ScheduleSearch(base._replace(fused_chunk=1))
+    b = ScheduleSearch(base._replace(fused_chunk=16))
+    refs = [enc_of(140, 1), enc_of(90, 2)]
+    for s in (a, b):
+        s.add_executed_trace(enc_of(100, 5))
+        s.add_failure_trace(enc_of(120, 6))
+    ra, rb = (s.run(refs, generations=gens + 3) for s in (a, b))
+    assert np.array_equal(ra.delays, rb.delays)
+    assert ra.fitness == rb.fitness
