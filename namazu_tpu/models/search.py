@@ -10,6 +10,7 @@ the reference has no equivalent for (SURVEY.md section 5.4).
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import time
 from typing import NamedTuple, Optional
@@ -138,6 +139,15 @@ def _device_row_update(buf, row, slot: int):
         _row_update_jit = jax.jit(f, donate_argnums=(0,))
     return _row_update_jit(buf, jnp.asarray(row),
                            jnp.asarray(slot, jnp.int32))
+
+
+@functools.lru_cache(maxsize=None)
+def _gathered(mesh):
+    """Compiled device-to-device all-gather over ``mesh`` (one chip: no-op)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return jax.jit(lambda x: x, out_shardings=NamedSharding(mesh, P()))
 
 
 class _ResidentTraces:
@@ -1265,8 +1275,7 @@ class ScheduleSearch(SearchBase):
             return None
         feats, labels = self.labeled_archive()
         pos = int((labels > 0.5).sum())
-        neg = int(len(labels) - pos)
-        if min(pos, neg) < self.MIN_CLASS_EXAMPLES:
+        if min(pos, len(labels) - pos) < self.MIN_CLASS_EXAMPLES:
             return None  # nothing reliably learnable yet
         if self._surrogate is None:
             from namazu_tpu.models.surrogate import RewardSurrogate
@@ -1274,7 +1283,8 @@ class ScheduleSearch(SearchBase):
             self._surrogate = RewardSurrogate(
                 K=self._surrogate_input_dims(), seed=self.cfg.seed)
         self._surrogate.train(feats, labels, epochs=4,
-                              seed=self.cfg.seed + self.generations_run)
+                              seed=self.cfg.seed + self.generations_run,
+                              capacity=self.cfg.archive_size)
         return self._surrogate
 
     def _candidate_guidance(self, delays: np.ndarray, encs):
@@ -1306,69 +1316,59 @@ class ScheduleSearch(SearchBase):
                         nov_scale=None, encs=()) -> Optional[BestSchedule]:
         """Re-rank the evolved population's fitness top-k; return the
         winner (None = nothing to re-rank with — fitness argmax).
-
         The base score is predicted P(reproduce): the local online MLP
-        once it has enough of both outcome classes, before that — the
-        cold-start window — the shared knowledge-service surrogate
-        (``remote_surrogate``), and with neither trained, the top-k's
-        min-max-normalized fitness. With a guidance map wired
-        (doc/search.md) the pick becomes COVERAGE-GUIDED:
-        ``cfg.guidance_bonus`` times each candidate's predicted
-        relation-coverage gain is added on top, so among comparably
-        promising schedules the one predicted to exercise untested
-        orderings wins the next wall-clock replay. Without a map the
-        behavior is exactly the pre-guidance surrogate re-rank."""
+        once it has both outcome classes (train, score, pick: three
+        compiled calls on the resident population, ONE fetch); in the
+        cold-start window ``remote_surrogate``'s; with neither, the
+        top-k's normalized fitness. A guidance map adds
+        ``cfg.guidance_bonus`` x its gain (for both the k rows cross)."""
         surrogate = self._train_surrogate()
         remote = self.remote_surrogate if surrogate is None else None
         guided = self.guidance is not None and len(encs) > 0
-        if self.cfg.surrogate_topk <= 0:
-            return None  # explicit knob: raw fitness argmax only
-        if surrogate is None and remote is None and not guided:
-            return None
-        import jax.numpy as jnp
+        if self.cfg.surrogate_topk <= 0 or (
+                surrogate is None and remote is None and not guided):
+            return None  # the knob is off, or nothing to re-rank with
+        import jax
 
-        from namazu_tpu.ops.schedule import score_population_multi
+        from namazu_tpu.models.surrogate import top_rows
+        from namazu_tpu.ops import schedule
 
         k = min(self.cfg.surrogate_topk, self.population)
-        # de-shard the island population (a few MB) — this re-score runs
-        # outside shard_map, where scatter on an @i-sharded operand is
-        # ambiguous; trace arrives stacked [T, L] from
-        # _device_inputs_fused
-        delays_np, faults = self._fetch_population()
-        delays = jnp.asarray(delays_np)
-        fitness, feats = score_population_multi(
+        # ONE chip's copy of the islands' shards (the Mosaic pair kernel
+        # cannot be partitioned); self._state keeps its sharding
+        delays, faults = (x.addressable_shards[0].data
+                          for x in _gathered(self.mesh)(self._state.pop))
+        # by its module attribute and OUTSIDE jit: a launcher may wrap
+        # the name to keep the [P] fitness; trace arrives stacked [T, L]
+        fitness, feats = schedule.score_population_multi(
             delays, trace, pairs, archive, failures, self.cfg.weights,
-            faults=None if self._coin is None else jnp.asarray(faults),
-            coin=None if self._coin is None else jnp.asarray(self._coin),
-            novelty_scale=nov_scale,
-        )
-        top = np.asarray(jnp.argsort(-fitness)[:k])
-        # features averaged over the reference traces, like the fitness
-        cand_feats = np.asarray(feats[top].mean(axis=1))
-        gains = frags = None
-        if guided:
-            gains, frags = self._candidate_guidance(delays_np[top], encs)
+            faults=None if self._coin is None else faults,
+            coin=self._dev_coin, novelty_scale=nov_scale)
+        if remote is None and not guided:
+            table, drops, fit = jax.device_get(
+                surrogate.pick(fitness, feats, delays, faults, k))
+            obs.rerank_request("compiled")
+            return BestSchedule(table, drops, float(fit))
+        cand, top_delays, top_faults, f = jax.device_get(
+            top_rows(fitness, feats, delays, faults, k))
+        obs.rerank_request("host")
+        gains, frags = (self._candidate_guidance(top_delays, encs)
+                        if guided else (None, None))
         base = None
         if surrogate is not None or remote is not None:
-            full = (cand_feats if frags is None
-                    else np.hstack([cand_feats, frags]))
+            full = cand if frags is None else np.hstack([cand, frags])
             base = (surrogate.predict(full) if surrogate is not None
                     else remote(full))
         if base is None:
             if gains is None:
                 return None  # outage/untrained, no guidance: argmax
-            f = np.asarray(fitness)[top]
             span = float(f.max() - f.min())
-            base = ((f - f.min()) / span if span > 0
-                    else np.zeros_like(f))
+            base = (f - f.min()) / span if span > 0 else np.zeros_like(f)
         score = (np.asarray(base) if gains is None
                  else np.asarray(base) + self.cfg.guidance_bonus * gains)
-        winner = int(top[int(np.argmax(score))])
-        return BestSchedule(
-            delays=np.asarray(delays[winner]),
-            faults=faults[winner],
-            fitness=float(fitness[winner]),
-        )
+        winner = int(np.argmax(score))
+        return BestSchedule(top_delays[winner], top_faults[winner],
+                            float(f[winner]))
 
     def best(self) -> BestSchedule:
         return BestSchedule(

@@ -10,7 +10,8 @@ real replays — the "learned surrogate" of BASELINE.json config 5.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Tuple
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -34,95 +35,94 @@ class SurrogateMLP(nn.Module):
 class SurrogateState(NamedTuple):
     params: dict
     opt_state: optax.OptState
-    steps: jax.Array
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def top_rows(fitness, feats, delays, faults, k: int):
+    """A scored population's fitness top-k, best first, on the device:
+    features averaged over the T traces [k, K], tables, faults, fitness."""
+    top = jnp.argsort(-fitness)[:k]
+    return feats[top].mean(axis=1), delays[top], faults[top], fitness[top]
+
+
+@functools.lru_cache(maxsize=None)
+def _programs(hidden: int, lr: float):
+    """``(model, optimiser, train, predict, pick)`` of one ``(hidden,
+    lr)``: a process's surrogates share ONE compiled program per shape."""
+    model, tx = SurrogateMLP(hidden=hidden), optax.adam(lr)
+
+    def loss_fn(params, feats, labels, weight):
+        per = optax.sigmoid_binary_cross_entropy(
+            model.apply(params, feats), labels)
+        # mean over the REAL rows: a partial batch's padding has weight 0
+        return (per * weight).sum() / jnp.maximum(weight.sum(), 1.0)
+
+    def train(state: SurrogateState, feats, labels, idx):
+        """(state, last real step's loss) after one scan of minibatch
+        steps over rows ``idx`` i32[S, B] (-1 = padding) of ``feats``."""
+        def step(carry, rows):
+            state = carry[0]
+            weight = (rows >= 0).astype(jnp.float32)
+            rows = jnp.maximum(rows, 0)
+            loss, grads = jax.value_and_grad(loss_fn)(
+                state.params, feats[rows] * weight[:, None],
+                labels[rows] * weight, weight)
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            new = SurrogateState(
+                optax.apply_updates(state.params, updates), opt_state)
+            # a step of padding keeps the WHOLE old state: on a zero
+            # gradient Adam still decays, counts and moves the parameters
+            return jax.tree.map(
+                lambda a, b: jnp.where(weight.sum() > 0, a, b),
+                (new, loss), carry), None
+
+        return jax.lax.scan(step, (state, jnp.zeros(())), idx)[0]
+
+    def predict(params, feats):
+        return jax.nn.sigmoid(model.apply(params, feats))
+
+    def pick(params, fitness, feats, delays, faults, k: int):
+        cand, *rows = top_rows(fitness, feats, delays, faults, k)
+        winner = jnp.argmax(predict(params, cand))
+        return tuple(x[winner] for x in rows)
+
+    return (model, tx, jax.jit(train), jax.jit(predict),
+            jax.jit(pick, static_argnames=("k",)))
 
 
 class RewardSurrogate:
     def __init__(self, K: int, hidden: int = 128, lr: float = 1e-3,
                  seed: int = 0):
-        self.model = SurrogateMLP(hidden=hidden)
-        self.tx = optax.adam(lr)
-        params = self.model.init(
-            jax.random.PRNGKey(seed), jnp.zeros((1, K), jnp.float32)
-        )
-        self.state = SurrogateState(
-            params=params,
-            opt_state=self.tx.init(params),
-            steps=jnp.zeros((), jnp.int32),
-        )
-
-        def loss_fn(params, feats, labels, weight):
-            logits = self.model.apply(params, feats)
-            per = optax.sigmoid_binary_cross_entropy(logits, labels)
-            # weighted mean over the REAL rows only: partial batches are
-            # padded to a fixed shape with zero-weight rows, so the loss
-            # (and gradient) equals the unpadded mean while every batch
-            # hits one compiled specialization
-            return (per * weight).sum() / jnp.maximum(weight.sum(), 1.0)
-
-        @jax.jit
-        def train_step(state: SurrogateState, feats, labels, weight):
-            loss, grads = jax.value_and_grad(loss_fn)(
-                state.params, feats, labels, weight
-            )
-            updates, opt_state = self.tx.update(grads, state.opt_state,
-                                                state.params)
-            params = optax.apply_updates(state.params, updates)
-            return SurrogateState(params, opt_state, state.steps + 1), loss
-
-        @jax.jit
-        def predict_fn(state: SurrogateState, feats):
-            return jax.nn.sigmoid(self.model.apply(state.params, feats))
-
-        self._train_step = train_step
-        self._predict = predict_fn
+        (self.model, self.tx, self._train, self._predict,
+         self._pick) = _programs(hidden, lr)
+        params = self.model.init(jax.random.PRNGKey(seed), jnp.zeros((1, K)))
+        self.state = SurrogateState(params, self.tx.init(params))
 
     def train(self, feats: np.ndarray, labels: np.ndarray,
-              epochs: int = 1, batch: int = 256,
-              seed: int = 0) -> float:
-        """Train on (feats [N,K], labels [N] in {0,1}); returns last loss.
-
-        Every minibatch is padded to the fixed ``batch`` shape with
-        zero-WEIGHT rows (the weighted loss ignores them exactly), so
-        the jitted train step compiles ONCE per feature width no matter
-        how the archive's occupancy grows between rounds — pre-padding,
-        each new occupancy's partial tail batch was a fresh
-        trace+compile in the middle of a campaign (compile-count and
-        padded-vs-exact equality pinned by tests/test_fused_loop.py)."""
+              epochs: int = 1, batch: int = 256, seed: int = 0,
+              capacity: int = 0) -> jax.Array:
+        """Fit (feats [N, K], labels [N] in {0, 1}) in ONE compiled call;
+        the last loss stays on the device (nothing waits for it). Per
+        epoch the minibatches of ``RandomState(seed).permutation``, their
+        count padded to a power of two holding ``capacity`` rows."""
         n = len(feats)
-        K = feats.shape[1]
+        steps = 1 << max(-(-max(n, capacity) // batch) - 1, 0).bit_length()
+        pad = steps * batch - n
+        idx = np.full((epochs, n + pad), -1, np.int32)
         rng = np.random.RandomState(seed)
-        loss = 0.0
-        for _ in range(epochs):
-            order = rng.permutation(n)
-            for i in range(0, n, batch):
-                idx = order[i : i + batch]
-                nb = len(idx)
-                f = np.zeros((batch, K), np.float32)
-                f[:nb] = feats[idx]
-                lb = np.zeros((batch,), np.float32)
-                lb[:nb] = labels[idx]
-                w = np.zeros((batch,), np.float32)
-                w[:nb] = 1.0
-                self.state, l = self._train_step(
-                    self.state,
-                    jnp.asarray(f),
-                    jnp.asarray(lb),
-                    jnp.asarray(w),
-                )
-                loss = float(l)
+        for e in range(epochs):
+            idx[e, :n] = rng.permutation(n)
+        self.state, loss = self._train(
+            self.state, np.pad(feats, ((0, pad), (0, 0))),
+            np.pad(labels, (0, pad)), idx.reshape(epochs * steps, batch))
         return loss
 
     def predict(self, feats: np.ndarray) -> np.ndarray:
         """P(reproduce bug) per feature vector."""
-        return np.asarray(self._predict(self.state, jnp.asarray(feats)))
+        return np.asarray(self._predict(self.state.params, feats))
 
-    def rerank(self, feats: np.ndarray,
-               top: Optional[int] = None) -> Tuple[np.ndarray, np.ndarray]:
-        """Indices (desc) + probabilities; used to pick which GA elites get
-        real wall-clock replays."""
-        p = self.predict(feats)
-        order = np.argsort(-p)
-        if top is not None:
-            order = order[:top]
-        return order, p[order]
+    def pick(self, fitness, feats, delays, faults, k: int):
+        """``(table, faults, fitness)`` of the likeliest top-k row."""
+        return self._pick(self.state.params, fitness, feats, delays,
+                          faults, k=k)

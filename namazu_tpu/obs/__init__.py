@@ -127,6 +127,7 @@ from namazu_tpu.obs.spans import (  # noqa: F401
     ingest_embed_call,
     ingest_events,
     ingest_runs,
+    rerank_request,
     search_device_trace,
     search_phase,
     search_phase_observed,

@@ -70,6 +70,7 @@ INGEST_EMBED_CALLS = "nmz_ingest_embed_calls_total"
 INGEST_EVENTS = "nmz_ingest_events_total"
 INGEST_CACHED_RUNS = "nmz_ingest_cached_runs_total"
 EVOLVE_REQUESTS = "nmz_evolve_requests_total"
+RERANK_REQUESTS = "nmz_rerank_requests_total"
 # the policy's reorder buffer (release_mode "reorder"): windows drained,
 # those whose paced drain ended after the NEXT window's boundary (the
 # scorer assumes a window's slots run from its own close), and how many
@@ -1426,6 +1427,19 @@ def evolve_request(scorer: str) -> None:
         EVOLVE_REQUESTS, "evolve requests by the scorer branch of the "
                          "compiled step", ("scorer",),
     ).labels(scorer=scorer).inc()
+
+
+def rerank_request(path: str) -> None:
+    """One reply re-ranked from the population's fitness top-k
+    (``models/search.py::_surrogate_pick``): ``compiled`` when the pick
+    finished on the device (one fetch of the winner), ``host`` when a
+    remote surrogate or a guidance map took the k rows to the host.
+    Nothing to re-rank with counts nothing."""
+    if not metrics.enabled():
+        return
+    metrics.get().counter(
+        RERANK_REQUESTS, "re-ranked replies by where the pick finished",
+        ("path",)).labels(path=path).inc()
 
 
 def search_device_trace(path: str) -> None:

@@ -276,8 +276,8 @@ def order_release_times(prio: jax.Array, trace: TraceArrays,
     1-D trace only (vmap over genomes; use score_population_multi for
     stacked traces). Masked positions are absent and stay BIG.
     Jitted in its own right, as :func:`first_occurrence_blockwise` is
-    and for the same reason: the reply's re-rank calls the scorer
-    eagerly; inside the fused island step the jit is inlined.
+    and for the same reason: a caller may run the scorer op by op;
+    inside a compiled program (the island step) the jit is inlined.
     """
     if trace.hint_ids.ndim != 1:
         raise ValueError(
@@ -597,13 +597,13 @@ def score_population_multi(
     archive_n: Optional[jax.Array] = None,  # dynamic i32 occupancy
     failure_n: Optional[jax.Array] = None,  # dynamic i32 occupancy
 ) -> tuple[jax.Array, jax.Array]:
-    """Fitness aggregated over T recorded traces.
-
-    A schedule that is only novel against one historical run is usually
-    just exploiting that run's noise; averaging novelty/bug affinity over
-    every stored trace rewards schedules whose *interleaving structure*
-    transfers. Returns (fitness f32[P], feats f32[P, T, K]).
-    """
+    """Fitness averaged over T recorded traces (novelty against ONE run
+    is mostly its noise): (fitness [P], feats [P, T, K]). ONE compiled
+    program on concrete arrays (the re-rank), inline under a trace."""
+    args = (delays, traces, pairs, archive, failure_feats, weights, faults,
+            coin, novelty_scale, archive_n, failure_n)
+    if _all_concrete(args):
+        return _score_population_multi_jit(*args)
     def per_trace(tr: TraceArrays):
         """(feats [P, K], drop fraction [P]) against one trace."""
         if faults is None:
@@ -645,6 +645,17 @@ def score_population_multi(
         - fault_pen
     )
     return fitness, feats
+
+
+_score_population_multi_jit = jax.jit(score_population_multi,
+                                      static_argnames=("weights",))
+
+
+def _all_concrete(args) -> bool:
+    """No array of ``args`` a tracer, and jit not disabled (where the
+    compiled twin would call straight back)."""
+    return not (jax.config.jax_disable_jit or any(
+        isinstance(x, jax.core.Tracer) for x in jax.tree.leaves(args)))
 
 
 # -- long traces: blockwise first-occurrence --------------------------------
@@ -689,13 +700,13 @@ def first_occurrence_blockwise(
     this framework's long sequences). Fault drops are applied per chunk so
     a vmapped population never materialises a [P, L] drop mask.
 
-    Jitted in its own right: the reply's re-rank calls the scorer
-    eagerly (``models/search.py::_surrogate_pick``), and a bare
-    ``lax.scan`` dispatched eagerly is lowered anew at every call — its
-    body is a fresh closure, so no cache holds it — which a warm
-    sidecar pays per request. Under a jit of its own the scan is traced
-    once per shape, eager ``vmap``s included; inside the fused island
-    step the jit is inlined.
+    Jitted in its own right: a caller may run the scorer op by op
+    (``jax.disable_jit``, a bare ``vmap`` of ``schedule_features``),
+    and a bare ``lax.scan`` dispatched eagerly is lowered anew at every
+    call — its body is a fresh closure, so no cache holds it. Under a
+    jit of its own the scan is traced once per shape, eager ``vmap``s
+    included; inside a compiled program (the island step, the reply's
+    re-rank) the jit is inlined.
     """
     H = delays.shape[0]
     L = hint_ids.shape[0]

