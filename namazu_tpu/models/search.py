@@ -1153,8 +1153,13 @@ class ScheduleSearch(SearchBase):
         # one completed evolve, counted where its ``evolve`` span ends
         # (the two agree over any window), under the scorer branch the
         # island step takes for these reference traces' padded length
+        order_mode = self.cfg.weights.order_mode
         obs.evolve_request(scorer_branch(trace.hint_ids.shape[-1],
-                                         self.cfg.weights.order_mode))
+                                         order_mode))
+        if not order_mode:
+            # delay mode, at every length: the step's first occurrences
+            # came from per-trace tables
+            obs.evolve_table_request()
         elapsed = time.perf_counter() - t0
         self.generations_run += generations
         # recovery snapshot (tiny: two [H] rows + a scalar): the newest

@@ -123,6 +123,7 @@ from namazu_tpu.obs.spans import (  # noqa: F401
     current_request,
     ensure_compile_listener,
     evolve_request,
+    evolve_table_request,
     ingest_cached_runs,
     ingest_embed_call,
     ingest_events,

@@ -70,6 +70,7 @@ INGEST_EMBED_CALLS = "nmz_ingest_embed_calls_total"
 INGEST_EVENTS = "nmz_ingest_events_total"
 INGEST_CACHED_RUNS = "nmz_ingest_cached_runs_total"
 EVOLVE_REQUESTS = "nmz_evolve_requests_total"
+EVOLVE_TABLE_REQUESTS = "nmz_evolve_table_requests_total"
 RERANK_REQUESTS = "nmz_rerank_requests_total"
 # the policy's reorder buffer (release_mode "reorder"): windows drained,
 # those whose paced drain ended after the NEXT window's boundary (the
@@ -1427,6 +1428,19 @@ def evolve_request(scorer: str) -> None:
         EVOLVE_REQUESTS, "evolve requests by the scorer branch of the "
                          "compiled step", ("scorer",),
     ).labels(scorer=scorer).inc()
+
+
+def evolve_table_request() -> None:
+    """One evolve whose compiled step took its first occurrences from
+    per-trace tables (``ops/schedule.py::_delay_tables``: ``first =
+    delays + earliest arrival``, no per-event op under the population
+    ``vmap``): every delay-mode evolve, no order-mode one."""
+    if not metrics.enabled():
+        return
+    metrics.get().counter(
+        EVOLVE_TABLE_REQUESTS, "evolve requests whose compiled step took "
+                               "first occurrences from per-trace tables",
+    ).inc()
 
 
 def rerank_request(path: str) -> None:

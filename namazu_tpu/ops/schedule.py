@@ -10,7 +10,10 @@ schedules agree by construction.
 
 Scoring (vmapped over a population [P, H]):
 
-1. first-occurrence time per hint bucket, ``first f32[H]`` (scatter-min);
+1. first-occurrence time per hint bucket, ``first f32[H]``: in delay
+   mode ``delays + earliest arrival``, the earliest arrivals a per-trace
+   table built once outside the vmap (:class:`_DelayTables`); in order
+   mode a scatter-min of the genome's release times;
 2. precedence features over K sampled bucket pairs:
    ``feat[k] = sigmoid((first[v_k] - first[u_k]) / tau)`` — a smooth
    "does u happen before v" indicator in (0,1); buckets absent from the
@@ -336,38 +339,133 @@ def precedence_features(
     return jax.nn.sigmoid(z)
 
 
+class _DelayTables(NamedTuple):
+    """What delay-mode scoring needs of ONE trace and no genome: inside
+    a bucket ``delays[h]`` is one number and float addition of one
+    number is monotone, so ``min_l fl(arrival[l] + d) == fl(min_l
+    arrival[l] + d)`` exactly, and a fault drop is decided per bucket
+    (:func:`drop_mask`). Built from the trace alone, before the
+    population ``vmap``: once per trace, not per genome (in the fused
+    island step once a dispatch: :func:`trace_tables`).
+    """
+
+    earliest_all: jax.Array  # f32[H] min arrival of the bucket's events
+    # the fault half's two tables (None where no fault half is scored)
+    earliest_fixed: Optional[jax.Array] = None  # f32[H] non-faultable only
+    faultable_count: Optional[jax.Array] = None  # i32[H] faultable events
+
+
+def _earliest_arrival(trace: TraceArrays, mask: jax.Array,
+                      H: int) -> jax.Array:
+    """Earliest arrival per bucket over the events of ``mask`` (BIG
+    where none), by the per-event functions at zero delay (``a + 0.0 ==
+    a`` exactly): the blockwise scan for a long trace, the dense
+    scatter-min below it (:func:`scorer_branch`)."""
+    zero = jnp.zeros((H,), jnp.float32)
+    if scorer_branch(trace.hint_ids.shape[-1]) == "blockwise":
+        first, _ = first_occurrence_blockwise(
+            zero, trace.hint_ids, trace.arrival, mask)
+        return first
+    tr = trace._replace(mask=mask)
+    return first_occurrence(release_times(zero, tr), tr, H)
+
+
+def _delay_tables(trace: TraceArrays, H: int,
+                  with_faults: bool = False) -> _DelayTables:
+    """The per-trace tables of delay-mode scoring. ``trace.faultable is
+    None`` reads "every event may be dropped", as :func:`drop_mask`
+    reads it."""
+    earliest_all = _earliest_arrival(trace, trace.mask, H)
+    if not with_faults:
+        return _DelayTables(earliest_all)
+    if trace.faultable is None:
+        droppable = trace.mask
+        earliest_fixed = jnp.full((H,), BIG, jnp.float32)
+    else:
+        droppable = trace.mask & trace.faultable
+        earliest_fixed = _earliest_arrival(
+            trace, trace.mask & ~trace.faultable, H)
+    count = jnp.zeros((H,), jnp.int32).at[trace.hint_ids].add(
+        droppable.astype(jnp.int32))
+    return _DelayTables(earliest_all, earliest_fixed, count)
+
+
+def _table_first_occurrence(
+    delays: jax.Array, tables: _DelayTables,
+    faults: Optional[jax.Array] = None,
+    coin: Optional[jax.Array] = None,
+) -> tuple[jax.Array, jax.Array]:
+    """(first-occurrence times f32[H], dropped-event count i32) of one
+    delay-mode genome from its trace's tables: O(H), no gather or
+    scatter over the events. A dropped bucket keeps its non-faultable
+    events; the ``minimum`` is the scatter-min's BIG initial value."""
+    if faults is None:
+        earliest = tables.earliest_all
+        ndrop = jnp.zeros((), jnp.int32)
+    else:
+        dropped = coin < faults
+        earliest = jnp.where(dropped, tables.earliest_fixed,
+                             tables.earliest_all)
+        ndrop = jnp.sum(jnp.where(dropped, tables.faultable_count, 0))
+    first = jnp.where(earliest < BIG,
+                      jnp.minimum(earliest + delays, BIG), BIG)
+    return first, ndrop
+
+
 def _genome_features(
     delays: jax.Array, trace: TraceArrays, pairs: jax.Array, tau: float,
     order_mode: bool = False, order_gap: float = 0.001,
     order_window: float = 0.0,
     faults: Optional[jax.Array] = None,
     coin: Optional[jax.Array] = None,
+    tables: Optional[_DelayTables] = None,
 ) -> tuple[jax.Array, jax.Array]:
     """(features f32[K], dropped-event count i32) for one genome.
 
-    Delay-mode traces longer than ``LONG_TRACE_THRESHOLD`` take the
-    blockwise scan (bounded memory under a population vmap — no [P, L]
-    intermediates); shorter ones the dense path; order mode its own
-    (:func:`scorer_branch`). The dispatch is on static shape and mode,
-    so each jit specialization compiles exactly one branch."""
+    Delay mode: ``first = delays + earliest arrival`` from the trace's
+    :class:`_DelayTables` (``tables``: built by the caller before its
+    population ``vmap``, here for one genome alone); the padded length
+    decides only how those are built (:func:`scorer_branch`: the
+    blockwise scan above ``LONG_TRACE_THRESHOLD``, the dense
+    scatter-min below). Order mode has a per-event branch of its own.
+    The dispatch is on static shape and mode, so each jit
+    specialization compiles exactly one branch."""
     H = delays.shape[0]
-    if scorer_branch(trace.hint_ids.shape[-1], order_mode) == "blockwise":
-        first, ndrop = first_occurrence_blockwise(
-            delays, trace.hint_ids, trace.arrival, trace.mask,
-            faults=faults, coin=coin, faultable=trace.faultable,
-        )
+    if not order_mode:
+        if tables is None:
+            tables = _delay_tables(trace, H, faults is not None)
+        first, ndrop = _table_first_occurrence(delays, tables, faults, coin)
         return precedence_features(first, pairs, tau), ndrop
     eff = apply_faults(trace, faults, coin)
     if faults is None:
         ndrop = jnp.zeros((), jnp.int32)
     else:
         ndrop = (jnp.sum(trace.mask) - jnp.sum(eff.mask)).astype(jnp.int32)
-    if order_mode:
-        t = order_release_times(delays, eff, order_gap, order_window)
-    else:
-        t = release_times(delays, eff)
+    t = order_release_times(delays, eff, order_gap, order_window)
     first = first_occurrence(t, eff, H)
     return precedence_features(first, pairs, tau), ndrop
+
+
+def _population_features(
+    delays: jax.Array,  # [P, H]
+    trace: TraceArrays,  # one [L] trace
+    pairs: jax.Array, weights: ScoreWeights,
+    faults: Optional[jax.Array] = None,  # [P, H]
+    coin: Optional[jax.Array] = None,
+    tables: Optional[_DelayTables] = None,
+) -> tuple[jax.Array, jax.Array]:
+    """(features f32[P, K], dropped-event counts i32[P]) of a population
+    against one trace. In delay mode the trace's tables (``tables``, or
+    built here) come before the ``vmap`` over genomes, so that no
+    per-event op carries the population dimension."""
+    if tables is None and not weights.order_mode:
+        tables = _delay_tables(trace, delays.shape[-1], faults is not None)
+    return jax.vmap(
+        lambda d, f: _genome_features(d, trace, pairs, weights.tau,
+                                      weights.order_mode, weights.order_gap,
+                                      weights.order_window, faults=f,
+                                      coin=coin, tables=tables),
+        in_axes=(0, None if faults is None else 0))(delays, faults)
 
 
 def schedule_features(
@@ -512,8 +610,9 @@ def score_population(
     With ``faults``/``coin``, the genome's fault half is part of the
     counterfactual: dropped events reshape the features, and a
     ``fault_cost`` per dropped event keeps "drop everything" from being
-    the novelty optimum. Long delay-mode traces score blockwise (see
-    :func:`_genome_features`).
+    the novelty optimum. In delay mode no per-event op runs under the
+    population ``vmap``: the trace's tables are built once, blockwise
+    for a long trace (see :func:`_population_features`).
 
     ``novelty_scale`` multiplies ``weights.novelty`` as a *traced*
     scalar — the novelty-anneal lever (exploration weight decays as the
@@ -531,22 +630,11 @@ def score_population(
     default, and the pre-occupancy graphs) on purpose — SearchBase's
     rings treat unoccupied slots as neutral 0.5 feature points, and
     masking them would change fitness."""
+    feats, ndrop = _population_features(delays, trace, pairs, weights,
+                                        faults, coin)
     if faults is None:
-        feats, _ = jax.vmap(
-            lambda d: _genome_features(d, trace, pairs, weights.tau,
-                                       weights.order_mode,
-                                       weights.order_gap,
-                                       weights.order_window)
-        )(delays)
         fault_pen = 0.0
     else:
-        feats, ndrop = jax.vmap(
-            lambda d, f: _genome_features(d, trace, pairs, weights.tau,
-                                          weights.order_mode,
-                                          weights.order_gap,
-                                          weights.order_window,
-                                          faults=f, coin=coin)
-        )(delays, faults)
         live = jnp.maximum(jnp.sum(trace.mask), 1)
         fault_pen = weights.fault_cost * ndrop / live
     nov_d2, bug_d2 = _min_sq_pair_best(feats, archive, failure_feats,
@@ -584,6 +672,20 @@ def score_population_jit(delays, trace, pairs, archive, failure_feats,
 # -- multi-trace scoring ----------------------------------------------------
 
 
+def trace_tables(traces: TraceArrays, H: int, weights: ScoreWeights,
+                 with_faults: bool) -> Optional[_DelayTables]:
+    """What :func:`score_population_multi` builds of its stacked traces
+    ``[T, L]`` and of no population: in delay mode their
+    :class:`_DelayTables` (leading dimension T), in order mode nothing
+    (its per-trace tables depend on the static window and are built
+    where they are used). A program that scores many populations
+    against the same traces — the fused island step, G generations a
+    dispatch — builds them once and hands them in as ``tables``."""
+    if weights.order_mode:
+        return None
+    return jax.vmap(lambda tr: _delay_tables(tr, H, with_faults))(traces)
+
+
 def score_population_multi(
     delays: jax.Array,  # [P, H]
     traces: TraceArrays,  # arrays with leading trace dim [T, L]
@@ -596,35 +698,28 @@ def score_population_multi(
     novelty_scale: Optional[jax.Array] = None,  # dynamic f32 scalar
     archive_n: Optional[jax.Array] = None,  # dynamic i32 occupancy
     failure_n: Optional[jax.Array] = None,  # dynamic i32 occupancy
+    tables: Optional[_DelayTables] = None,  # trace_tables(traces, ...)
 ) -> tuple[jax.Array, jax.Array]:
     """Fitness averaged over T recorded traces (novelty against ONE run
     is mostly its noise): (fitness [P], feats [P, T, K]). ONE compiled
-    program on concrete arrays (the re-rank), inline under a trace."""
+    program on concrete arrays (the re-rank), inline under a trace.
+    ``tables``: the traces' :func:`trace_tables` where the caller built
+    them already; built here otherwise."""
     args = (delays, traces, pairs, archive, failure_feats, weights, faults,
-            coin, novelty_scale, archive_n, failure_n)
+            coin, novelty_scale, archive_n, failure_n, tables)
     if _all_concrete(args):
         return _score_population_multi_jit(*args)
-    def per_trace(tr: TraceArrays):
-        """(feats [P, K], drop fraction [P]) against one trace."""
-        if faults is None:
-            f, _ = jax.vmap(
-                lambda d: _genome_features(d, tr, pairs, weights.tau,
-                                           weights.order_mode,
-                                           weights.order_gap,
-                                           weights.order_window)
-            )(delays)
-            return f, jnp.zeros((delays.shape[0],), jnp.float32)
-        f, ndrop = jax.vmap(
-            lambda d, ft: _genome_features(d, tr, pairs, weights.tau,
-                                           weights.order_mode,
-                                           weights.order_gap,
-                                           weights.order_window,
-                                           faults=ft, coin=coin)
-        )(delays, faults)
-        live = jnp.maximum(jnp.sum(tr.mask), 1)
-        return f, ndrop / live
+    if tables is None:
+        tables = trace_tables(traces, delays.shape[-1], weights,
+                              faults is not None)
 
-    feats, frac = jax.vmap(per_trace)(traces)  # [T, P, K], [T, P]
+    def per_trace(tr: TraceArrays, tb: Optional[_DelayTables]):
+        """(feats [P, K], drop fraction [P]) against one trace."""
+        f, ndrop = _population_features(delays, tr, pairs, weights,
+                                        faults, coin, tables=tb)
+        return f, ndrop / jnp.maximum(jnp.sum(tr.mask), 1)
+
+    feats, frac = jax.vmap(per_trace)(traces, tables)  # [T, P, K], [T, P]
     feats = jnp.swapaxes(feats, 0, 1)  # [P, T, K]
     P, T, K = feats.shape
     flat = feats.reshape(P * T, K)
@@ -660,19 +755,21 @@ def _all_concrete(args) -> bool:
 
 # -- long traces: blockwise first-occurrence --------------------------------
 
-# delay-mode traces longer than this are scored blockwise; below it the
-# dense path is cheaper (one fused gather + scatter-min). Order mode
-# has a branch of its own at every length: its release times come from
-# :func:`order_release_times` (counts over the whole trace), then one
-# dense scatter-min.
+# how a delay-mode trace's per-trace tables (:func:`_delay_tables`) are
+# built: blockwise past this length, below it the dense path is cheaper
+# (one fused gather + scatter-min). Either way once per trace, not per
+# genome. Order mode has a per-genome branch of its own at every
+# length: its release times come from :func:`order_release_times`
+# (counts over the whole trace), then one dense scatter-min.
 LONG_TRACE_THRESHOLD = 1024
 LONG_TRACE_CHUNK = 512
 
 
 def scorer_branch(L: int, order_mode: bool = False) -> str:
     """The first-occurrence branch a step compiled for padded trace
-    length ``L`` takes: ``"order"`` | ``"blockwise"`` | ``"dense"``. One
-    home for the rule, so that what the search counts per evolve
+    length ``L`` takes: ``"order"`` | ``"blockwise"`` | ``"dense"`` (in
+    delay mode: how the trace's tables are built). One home for the
+    rule, so that what the search counts per evolve
     (``nmz_evolve_requests_total{scorer}``) is what was compiled."""
     if order_mode:
         return "order"

@@ -44,6 +44,7 @@ from namazu_tpu.ops.schedule import (
     normalize_fault_trace,
     replicated_trace_specs,
     score_population_multi,
+    trace_tables,
 )
 
 
@@ -73,7 +74,7 @@ def _make_local_step(mesh: Mesh, axis: str, cfg: GAConfig,
     generation -> ring migration -> global-best all_gather."""
     n_islands = mesh.shape[axis]
 
-    def _local_step(key, pop, trace, pairs, archive, failure_feats,
+    def _local_step(key, pop, trace, tables, pairs, archive, failure_feats,
                     novelty_scale, mutation_bias, coin=None):
         # named scopes mark the per-phase op regions in any captured
         # device profile (xprof/perfetto) — the in-jit counterpart of the
@@ -85,7 +86,7 @@ def _make_local_step(mesh: Mesh, axis: str, cfg: GAConfig,
             fitness, _feats = score_population_multi(
                 pop.delays, trace, pairs, archive, failure_feats, weights,
                 faults=None if coin is None else pop.faults, coin=coin,
-                novelty_scale=novelty_scale,
+                novelty_scale=novelty_scale, tables=tables,
             )
         # local best before evolution (elites survive anyway)
         best_i = jnp.argmax(fitness)
@@ -213,11 +214,17 @@ def make_fused_island_step(
 
     def _fused_local(state, base_key, trace, pairs, archive, failure_feats,
                      novelty_scale, mutation_bias, coin=None):
+        # what the scorer needs of the traces and of no population:
+        # once a dispatch, outside the generation loop
+        with jax.named_scope("nmz_score"):
+            tables = trace_tables(trace, state.pop.delays.shape[-1],
+                                  weights, coin is not None)
+
         def body(carry, i):
             pop, gen, bf, bd, bfa = carry
             key = jax.random.fold_in(base_key, gen)
             new_pop, fit, d, f = _local_step(
-                key, pop, trace, pairs, archive, failure_feats,
+                key, pop, trace, tables, pairs, archive, failure_feats,
                 novelty_scale, mutation_bias,
                 *(() if coin is None else (coin,)))
             improved = fit > bf

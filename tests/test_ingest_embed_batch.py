@@ -512,6 +512,9 @@ def test_an_evolve_is_counted_under_the_branch_its_step_compiled(
     other = ({"dense", "blockwise"} - {scorer}).pop()
     assert reg.value(spans.EVOLVE_REQUESTS, scorer=scorer) == 2
     assert not reg.value(spans.EVOLVE_REQUESTS, scorer=other)
+    # delay mode at either length: first occurrences from per-trace
+    # tables, one count per evolve
+    assert reg.value(spans.EVOLVE_TABLE_REQUESTS) == 2
     evolves = [r for r in fresh_obs.since(0)["rows"] if r[1] == "evolve"]
     assert len(evolves) == 2
 
@@ -541,6 +544,7 @@ def test_an_order_mode_evolve_is_counted_as_order(fused_chunk, fresh_obs):
     assert reg.value(spans.EVOLVE_REQUESTS, scorer="order") == 2
     for other in ("dense", "blockwise"):
         assert not reg.value(spans.EVOLVE_REQUESTS, scorer=other)
+    assert not reg.value(spans.EVOLVE_TABLE_REQUESTS)  # per-event branch
 
 
 @pytest.mark.parametrize("n_events, explicit_L, want_L, cut", [
