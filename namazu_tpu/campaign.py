@@ -46,6 +46,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 from namazu_tpu.cli.run_cmd import EXIT_TIMEOUT
+from namazu_tpu.obs import metrics as obs_metrics
 from namazu_tpu.obs import spans as obs_spans
 from namazu_tpu.utils.atomic import atomic_write_json
 from namazu_tpu.utils.cmd import (
@@ -149,6 +150,12 @@ class Campaign:
         self._child_lock = threading.Lock()
         self._telemetry_server = None
         self._telemetry_path = ""
+        # run phases (doc/observability.md "Run phases"): when the last
+        # attempt's child was reaped (monotonic; the start of the next
+        # attempt's `respawn`), and how many runs the storage held then
+        # (a run beyond that count is the next attempt's)
+        self._last_reap: Optional[float] = None
+        self._stored_runs: Optional[int] = None
 
     # -- checkpoint ------------------------------------------------------
 
@@ -251,10 +258,15 @@ class Campaign:
         argv += spec.extra_run_args
         return argv
 
-    def _child_env(self) -> Dict[str, str]:
+    def _child_env(self, spawned: Optional[float] = None
+                   ) -> Dict[str, str]:
         # the child must be able to import the framework even when it is
         # not installed site-wide; CmdFactory.env() owns that logic
         env = CmdFactory(extra_env=self.spec.extra_env).env()
+        if spawned is not None:
+            # the origin of the child's run phases: its `boot` is from
+            # this stamp to its own first (obs/spans.py run_begin)
+            env[obs_spans.RUN_SPAWNED_ENV] = repr(spawned)
         if self._telemetry_path:
             # run children push their metrics (and forward their
             # inspectors') to the supervisor's collector — the one
@@ -339,9 +351,13 @@ class Campaign:
         if self.spec.serve_url:
             return self._one_serve_attempt(slot_index)
         spec = self.spec
+        observed = obs_metrics.enabled()
+        if observed and self._stored_runs is None:
+            self._stored_runs, _ = self._newest_run()
         t0 = time.monotonic()
         child = subprocess.Popen(
-            self._run_argv(), env=self._child_env(),
+            self._run_argv(),
+            env=self._child_env(t0 if observed else None),
             start_new_session=True)
         with self._child_lock:
             self._child = child
@@ -380,9 +396,65 @@ class Campaign:
             cls = CLASS_TIMEOUT  # a child-enforced phase deadline fired
         else:
             cls = CLASS_INFRA  # nonzero exit or signal death (rc < 0)
-        return {"class": cls, "exit_status": rc,
-                "wall_s": round(wall_s, 3),
-                "wall_deadline_hit": timed_out}
+        attempt = {"class": cls, "exit_status": rc,
+                   "wall_s": round(wall_s, 3),
+                   "wall_deadline_hit": timed_out}
+        if observed:
+            phases = self._attempt_phases(t0, wall_s)
+            if phases:
+                attempt["phases"] = phases
+        return attempt
+
+    def _newest_run(self):
+        """How many runs the storage holds, and the newest one's
+        ``metadata["phases"]`` (the rows the run child stored of itself,
+        cli/run_cmd.py) or ``[]``. Best-effort like the progress
+        publication: a storage that cannot be read costs the attempt
+        its child rows, never the campaign its loop."""
+        from namazu_tpu.storage import load_storage
+
+        try:
+            st = load_storage(self.spec.storage_dir)
+            try:
+                n = st.nr_stored_histories()
+                rows = st.get_metadata(n - 1).get("phases") if n else None
+                return n, [list(r) for r in rows or []]
+            finally:
+                st.close()
+        except Exception:
+            log.warning("could not read the newest run's phases; "
+                        "continuing", exc_info=True)
+            return self._stored_runs, []
+
+    def _attempt_phases(self, t0: float, wall_s: float) -> List[list]:
+        """One attempt's cycle as ``[name, parent, start_s, seconds]``
+        rows counted from the spawn stamp ``t0``: the rows the child
+        stored with its run, and around them what only the supervisor
+        sees — ``respawn``, from the previous attempt's reap to this
+        spawn (so it starts before 0), and ``teardown``, from the end of
+        the child's last row to the reap (the ``result.json`` write, the
+        storage's close, the exit hooks, the interpreter's exit). An
+        attempt whose child stored no run has neither child rows nor
+        ``teardown``. The two are observed here, into the supervisor's
+        own ``nmz_run_phase_seconds``; the child observed its own."""
+        before, after = [], []
+        if self._last_reap is not None:
+            gap = t0 - self._last_reap
+            before.append(["respawn", None, round(-gap, 6), round(gap, 6)])
+        self._last_reap = t0 + wall_s
+        n, rows = self._newest_run()
+        if n == self._stored_runs:
+            rows = []  # the newest run is an earlier attempt's
+        self._stored_runs = n
+        try:
+            end = max(r[2] + r[3] for r in rows if r[1] is None)
+        except (TypeError, ValueError, IndexError):
+            end = None  # no child rows, or rows no run child wrote
+        if end is not None:
+            after.append(["teardown", None, round(end, 6),
+                          round(wall_s - end, 6)])
+        obs_spans.run_phases_observed(before + after)
+        return before + rows + after
 
     # -- tenancy serve mode (doc/tenancy.md) ------------------------------
 

@@ -99,6 +99,15 @@ def run(args) -> int:
               "afterwards)", file=sys.stderr)
         return 1
     cfg = Config.from_file(cfg_path)
+    # the run's own phases (doc/observability.md "Run phases") are timed
+    # only where the config leaves observability on, so the switches
+    # (obs_enabled, and telemetry_enabled = false for the relay below)
+    # are read before the first stamp; `boot` ends and `prepare` starts
+    # here
+    from namazu_tpu import obs
+
+    obs.configure_from_config(cfg)
+    entered = obs.run_entered()
     # chaos plane (doc/robustness.md): fault plans reach child `run`
     # processes (campaign slots, kill-tests) via NMZ_CHAOS; no-op unless
     # set, and an explicitly installed plan wins
@@ -118,6 +127,7 @@ def run(args) -> int:
     # (GET /traces/<run_id>) with the on-disk run dir via one key
     if not cfg.is_set("run_id"):
         cfg.set("run_id", os.path.basename(os.path.normpath(working_dir)))
+    obs.run_begin(str(cfg.get("run_id")), entered)
     init_log(os.path.join(working_dir, "nmz.log"))
     if args.journal or bool(cfg.get("event_journal")):
         # the journal lives in the run's own dir: recovery is per-run,
@@ -166,8 +176,6 @@ def run(args) -> int:
 
     # the live GET /analytics route aggregates over this storage (the
     # same dir `tools report` reads offline — one payload, two surfaces)
-    from namazu_tpu import obs
-
     obs.set_analytics_storage(os.path.abspath(storage_dir))
     if args.knowledge:
         # fold the fleet's pool/tenant stats into GET /analytics
@@ -178,7 +186,6 @@ def run(args) -> int:
     # $NMZ_TELEMETRY_URL (the campaign supervisor's export) > config
     if args.telemetry_url:
         cfg.set("telemetry_url", args.telemetry_url)
-    obs.configure_from_config(cfg)  # honor telemetry_enabled = false
     obs.federation.ensure_self_relay(
         "run",
         push_url=(args.telemetry_url
@@ -197,6 +204,7 @@ def run(args) -> int:
 
     orchestrator = Orchestrator(cfg, policy, collect_trace=True)
     orchestrator.start()
+    obs.run_phase_since("prepare", entered)
 
     successful = False
     recorded = False
@@ -212,7 +220,8 @@ def run(args) -> int:
                 print("error: config has no 'run' script", file=sys.stderr)
                 return EXIT_INFRA
             try:
-                res = factory.run(run_script, deadline=run_deadline)
+                with obs.run_phase("testee"):
+                    res = factory.run(run_script, deadline=run_deadline)
             except subprocess.TimeoutExpired:
                 print(f"error: run script exceeded its {run_deadline:.1f}s "
                       "deadline; killed its process group; not recording "
@@ -227,7 +236,8 @@ def run(args) -> int:
                       "not recording this run", file=sys.stderr)
                 return EXIT_INFRA
         finally:
-            trace = orchestrator.shutdown()
+            with obs.run_phase("drain"):
+                trace = orchestrator.shutdown()
             # stop fast-forwarding before validate/clean: the oracle
             # runs at wall rate, and the restored default TimeSource
             # must not leak a jumped clock into the next in-process run
@@ -237,9 +247,10 @@ def run(args) -> int:
         validate_script = cfg.get("validate")
         if validate_script:
             try:
-                successful = factory.run(
-                    validate_script,
-                    deadline=validate_deadline).returncode == 0
+                with obs.run_phase("validate"):
+                    successful = factory.run(
+                        validate_script,
+                        deadline=validate_deadline).returncode == 0
             except subprocess.TimeoutExpired:
                 print("error: validate script exceeded its "
                       f"{validate_deadline:.1f}s deadline; killed its "
@@ -250,7 +261,8 @@ def run(args) -> int:
 
         from namazu_tpu.signal.base import HINT_SPACE
 
-        storage.record_new_trace(trace)
+        with obs.run_phase("record"):
+            storage.record_new_trace(trace)
         # stamp the replay-hint format version: a future format bump must
         # be able to tell (and skip) histories whose recorded event_hint
         # strings hash into a different bucket space (policy/tpu.py
@@ -266,6 +278,12 @@ def run(args) -> int:
             metadata["wall_time_s"] = vclock_summary["wall_elapsed_s"]
             metadata["vclock_speedup"] = vclock_summary["speedup_ratio"]
             metadata["vclock_pinned_s"] = vclock_summary["pinned_s"]
+        # the run carries its own spans: whoever reads this record (the
+        # campaign supervisor, the search home's ingest) has the run's
+        # cycle by phase without a wire to this process
+        phases = obs.run_end()
+        if phases:
+            metadata["phases"] = phases
         storage.record_result(successful, required_time,
                               metadata=metadata)
         recorded = True
@@ -279,6 +297,7 @@ def run(args) -> int:
               f"actions workdir={working_dir}")
         return EXIT_OK
     finally:
+        obs.run_end()  # an aborted run's scope; closed already otherwise
         # abort paths (deadline kill, infra failure, Ctrl-C) must also
         # restore the wall TimeSource; finish() is idempotent
         if vclock_handle is not None:

@@ -224,19 +224,21 @@ _RUN_RECORDS = RunRecordCache(RUN_CACHE_BYTES)
 
 
 def _read_run(storage, i: int):
-    """``(trace, successful, hint-space stamp)`` of stored run ``i``;
-    raises what the storage raises for a run it will not serve. Absent
-    stamps default to "content-v1", the same convention the checkpoint
-    loader uses (te.checkpoint_hint_space): every recording made by a
-    stamping build carries the tag (cli/run_cmd.py)."""
+    """``(trace, successful, hint-space stamp, run phases)`` of stored
+    run ``i``; raises what the storage raises for a run it will not
+    serve. Absent stamps default to "content-v1", the same convention
+    the checkpoint loader uses (te.checkpoint_hint_space): every
+    recording made by a stamping build carries the tag
+    (cli/run_cmd.py). The phases are the rows an observed run stored of
+    itself (``metadata["phases"]``), None where it stored none."""
     trace = storage.get_stored_history(i)
     ok = storage.is_successful(i)
     try:
-        stamp = ((storage.get_metadata(i) or {})
-                 .get("hint_space", "content-v1"))
+        meta = storage.get_metadata(i) or {}
     except Exception:
-        stamp = "content-v1"
-    return trace, ok, stamp
+        meta = {}
+    return trace, ok, meta.get("hint_space", "content-v1"), \
+        meta.get("phases")
 
 
 def _encode_run(trace, ok: bool, stamp: str, cap: Optional[int],
@@ -367,9 +369,16 @@ def _ingest_history(search, storage, p: IngestParams, stages: _Stages,
                 continue
         t = stages.add("ingest_read", t)
         if run is not None:
+            *run, phases = run
             rec = _encode_run(*run, cap, pads, p)
             if key is not None:
                 _RUN_RECORDS.put(key, signature, rec)
+                # the run's own cycle by phase, observed where its
+                # record is first kept: once per run and process, until
+                # the record is evicted or the run rewritten. A storage
+                # without signatures keeps no record and would observe
+                # its whole history again at every request
+                obs.run_phases_observed(phases)
         # runs recorded under a different replay-hint format hash into a
         # different bucket space — training on them would deliver
         # arbitrary delays under a "searched schedule" log

@@ -129,6 +129,12 @@ from namazu_tpu.obs.spans import (  # noqa: F401
     ingest_events,
     ingest_runs,
     rerank_request,
+    run_begin,
+    run_end,
+    run_entered,
+    run_phase,
+    run_phase_since,
+    run_phases_observed,
     search_device_trace,
     search_phase,
     search_phase_observed,

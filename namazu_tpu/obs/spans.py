@@ -33,6 +33,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import itertools
+import os
 import threading
 import time
 from typing import Optional
@@ -62,6 +63,7 @@ SEARCH_ARCHIVE = "nmz_search_archive_entries"
 SEARCH_INSTALLS = "nmz_search_installs_total"
 SCORER_THROUGHPUT = "nmz_scorer_schedules_per_sec"
 SEARCH_PHASE = "nmz_search_phase_seconds"
+RUN_PHASE = "nmz_run_phase_seconds"
 SEARCH_HOST_GAP = "nmz_search_host_gap_share"
 SEARCH_DEVICE_TRACES = "nmz_search_device_traces_total"
 SPAN_ROWS_DROPPED = "nmz_span_rows_dropped_total"
@@ -1148,7 +1150,7 @@ def _trace_annotation(name: str, rid: Optional[str] = None):
     return cls(name) if rid is None else cls(name, rid=rid)
 
 
-# -- request-scoped spans (search plane) ----------------------------------
+# -- request-scoped spans (search plane) and run-scoped spans -------------
 #
 # ``search_phase`` is the search plane's ONE span source: a histogram
 # observation, a profiler annotation and a row in a bounded in-memory
@@ -1158,6 +1160,30 @@ def _trace_annotation(name: str, rid: Optional[str] = None):
 # under the phase that is open on the same thread. Rows are read over
 # the framed ``spans`` op (obs/federation.py) and rendered by
 # ``nmz-tpu tools spans``.
+#
+# ``run_phase`` is the same source under a second histogram family,
+# ``nmz_run_phase_seconds``, for the phases of one ``nmz-tpu run``
+# (doc/observability.md "Run phases"): ``run_begin`` opens the scope
+# under the run's id where ``request_begin`` opens one under a
+# request's, the rows go to the same ring, and ``run_end`` hands them
+# to the run's stored metadata. The control plane imports no jax, so a
+# run phase carries no profiler annotation.
+
+#: the one environment variable a campaign supervisor exports to its
+#: ``run`` child: ``time.monotonic()`` just before the spawn
+#: (CLOCK_MONOTONIC is host-wide on Linux, so the child's stamps are on
+#: the same clock)
+RUN_SPAWNED_ENV = "NMZ_RUN_SPAWNED"
+
+#: what a run records of itself, and what only its supervisor sees
+RUN_PHASES = ("boot", "prepare", "testee", "drain", "search", "endpoints",
+              "validate", "record")
+SUPERVISOR_PHASES = ("teardown", "respawn")
+
+_PHASE_HELP = {
+    SEARCH_PHASE: "wall time per search-plane phase",
+    RUN_PHASE: "wall time per phase of one campaign run",
+}
 
 #: rows the span ring keeps; overflow drops the oldest
 SPAN_RING_ROWS = 8192
@@ -1183,6 +1209,12 @@ class SpanRing:
             metrics.get().counter(
                 SPAN_ROWS_DROPPED,
                 "span rows pushed out of the ring by newer ones").inc()
+
+    def end(self) -> int:
+        """The cursor past the newest row: rows appended from now on
+        are ``since(end())``."""
+        with self._lock:
+            return self._appended
 
     def since(self, cursor: int = 0, limit: int = 1024) -> dict:
         """Rows from ``cursor`` on (at most ``limit``), the cursor to
@@ -1262,17 +1294,35 @@ def _append_row(name: str, seconds: float, t_mono: float,
         threading.current_thread().name, attrs))
 
 
-def _record_phase(phase: str, seconds: float, t_mono: float,
-                  parent: Optional[str], attrs: dict) -> None:
+def _observe_phase(family: str, phase: str, seconds: float) -> None:
     metrics.get().histogram(
-        SEARCH_PHASE,
-        "wall time per search-plane phase",
-        ("phase",),
+        family, _PHASE_HELP[family], ("phase",),
     ).labels(phase=phase).observe(seconds)
+
+
+def _record_phase(family: str, phase: str, seconds: float, t_mono: float,
+                  parent: Optional[str], attrs: dict) -> None:
+    _observe_phase(family, phase, seconds)
     _append_row(phase, seconds, t_mono, parent, attrs)
 
 
 @contextlib.contextmanager
+def _phase(family: str, phase: str, attrs: dict, annotate: bool):
+    scope = _scope.__dict__
+    stack = scope.setdefault("stack", [])
+    parent = stack[-1] if stack else None
+    stack.append(phase)
+    t0 = time.monotonic()
+    try:
+        with (_trace_annotation(f"nmz:{phase}", scope.get("rid"))
+              if annotate else contextlib.nullcontext()):
+            yield attrs
+    finally:
+        stack.pop()
+        _record_phase(family, phase, time.monotonic() - t0, t0, parent,
+                      attrs)
+
+
 def search_phase(phase: str, **attrs):
     """Time one search-plane phase into
     ``nmz_search_phase_seconds{phase=...}``, annotate the region into
@@ -1286,20 +1336,8 @@ def search_phase(phase: str, **attrs):
     annotated with ``jax.named_scope`` inside the jitted island step
     (parallel/islands.py), where host-side timers cannot reach."""
     if not metrics.enabled():
-        yield attrs
-        return
-    scope = _scope.__dict__
-    stack = scope.setdefault("stack", [])
-    parent = stack[-1] if stack else None
-    rid = scope.get("rid")
-    stack.append(phase)
-    t0 = time.monotonic()
-    try:
-        with _trace_annotation(f"nmz:{phase}", rid):
-            yield attrs
-    finally:
-        stack.pop()
-        _record_phase(phase, time.monotonic() - t0, t0, parent, attrs)
+        return contextlib.nullcontext(attrs)
+    return _phase(SEARCH_PHASE, phase, attrs, annotate=True)
 
 
 def search_phase_observed(phase: str, seconds: float, t_mono_start: float,
@@ -1313,7 +1351,104 @@ def search_phase_observed(phase: str, seconds: float, t_mono_start: float,
     ``span_trees``)."""
     if not metrics.enabled():
         return
-    _record_phase(phase, seconds, t_mono_start, _open_phase(), attrs)
+    _record_phase(SEARCH_PHASE, phase, seconds, t_mono_start,
+                  _open_phase(), attrs)
+
+
+# -- run-scoped spans (campaign supervisor, cli/run_cmd.py) ---------------
+
+def run_entered() -> Optional[float]:
+    """The stamp ``nmz-tpu run`` takes once its config has said whether
+    the run is observed at all; None (and no clock read) while it is
+    not."""
+    return time.monotonic() if metrics.enabled() else None
+
+
+def run_begin(run_id: str, entered: Optional[float]) -> None:
+    """Open the calling thread's run scope under ``run_id`` (the run
+    directory's name). The run's rows start at the supervisor's spawn
+    stamp (``RUN_SPAWNED_ENV``), and ``boot`` is the row from there to
+    ``entered``; a bare ``nmz-tpu run`` has no such stamp, records no
+    ``boot`` and counts from ``entered``. The variable is taken out of
+    the environment: what this run spawns is no child of that stamp.
+    No scope while observability is off (``entered`` None)."""
+    stamp = os.environ.pop(RUN_SPAWNED_ENV, None)
+    if entered is None or not metrics.enabled():
+        return
+    try:
+        spawned = float(stamp) if stamp else None
+    except ValueError:
+        spawned = None
+    _scope.rid = run_id
+    # the scope: where the run's clock starts and where its rows do
+    _scope.run = (entered if spawned is None else spawned,
+                  _span_ring.end())
+    if spawned is not None:
+        _record_phase(RUN_PHASE, "boot", entered - spawned, spawned,
+                      None, {})
+
+
+def run_phase(phase: str, **attrs):
+    """Time one phase of the run whose scope is open on this thread
+    into ``nmz_run_phase_seconds{phase=...}`` and the span ring, nested
+    like :func:`search_phase`; outside a run scope (an orchestrator no
+    ``nmz-tpu run`` drives) and while observability is off it times
+    nothing."""
+    if not metrics.enabled() or "run" not in _scope.__dict__:
+        return contextlib.nullcontext(attrs)
+    return _phase(RUN_PHASE, phase, attrs, annotate=False)
+
+
+def run_phase_since(phase: str, t_mono_start: Optional[float]) -> None:
+    """Record the run phase that began at ``t_mono_start`` and ends
+    now (``prepare``: too long a stretch of ``run`` to indent under a
+    ``with``)."""
+    if (t_mono_start is None or not metrics.enabled()
+            or "run" not in _scope.__dict__):
+        return
+    _record_phase(RUN_PHASE, phase, time.monotonic() - t_mono_start,
+                  t_mono_start, _open_phase(), {})
+
+
+def run_end() -> Optional[list]:
+    """Close the run scope and return the run's rows as
+    ``[name, parent, start_s, seconds]``, ``start_s`` counted from the
+    scope's origin: what ``cli/run_cmd.py`` stores as
+    ``metadata["phases"]``. None without a scope."""
+    scope = _scope.__dict__
+    run = scope.get("run")
+    if run is None:
+        return None
+    origin, cursor = run
+    rid = scope.get("rid")
+    rows = [[name, parent, round(t_mono - origin, 6), round(seconds, 6)]
+            for r, name, parent, _wall, t_mono, seconds, _thread, _attrs
+            in _span_ring.since(cursor, SPAN_RING_ROWS)["rows"]
+            if r == rid and name in RUN_PHASES]
+    # the ring holds a phase where it ended: a parent before its children
+    rows.sort(key=lambda row: (row[2], -row[3]))
+    request_end()
+    return rows
+
+
+def run_phases_observed(rows) -> None:
+    """Observe stored run rows (``metadata["phases"]`` of a
+    ``result.json``, or a supervisor's ``teardown`` / ``respawn``) into
+    this process's ``nmz_run_phase_seconds{phase}``: the histogram
+    alone, the rows live where they were stored. The rows come from a
+    file: one that is no ``[name, parent, start_s, seconds]`` with a
+    known name and a finite, non-negative length is passed over."""
+    if not rows or not metrics.enabled():
+        return
+    for row in rows:
+        try:
+            name, _parent, _start, seconds = row
+            seconds = float(seconds)
+        except (TypeError, ValueError):
+            continue
+        if (name in RUN_PHASES or name in SUPERVISOR_PHASES) \
+                and 0.0 <= seconds < float("inf"):
+            _observe_phase(RUN_PHASE, name, seconds)
 
 
 _compile_listener_on = False

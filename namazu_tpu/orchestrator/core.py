@@ -275,7 +275,13 @@ class Orchestrator:
         self._threads["events"].join(timeout=10)
         # 2. flush the policies; their dequeue workers emit remaining
         #    actions and then POLICY_DONE
-        self.policy.shutdown()
+        # (`search`: the run's join on its end-of-run search request,
+        # under the id the policy stamped on it — the same id the
+        # sidecar's span tree of that request carries)
+        with obs.run_phase("search") as attrs:
+            self.policy.shutdown()
+            if self.policy.sidecar_request_id:
+                attrs["request"] = self.policy.sidecar_request_id
         self.dumb.shutdown()
         # 3. forward loops exit on POLICY_DONE after draining; the action
         #    loop exits after both _FWD_DONE markers
@@ -288,7 +294,8 @@ class Orchestrator:
             self._threads["watchdog"].join(timeout=10)
         self.hub.control_queue.put(_STOP)  # type: ignore[arg-type]
         self._threads["control"].join(timeout=10)
-        self.hub.shutdown()
+        with obs.run_phase("endpoints"):
+            self.hub.shutdown()
         if self.journal is not None:
             # every parked event was flushed above and its release
             # journaled: the run completed, so remove the file — a

@@ -53,6 +53,11 @@ class ExplorePolicy:
 
     NAME = "abstract"
 
+    #: id of the last search request this policy sent to a sidecar: what
+    #: the run's `search` phase row names (orchestrator/core.py
+    #: shutdown). "" for a policy that sends none
+    sidecar_request_id = ""
+
     def __init__(self) -> None:
         self.action_out: "queue.Queue[Action]" = queue.Queue()
         self._storage: Optional["HistoryStorage"] = None

@@ -902,7 +902,8 @@ class TPUSearchPolicy(QueueBackedPolicy):
             # (obs/spans.py request_begin), so this run's log line and
             # the sidecar's span tree name the same request
             stamp = req["ctx"] = wire_stamp()
-            rid = f", request {stamp['o']}:{stamp['lc']}"
+            self.sidecar_request_id = f"{stamp['o']}:{stamp['lc']}"
+            rid = f", request {self.sidecar_request_id}"
         resp = request(self.sidecar, req,
                        timeout=max(self.search_join_timeout, 30.0))
         if not resp.get("ok"):
