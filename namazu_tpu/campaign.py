@@ -11,6 +11,11 @@ campaign runner is that loop with supervision (doc/robustness.md):
 * each run is a child ``nmz-tpu run`` in its OWN session (process
   group); a per-run wall-clock deadline kills the entire group on
   expiry, so orphaned testee children cannot outlive their run;
+* the NEXT attempt's child is started while the current run is going
+  and waits, imported, at a gate before it has read anything (the
+  standby, doc/performance.md "Standby run child"): an attempt tells
+  it to go instead of paying an interpreter's start, and every way out
+  of the campaign ends it;
 * per-phase (run/validate/clean) deadlines are forwarded to the child,
   which enforces them the same way (cli/run_cmd.py, utils/cmd.py);
 * every completed run is classified — ``experiment`` (an outcome,
@@ -45,7 +50,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
-from namazu_tpu.cli.run_cmd import EXIT_TIMEOUT
+from namazu_tpu.cli.run_cmd import EXIT_TIMEOUT, RUN_STANDBY_ENV
 from namazu_tpu.obs import metrics as obs_metrics
 from namazu_tpu.obs import spans as obs_spans
 from namazu_tpu.utils.atomic import atomic_write_json
@@ -148,6 +153,10 @@ class Campaign:
         self._abort = threading.Event()
         self._child: Optional[subprocess.Popen] = None
         self._child_lock = threading.Lock()
+        # the next attempt's run child, waiting at its gate, and the
+        # `_child_env()` it was started with (fork mode; _start_standby)
+        self._standby: Optional[subprocess.Popen] = None
+        self._standby_env: Dict[str, str] = {}
         self._telemetry_server = None
         self._telemetry_path = ""
         # run phases (doc/observability.md "Run phases"): when the last
@@ -344,21 +353,96 @@ class Campaign:
         if server is not None:
             server.shutdown()
 
+    # -- the standby run child -------------------------------------------
+
+    def _start_standby(self) -> None:
+        """Start the NEXT attempt's run child now, while this attempt's
+        run is going: the same ``nmz-tpu run`` in a session of its own,
+        told (``RUN_STANDBY_ENV``) to wait at its gate — a blocking
+        read of the pipe held here — before it reads anything, so its
+        interpreter start and imports overlap the testee. It cannot
+        outlive this process: however the supervisor dies, the pipe's
+        other end sees EOF and the child exits, nothing touched."""
+        env = self._child_env()
+        try:
+            self._standby = subprocess.Popen(
+                self._run_argv(), env={**env, RUN_STANDBY_ENV: "1"},
+                stdin=subprocess.PIPE, start_new_session=True)
+        except OSError as e:
+            log.warning("could not start a standby run child (%s); the "
+                        "next attempt starts cold", e)
+            return
+        self._standby_env = env
+
+    def _take_standby(self, spawned: Optional[float]
+                      ) -> Optional[subprocess.Popen]:
+        """Tell the standby to go and hand it over as this attempt's
+        child; None when there is none or it died while waiting (the
+        attempt then starts cold). The go line carries what ``Popen``
+        would have put into this attempt's environment: the spawn stamp
+        and whatever of ``_child_env()`` moved since the standby was
+        started (null = no longer set)."""
+        child, self._standby = self._standby, None
+        if child is None:
+            return None
+        was, env = self._standby_env, self._child_env()
+        moved: Dict[str, Optional[str]] = {
+            k: v for k, v in env.items() if was.get(k) != v}
+        moved.update({k: None for k in was if k not in env})
+        go = json.dumps({"spawned": spawned, "env": moved}) + "\n"
+        if child.poll() is None:
+            try:
+                child.stdin.write(go.encode())
+                # closed at once: the run's own children read EOF there
+                child.stdin.close()
+                return child
+            except OSError:
+                pass
+        log.warning("the standby run child died while waiting (exit %s); "
+                    "starting this attempt cold", child.poll())
+        self._end_standby(child)
+        return None
+
+    def _end_standby(self, child: Optional[subprocess.Popen] = None) -> None:
+        """End a standby no attempt will take (every way out of the
+        campaign comes through here): EOF at its gate is "never
+        wanted" and it exits by itself; its whole session is killed if
+        it has not within a second. Reaped either way."""
+        if child is None:
+            child, self._standby = self._standby, None
+        if child is None:
+            return
+        try:
+            child.stdin.close()
+        except OSError:
+            pass
+        try:
+            child.wait(timeout=1.0)
+        except subprocess.TimeoutExpired:
+            kill_process_group(child)
+
     def _one_attempt(self, slot_index: int = 0) -> Dict[str, Any]:
-        """One attempt: fork mode spawns an ``nmz-tpu run`` child in
-        its own session under the wall deadline; serve mode leases a
-        run slot on the shared orchestrator instead (doc/tenancy.md)."""
+        """One attempt: fork mode runs an ``nmz-tpu run`` child in its
+        own session under the wall deadline — the standby told to go,
+        else a cold spawn — and starts the next attempt's standby;
+        serve mode leases a run slot on the shared orchestrator instead
+        (doc/tenancy.md)."""
         if self.spec.serve_url:
             return self._one_serve_attempt(slot_index)
         spec = self.spec
         observed = obs_metrics.enabled()
         if observed and self._stored_runs is None:
             self._stored_runs, _ = self._newest_run()
+        # the moment this run is wanted: the origin of its phases, of
+        # `wall_s` and of the wall deadline, standby or not
         t0 = time.monotonic()
-        child = subprocess.Popen(
-            self._run_argv(),
-            env=self._child_env(t0 if observed else None),
-            start_new_session=True)
+        child = self._take_standby(t0 if observed else None)
+        warm = child is not None
+        if child is None:
+            child = subprocess.Popen(
+                self._run_argv(),
+                env=self._child_env(t0 if observed else None),
+                start_new_session=True)
         with self._child_lock:
             self._child = child
         timed_out = False
@@ -366,6 +450,8 @@ class Campaign:
             deadline = (spec.run_wall_deadline_s
                         if spec.run_wall_deadline_s > 0 else None)
             try:
+                if not self._stop_requested.is_set():
+                    self._start_standby()
                 child.wait(timeout=deadline)
             except subprocess.TimeoutExpired:
                 timed_out = True
@@ -398,7 +484,8 @@ class Campaign:
             cls = CLASS_INFRA  # nonzero exit or signal death (rc < 0)
         attempt = {"class": cls, "exit_status": rc,
                    "wall_s": round(wall_s, 3),
-                   "wall_deadline_hit": timed_out}
+                   "wall_deadline_hit": timed_out,
+                   "start": "standby" if warm else "cold"}
         if observed:
             phases = self._attempt_phases(t0, wall_s)
             if phases:
@@ -732,6 +819,7 @@ class Campaign:
         try:
             return self._loop()
         finally:
+            self._end_standby()
             self._stop_telemetry()
             self._restore_signal_handlers(previous_handlers)
             self._checkpoint()

@@ -11,6 +11,7 @@ Driven N times by the user (``for i in $(seq 1 100); do nmz-tpu run d; done``)
 
 from __future__ import annotations
 
+import json
 import os
 import subprocess
 import sys
@@ -28,6 +29,13 @@ from namazu_tpu.utils.log import init_log
 EXIT_OK = 0
 EXIT_INFRA = 1
 EXIT_TIMEOUT = 124  # a phase deadline expired (same convention as timeout(1))
+
+#: a campaign supervisor's word to the run child it starts one run early
+#: (campaign.py, doc/performance.md "Standby run child"): wait at the
+#: gate for the go. Like ``NMZ_RUN_SPAWNED`` it is the supervisor's
+#: alone and is taken out of the environment by the run that reads it;
+#: a bare ``nmz-tpu run`` never sees it and never reads its stdin
+RUN_STANDBY_ENV = "NMZ_RUN_STANDBY"
 
 
 def register(sub) -> None:
@@ -81,7 +89,49 @@ def _deadline(cli_value: Optional[float], cfg: Config, key: str
     return v if v and v > 0 else None
 
 
+def _standby_gate() -> Optional[float]:
+    """A standby run child's wait for its go: one blocking read of one
+    line from stdin, before this process has read, opened or bound
+    anything of the storage. The line is the supervisor's JSON object
+    ``{"spawned": <its monotonic stamp at the go, or null while the
+    campaign is not observed>, "env": {<name>: <value, or null = unset>}}``
+    — what it would have put into this attempt's environment at
+    ``Popen`` time. Returns the stamp of the arrival at the gate, or
+    None when the run was never wanted: EOF (the supervisor closed the
+    pipe, or died) or anything that is not that object."""
+    # what a run imports inline later, whatever its config says: loaded
+    # while nobody waits for it. No config, storage, plugin or policy
+    # object — everything a user can change between two runs is read
+    # after the go
+    import namazu_tpu.calibrate.artifact  # noqa: F401
+    import namazu_tpu.endpoint.agent  # noqa: F401
+    import namazu_tpu.endpoint.rest  # noqa: F401
+    import namazu_tpu.endpoint.uds  # noqa: F401
+    import namazu_tpu.policy.plugins  # noqa: F401
+    from namazu_tpu import obs
+
+    arrived = time.monotonic()
+    try:
+        go = json.loads(sys.stdin.buffer.readline())
+        spawned, env = go["spawned"], go["env"]
+        if spawned is not None:
+            os.environ[obs.spans.RUN_SPAWNED_ENV] = repr(float(spawned))
+        for name, value in env.items():
+            if value is None:
+                os.environ.pop(name, None)
+            else:
+                os.environ[name] = value
+    except (AttributeError, KeyError, OSError, TypeError, ValueError):
+        return None
+    return arrived
+
+
 def run(args) -> int:
+    standby_since = None
+    if os.environ.pop(RUN_STANDBY_ENV, None):
+        standby_since = _standby_gate()
+        if standby_since is None:
+            return EXIT_OK  # never wanted: nothing was touched
     storage_dir = args.storage
     # a user-editable config.toml wins over the init-time config.json
     # snapshot, so swapping the policy between runs of one storage works
@@ -127,7 +177,7 @@ def run(args) -> int:
     # (GET /traces/<run_id>) with the on-disk run dir via one key
     if not cfg.is_set("run_id"):
         cfg.set("run_id", os.path.basename(os.path.normpath(working_dir)))
-    obs.run_begin(str(cfg.get("run_id")), entered)
+    obs.run_begin(str(cfg.get("run_id")), entered, standby_since)
     init_log(os.path.join(working_dir, "nmz.log"))
     if args.journal or bool(cfg.get("event_journal")):
         # the journal lives in the run's own dir: recovery is per-run,

@@ -1169,16 +1169,23 @@ def _trace_annotation(name: str, rid: Optional[str] = None):
 # to the run's stored metadata. The control plane imports no jax, so a
 # run phase carries no profiler annotation.
 
-#: the one environment variable a campaign supervisor exports to its
-#: ``run`` child: ``time.monotonic()`` just before the spawn
-#: (CLOCK_MONOTONIC is host-wide on Linux, so the child's stamps are on
-#: the same clock)
+#: the stamp a campaign supervisor hands its ``run`` child:
+#: ``time.monotonic()`` at the moment it wants the run — just before the
+#: spawn of a cold child (this variable in its environment), at the go
+#: of a standby one (the go line, which the child's gate writes here:
+#: cli/run_cmd.py). CLOCK_MONOTONIC is host-wide on Linux, so the
+#: child's stamps are on the same clock
 RUN_SPAWNED_ENV = "NMZ_RUN_SPAWNED"
 
-#: what a run records of itself, and what only its supervisor sees
+#: what a run records of itself from that stamp on, the wait of a
+#: standby child before it (a row of the run's too, kept out of
+#: RUN_PHASES because the benchmark's eight ``run_<phase>_s`` are that
+#: tuple's names), and what only the supervisor sees
 RUN_PHASES = ("boot", "prepare", "testee", "drain", "search", "endpoints",
               "validate", "record")
+STANDBY_PHASE = "standby"
 SUPERVISOR_PHASES = ("teardown", "respawn")
+_STORED_PHASES = frozenset(RUN_PHASES + (STANDBY_PHASE,))
 
 _PHASE_HELP = {
     SEARCH_PHASE: "wall time per search-plane phase",
@@ -1364,12 +1371,17 @@ def run_entered() -> Optional[float]:
     return time.monotonic() if metrics.enabled() else None
 
 
-def run_begin(run_id: str, entered: Optional[float]) -> None:
+def run_begin(run_id: str, entered: Optional[float],
+              standby_since: Optional[float] = None) -> None:
     """Open the calling thread's run scope under ``run_id`` (the run
     directory's name). The run's rows start at the supervisor's spawn
     stamp (``RUN_SPAWNED_ENV``), and ``boot`` is the row from there to
     ``entered``; a bare ``nmz-tpu run`` has no such stamp, records no
-    ``boot`` and counts from ``entered``. The variable is taken out of
+    ``boot`` and counts from ``entered``. A run whose child stood by
+    (``standby_since``: its arrival at the gate) has ``standby`` before
+    that: the wait from the arrival to the stamp, which is then the
+    go's, so the row starts before 0 and a child the go found still
+    importing has one of no length. The variable is taken out of
     the environment: what this run spawns is no child of that stamp.
     No scope while observability is off (``entered`` None)."""
     stamp = os.environ.pop(RUN_SPAWNED_ENV, None)
@@ -1384,6 +1396,10 @@ def run_begin(run_id: str, entered: Optional[float]) -> None:
     _scope.run = (entered if spawned is None else spawned,
                   _span_ring.end())
     if spawned is not None:
+        if standby_since is not None:
+            waited = max(0.0, spawned - standby_since)
+            _record_phase(RUN_PHASE, STANDBY_PHASE, waited,
+                          spawned - waited, None, {})
         _record_phase(RUN_PHASE, "boot", entered - spawned, spawned,
                       None, {})
 
@@ -1424,7 +1440,7 @@ def run_end() -> Optional[list]:
     rows = [[name, parent, round(t_mono - origin, 6), round(seconds, 6)]
             for r, name, parent, _wall, t_mono, seconds, _thread, _attrs
             in _span_ring.since(cursor, SPAN_RING_ROWS)["rows"]
-            if r == rid and name in RUN_PHASES]
+            if r == rid and name in _STORED_PHASES]
     # the ring holds a phase where it ended: a parent before its children
     rows.sort(key=lambda row: (row[2], -row[3]))
     request_end()
@@ -1446,7 +1462,7 @@ def run_phases_observed(rows) -> None:
             seconds = float(seconds)
         except (TypeError, ValueError):
             continue
-        if (name in RUN_PHASES or name in SUPERVISOR_PHASES) \
+        if (name in _STORED_PHASES or name in SUPERVISOR_PHASES) \
                 and 0.0 <= seconds < float("inf"):
             _observe_phase(RUN_PHASE, name, seconds)
 

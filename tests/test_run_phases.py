@@ -179,18 +179,23 @@ def test_a_campaign_adds_what_only_the_supervisor_sees(tmp_path, fresh_obs):
     state = load_checkpoint(storage)
     first, second = [s["attempts"][-1] for s in state["slots"]]
     child = ["boot"] + OWN
+    # the first attempt starts cold; the second takes the standby that
+    # was started beside the first (tests/test_campaign_standby.py)
+    assert (first["start"], second["start"]) == ("cold", "standby")
     assert [r[0] for r in first["phases"]] == child + ["teardown"]
-    assert [r[0] for r in second["phases"]] == (
-        ["respawn"] + child + ["teardown"])
+    assert sorted(r[0] for r in second["phases"][:3]) == [
+        "boot", "respawn", "standby"]
+    assert [r[0] for r in second["phases"][3:]] == OWN + ["teardown"]
     for i, attempt in enumerate((first, second)):
         rows = attempt["phases"]
         # the child's rows are the ones its run stored, untouched
-        assert [r for r in rows if r[0] in spans.RUN_PHASES] \
+        assert [r for r in rows if r[0] not in spans.SUPERVISOR_PHASES] \
             == stored_phases(storage, i)
         assert all(r[0] in spans.SUPERVISOR_PHASES + spans.RUN_PHASES
-                   for r in rows)
-        starts = [r[2] for r in rows]
-        assert starts == sorted(starts)
+                   + (spans.STANDBY_PHASE,) for r in rows)
+        # the two rows before 0 each end at 0; from 0 on, in order
+        starts = [r[2] for r in rows if r[0] not in ("respawn", "standby")]
+        assert starts == sorted(starts) and starts[0] == 0.0
         by_name = {r[0]: r for r in rows}
         teardown, record = by_name["teardown"], by_name["record"]
         assert teardown[1] is None and teardown[3] >= 0
