@@ -112,11 +112,11 @@ def _device_rows_scatter(archive, failures, rows, archive_slots,
     import jax
 
     if _rows_scatter_jit is None:
-        def f(a, f_, r, sa, sf):
+        def rows_scatter(a, f, r, sa, sf):
             return (a.at[sa].set(r, mode="drop"),
-                    f_.at[sf].set(r, mode="drop"))
+                    f.at[sf].set(r, mode="drop"))
 
-        _rows_scatter_jit = jax.jit(f, donate_argnums=(0, 1))
+        _rows_scatter_jit = jax.jit(rows_scatter, donate_argnums=(0, 1))
     return _rows_scatter_jit(archive, failures, rows, archive_slots,
                              failure_slots)
 
@@ -133,10 +133,10 @@ def _device_row_update(buf, row, slot: int):
     import jax.numpy as jnp
 
     if _row_update_jit is None:
-        def f(b, r, s):
+        def row_update(b, r, s):
             return jax.lax.dynamic_update_slice(b, r[None], (s, 0))
 
-        _row_update_jit = jax.jit(f, donate_argnums=(0,))
+        _row_update_jit = jax.jit(row_update, donate_argnums=(0,))
     return _row_update_jit(buf, jnp.asarray(row),
                            jnp.asarray(slot, jnp.int32))
 
@@ -191,29 +191,36 @@ class _ResidentTraces:
         L — ``te.pad_trace_row``, the host stacker's exact pad fills."""
         return te.pad_trace_row(enc, L)
 
+    def _put(self, key: str, enc: "te.EncodedTrace", slot: int) -> None:
+        """Write one trace's rows at ``slot`` — the ONE way a row gets
+        into the buffers, for the first staging and for every later
+        append, so the row update (one program per dtype) is lowered
+        where the buffers are born and no later request can find it
+        cold, whenever its run first moves the reference envelope."""
+        rows = self._pack(enc, self.L)
+        for name in self.bufs:
+            self.bufs[name] = _device_row_update(
+                self.bufs[name], rows[name], slot)
+        self.slots[key] = slot
+        self.order.append(key)
+
     def _rebuild(self, encs, keys, Lmax: int) -> None:
         import jax.numpy as jnp
 
         self.capacity = max(self.capacity, len(encs))
         self.L = max(self.L, Lmax)
-        host = {
-            "hint": np.zeros((self.capacity, self.L), np.int32),
-            "arr": np.zeros((self.capacity, self.L), np.float32),
-            "mask": np.zeros((self.capacity, self.L), bool),
-            "flt": np.zeros((self.capacity, self.L), bool),
+        shape = (self.capacity, self.L)
+        self.bufs = {
+            "hint": jnp.asarray(np.zeros(shape, np.int32)),
+            "arr": jnp.asarray(np.zeros(shape, np.float32)),
+            "mask": jnp.asarray(np.zeros(shape, bool)),
+            "flt": jnp.asarray(np.zeros(shape, bool)),
         }
         self.slots = {}
         self.order = []
         for k, e in zip(keys, encs):
-            if k in self.slots:
-                continue
-            slot = len(self.slots)
-            rows = self._pack(e, self.L)
-            for name in host:
-                host[name][slot] = rows[name]
-            self.slots[k] = slot
-            self.order.append(k)
-        self.bufs = {name: jnp.asarray(a) for name, a in host.items()}
+            if k not in self.slots:
+                self._put(k, e, len(self.slots))
         self.rebuilds += 1
 
     def _append(self, key: str, enc: "te.EncodedTrace", live) -> None:
@@ -224,12 +231,7 @@ class _ResidentTraces:
             victim = next(k for k in self.order if k not in live)
             slot = self.slots.pop(victim)
             self.order.remove(victim)
-        rows = self._pack(enc, self.L)
-        for name in self.bufs:
-            self.bufs[name] = _device_row_update(
-                self.bufs[name], rows[name], slot)
-        self.slots[key] = slot
-        self.order.append(key)
+        self._put(key, enc, slot)
         self.appends += 1
 
     def view(self, encs):
@@ -377,15 +379,22 @@ def configure_compile_cache() -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
+#: a ring of ``_EmbedBatch.writes`` -> its ``ring`` label on the
+#: ``nmz_ring_rows_*`` counters
+_RING_LABEL = {"archive": "archive", "failures": "failure"}
+
+
 class _EmbedBatch:
     """What an open :meth:`SearchBase.embed_batch` has queued: the
     traces to embed, in order, and per ring the ``(slot, row)`` writes
     the adds worked out. ``calls`` = device calls its flush made,
-    ``groups`` = padded trace lengths among the queued traces."""
+    ``groups`` = padded trace lengths among the queued traces,
+    ``overwrites`` = per ring, the writes whose slot held a live row."""
 
     def __init__(self) -> None:
         self.encs: list = []
         self.writes: dict = {"archive": [], "failures": []}
+        self.overwrites: dict = {"archive": 0, "failures": 0}
         # id(trace) -> its archive row, until a failure-ring write
         # claims it: a new failure's row IS its archive row, and no row
         # is written to two slots of one ring
@@ -628,6 +637,9 @@ class SearchBase:
             which: {row: slot for slot, row in dict(writes).items()}
             for which, writes in batch.writes.items()}
         batch.groups = len({e.hint_ids.shape[0] for e in batch.encs})
+        for which, writes in batch.writes.items():
+            obs.ring_rows(_RING_LABEL[which], len(writes),
+                          batch.overwrites[which])
         for indices, rows in self._embed_chunks(batch.encs):
             batch.calls += 1
             host = np.asarray(rows)
@@ -658,6 +670,8 @@ class SearchBase:
             slot = self._archive_n % self.cfg.archive_size
             row = batch.queue(encoded)
             batch.writes["archive"].append((slot, row))
+            batch.overwrites["archive"] += \
+                self._archive_n >= self.cfg.archive_size
             batch.unclaimed[id(encoded)] = row
             self.archive_labels[slot] = 1.0 if reproduced else 0.0
             if self.guidance_feats is not None:
@@ -675,12 +689,15 @@ class SearchBase:
 
         digest = trace_digest(encoded)
         if digest in self._failure_digest_set:
+            obs.failure_signatures_deduped()
             return
         with self.embed_batch() as batch:
             slot = self._failure_n % self.cfg.failure_size
             evicted = self._failure_digests[slot]
             if evicted:
                 self._failure_digest_set.discard(evicted)
+            batch.overwrites["failures"] += \
+                self._failure_n >= self.cfg.failure_size
             row = batch.unclaimed.pop(id(encoded), None)
             if row is None:
                 row = batch.queue(encoded)
@@ -976,12 +993,19 @@ class ScheduleSearch(SearchBase):
         if self._dev_pairs is None or self._dev_pairs_src is not self.pairs:
             self._dev_pairs = jnp.asarray(self.pairs)
             self._dev_pairs_src = self.pairs
-        if self._dev_mirrors["archive"] is None:
-            self._dev_mirrors["archive"] = jnp.asarray(self.archive)
-        if self._dev_mirrors["failures"] is None:
-            self._dev_mirrors["failures"] = jnp.asarray(self.failures)
-        return (encs, trace, self._dev_pairs,
-                self._dev_mirrors["archive"], self._dev_mirrors["failures"])
+        m = self._dev_mirrors
+        if m["archive"] is None or m["failures"] is None:
+            # staged whole, through the scatter that keeps them in step
+            # from the next ingest on (``_mirror_rows``), with every
+            # slot out of range: nothing is written, and the program is
+            # lowered in the request that builds the mirrors instead of
+            # in the first one that finds them built
+            m["archive"], m["failures"] = _device_rows_scatter(
+                jnp.asarray(self.archive), jnp.asarray(self.failures),
+                np.zeros((EMBED_CHUNK, self.cfg.K), np.float32),
+                *(np.full((EMBED_CHUNK,), ring.shape[0], np.int32)
+                  for ring in (self.archive, self.failures)))
+        return encs, trace, self._dev_pairs, m["archive"], m["failures"]
 
     def _place_state(self) -> None:
         """Commit the island state to its mesh sharding (population

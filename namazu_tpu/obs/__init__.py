@@ -124,11 +124,13 @@ from namazu_tpu.obs.spans import (  # noqa: F401
     ensure_compile_listener,
     evolve_request,
     evolve_table_request,
+    failure_signatures_deduped,
     ingest_cached_runs,
     ingest_embed_call,
     ingest_events,
     ingest_runs,
     rerank_request,
+    ring_rows,
     run_begin,
     run_end,
     run_entered,

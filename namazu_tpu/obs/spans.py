@@ -74,6 +74,9 @@ INGEST_CACHED_RUNS = "nmz_ingest_cached_runs_total"
 EVOLVE_REQUESTS = "nmz_evolve_requests_total"
 EVOLVE_TABLE_REQUESTS = "nmz_evolve_table_requests_total"
 RERANK_REQUESTS = "nmz_rerank_requests_total"
+RING_ROWS_WRITTEN = "nmz_ring_rows_written_total"
+RING_ROWS_OVERWRITTEN = "nmz_ring_rows_overwritten_total"
+FAILURE_SIGNATURES_DEDUPED = "nmz_failure_signatures_deduped_total"
 # the policy's reorder buffer (release_mode "reorder"): windows drained,
 # those whose paced drain ended after the NEXT window's boundary (the
 # scorer assumes a window's slots run from its own close), and how many
@@ -1476,16 +1479,17 @@ def ensure_compile_listener() -> None:
     ``nmz_compiles_total`` and ``nmz_compile_seconds{phase}`` with the
     innermost phase open on the compiling thread (``none`` outside
     one), and leaves a ``compile`` row under the current request —
-    which step recompiled, for which request. Called by the search
-    backends' constructors (the first thing in a process that can
-    compile)."""
+    which step recompiled, for which request, and under ``fun_name``
+    the name jax gives the lowered module (``jit(<function>)``; absent
+    where a jax passes none). Called by the search backends'
+    constructors (the first thing in a process that can compile)."""
     global _compile_listener_on
     if _compile_listener_on:
         return
     _compile_listener_on = True
     import jax.monitoring
 
-    def on_duration(event, duration, **_kw):
+    def on_duration(event, duration, fun_name=None, **_kw):
         if event != LOWERING_EVENT or not metrics.enabled():
             return
         phase = _open_phase()
@@ -1497,7 +1501,8 @@ def ensure_compile_listener() -> None:
             "was open on the compiling thread", ("phase",),
         ).labels(phase=phase or "none").observe(duration)
         _append_row("compile", float(duration),
-                    time.monotonic() - duration, phase, {})
+                    time.monotonic() - duration, phase,
+                    {} if fun_name is None else {"fun_name": str(fun_name)})
 
     jax.monitoring.register_event_duration_secs_listener(on_duration)
 
@@ -1605,6 +1610,37 @@ def rerank_request(path: str) -> None:
     metrics.get().counter(
         RERANK_REQUESTS, "re-ranked replies by where the pick finished",
         ("path",)).labels(path=path).inc()
+
+
+def ring_rows(ring: str, written: int, overwritten: int) -> None:
+    """Rows one embed batch gave slots of a search's ring (``archive``
+    | ``failure``), counted where the slot is decided
+    (``models/search.py`` ``add_executed_trace`` / ``add_failure_trace``)
+    and written here once per batch: ``written`` of them in all,
+    ``overwritten`` onto a slot that held a live row (the ring had gone
+    round). Overwritten over written is the regime a search is in: 0 %
+    while the history fits the ring, 100 % from the request on whose
+    history is past capacity before it starts."""
+    if not metrics.enabled() or not written:
+        return
+    reg = metrics.get()
+    reg.counter(RING_ROWS_WRITTEN, "rows given a slot of a search's "
+                "ring", ("ring",)).labels(ring=ring).inc(written)
+    # written at 0 too: a share over a sample that is not there reads
+    # as nothing to read, not as 0 %
+    reg.counter(RING_ROWS_OVERWRITTEN, "rows given a slot that held a "
+                "live row", ("ring",)).labels(ring=ring).inc(overwritten)
+
+
+def failure_signatures_deduped(n: int = 1) -> None:
+    """Failures whose signature the failure ring already holds, passed
+    over by ``add_failure_trace``: what keeps a re-fed history from
+    spending a slot per request on each stored failure."""
+    if not metrics.enabled():
+        return
+    metrics.get().counter(
+        FAILURE_SIGNATURES_DEDUPED, "failure traces passed over because "
+        "their signature is already in the failure ring").inc(n)
 
 
 def search_device_trace(path: str) -> None:

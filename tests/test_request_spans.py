@@ -321,6 +321,28 @@ def test_a_recompile_inside_evolve_is_counted_there(fresh_obs, tmp_path):
     assert obs.metrics.registry().value(spans.COMPILES) == total + 1
 
 
+def test_a_compile_row_names_the_lowered_program(fresh_obs, tmp_path):
+    from namazu_tpu.models.ingest import IngestParams, ingest_history
+    from namazu_tpu.models.search import build_search_from_params
+
+    st = make_history(tmp_path / "st")
+    search = build_search_from_params(SEARCH_PARAMS)
+    refs = ingest_history(search, st, IngestParams(**INGEST_PARAMS))
+    # a chunk length no search of this process has dispatched: the
+    # fused step is lowered whatever ran before in the process
+    search.run(refs, generations=5)
+    rows = [r for r in fresh_obs.since(0)["rows"] if r[1] == "compile"]
+    assert rows and all(r[7].get("fun_name") for r in rows)
+    # jax names the module after the jitted function
+    fused = [r for r in rows if r[7]["fun_name"] == "jit(fused)"]
+    assert fused and {r[2] for r in fused} == {"evolve"}
+    # ... and ``tools spans`` prints it on the row's line
+    text = export.render_span_trees(fresh_obs.since(0)["rows"])
+    assert any(line.lstrip().startswith("compile")
+               and "fun_name=jit(fused)" in line
+               for line in text.splitlines())
+
+
 # -- the off switch -----------------------------------------------------------
 
 
