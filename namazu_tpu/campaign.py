@@ -31,6 +31,12 @@ campaign runner is that loop with supervision (doc/robustness.md):
   will not unbreak it);
 * after every attempt the resumable ``campaign.json`` checkpoint is
   atomically rewritten, so a crashed supervisor resumes where it died;
+* between one run's reap and the next run's go the supervisor touches
+  only the run dirs the attempt it has just reaped created: one storage
+  handle a campaign, the progress document folded run by run, the pgid
+  sweep over the attempt's own dirs (doc/performance.md "Between
+  runs"), so that stretch is as short a thousand runs into a hunt as
+  ten runs into it;
 * SIGINT/SIGTERM request a graceful stop (finish the in-flight run,
   checkpoint, exit); a second signal kills the in-flight group and
   aborts immediately.
@@ -161,10 +167,15 @@ class Campaign:
         self._telemetry_path = ""
         # run phases (doc/observability.md "Run phases"): when the last
         # attempt's child was reaped (monotonic; the start of the next
-        # attempt's `respawn`), and how many runs the storage held then
-        # (a run beyond that count is the next attempt's)
+        # attempt's `respawn`)
         self._last_reap: Optional[float] = None
-        self._stored_runs: Optional[int] = None
+        # the campaign's one storage handle (doc/performance.md "Between
+        # runs"), how many runs it had allocated when it was last caught
+        # up (a run from there on is the next attempt's; None = not
+        # known), and the progress rows kept from slot to slot
+        self._storage = None
+        self._allocated: Optional[int] = None
+        self._fold = None
 
     # -- checkpoint ------------------------------------------------------
 
@@ -267,15 +278,18 @@ class Campaign:
         argv += spec.extra_run_args
         return argv
 
-    def _child_env(self, spawned: Optional[float] = None
-                   ) -> Dict[str, str]:
+    def _child_env(self, spawned: Optional[float] = None,
+                   respawn: Optional[float] = None) -> Dict[str, str]:
         # the child must be able to import the framework even when it is
         # not installed site-wide; CmdFactory.env() owns that logic
         env = CmdFactory(extra_env=self.spec.extra_env).env()
         if spawned is not None:
             # the origin of the child's run phases: its `boot` is from
-            # this stamp to its own first (obs/spans.py run_begin)
+            # this stamp to its own first (obs/spans.py run_begin), its
+            # `respawn` what the supervisor took to get here
             env[obs_spans.RUN_SPAWNED_ENV] = repr(spawned)
+            if respawn is not None:
+                env[obs_spans.RUN_RESPAWN_ENV] = repr(respawn)
         if self._telemetry_path:
             # run children push their metrics (and forward their
             # inspectors') to the supervisor's collector — the one
@@ -374,14 +388,16 @@ class Campaign:
             return
         self._standby_env = env
 
-    def _take_standby(self, spawned: Optional[float]
+    def _take_standby(self, spawned: Optional[float],
+                      respawn: Optional[float] = None
                       ) -> Optional[subprocess.Popen]:
         """Tell the standby to go and hand it over as this attempt's
         child; None when there is none or it died while waiting (the
         attempt then starts cold). The go line carries what ``Popen``
-        would have put into this attempt's environment: the spawn stamp
-        and whatever of ``_child_env()`` moved since the standby was
-        started (null = no longer set)."""
+        would have put into this attempt's environment: the spawn
+        stamp, the ``respawn`` that ended at it, and whatever of
+        ``_child_env()`` moved since the standby was started (null = no
+        longer set)."""
         child, self._standby = self._standby, None
         if child is None:
             return None
@@ -389,7 +405,8 @@ class Campaign:
         moved: Dict[str, Optional[str]] = {
             k: v for k, v in env.items() if was.get(k) != v}
         moved.update({k: None for k in was if k not in env})
-        go = json.dumps({"spawned": spawned, "env": moved}) + "\n"
+        go = json.dumps({"spawned": spawned, "respawn": respawn,
+                         "env": moved}) + "\n"
         if child.poll() is None:
             try:
                 child.stdin.write(go.encode())
@@ -431,17 +448,18 @@ class Campaign:
             return self._one_serve_attempt(slot_index)
         spec = self.spec
         observed = obs_metrics.enabled()
-        if observed and self._stored_runs is None:
-            self._stored_runs, _ = self._newest_run()
         # the moment this run is wanted: the origin of its phases, of
-        # `wall_s` and of the wall deadline, standby or not
+        # `wall_s` and of the wall deadline, standby or not; and the
+        # end of its `respawn`, which the run is told with the stamp
         t0 = time.monotonic()
-        child = self._take_standby(t0 if observed else None)
+        respawn = (t0 - self._last_reap
+                   if observed and self._last_reap is not None else None)
+        child = self._take_standby(t0 if observed else None, respawn)
         warm = child is not None
         if child is None:
             child = subprocess.Popen(
                 self._run_argv(),
-                env=self._child_env(t0 if observed else None),
+                env=self._child_env(t0 if observed else None, respawn),
                 start_new_session=True)
         with self._child_lock:
             self._child = child
@@ -468,8 +486,10 @@ class Campaign:
             # its run script's process group orphaned in its own
             # session, outside the group we just killed — the pgid
             # breadcrumb run_cmd wrote points the sweep at it
-            # (doc/robustness.md "Chaos plane")
-            sweep_stale_pgid_files(spec.storage_dir)
+            # (doc/robustness.md "Chaos plane"); it lies in a dir this
+            # attempt created, if anywhere
+            new_runs = self._catch_up()
+            self._sweep_pgid_files(new_runs)
         wall_s = time.monotonic() - t0
         rc = child.returncode
         if timed_out:
@@ -487,52 +507,105 @@ class Campaign:
                    "wall_deadline_hit": timed_out,
                    "start": "standby" if warm else "cold"}
         if observed:
-            phases = self._attempt_phases(t0, wall_s)
+            phases = self._attempt_phases(t0, wall_s, respawn, new_runs)
             if phases:
                 attempt["phases"] = phases
         return attempt
 
-    def _newest_run(self):
-        """How many runs the storage holds, and the newest one's
-        ``metadata["phases"]`` (the rows the run child stored of itself,
-        cli/run_cmd.py) or ``[]``. Best-effort like the progress
-        publication: a storage that cannot be read costs the attempt
-        its child rows, never the campaign its loop."""
-        from namazu_tpu.storage import load_storage
+    # -- the campaign's storage handle (doc/performance.md "Between runs")
 
-        try:
-            st = load_storage(self.spec.storage_dir)
+    def _open_storage(self):
+        """The campaign's one storage handle; opened — ``init()``'s
+        walk over every stored run — where there is none: at the
+        campaign's start or resume, and after something failed on it."""
+        if self._storage is None:
+            from namazu_tpu.storage import load_storage
+
+            self._storage = load_storage(self.spec.storage_dir)
+        return self._storage
+
+    def _close_storage(self) -> None:
+        storage, self._storage = self._storage, None
+        self._allocated = None
+        if storage is not None:
             try:
-                n = st.nr_stored_histories()
-                rows = st.get_metadata(n - 1).get("phases") if n else None
-                return n, [list(r) for r in rows or []]
-            finally:
-                st.close()
-        except Exception:
-            log.warning("could not read the newest run's phases; "
-                        "continuing", exc_info=True)
-            return self._stored_runs, []
+                storage.close()
+            except Exception:
+                log.warning("storage close failed", exc_info=True)
 
-    def _attempt_phases(self, t0: float, wall_s: float) -> List[list]:
+    def _catch_up(self) -> Optional[range]:
+        """Catch the handle up with what an attempt just allocated
+        (``refresh()``: ``storage.json`` re-read, ``init()``'s
+        quarantine applied to the new dirs alone) and return the new
+        runs' indices: the dirs the attempt created. None where that is
+        not known (no handle until now, or the storage cannot be read:
+        anything may be new). Best-effort like the progress
+        publication: a storage that cannot be read costs the attempt
+        its child rows and the sweep its aim, never the campaign its
+        loop."""
+        try:
+            storage = self._open_storage()
+            before = self._allocated
+            self._allocated = storage.refresh()
+        except Exception:
+            log.warning("could not catch the storage handle up; "
+                        "continuing", exc_info=True)
+            self._close_storage()
+            return None
+        if before is None:
+            return None
+        return range(min(before, self._allocated), self._allocated)
+
+    def _sweep_pgid_files(self, runs: Optional[range]) -> None:
+        """Sweep the breadcrumbs of ``runs``' dirs; of every entry of
+        the storage where ``runs`` is None (the campaign's start; an
+        attempt whose dirs are not known)."""
+        storage_dir = self.spec.storage_dir
+        if runs is not None and self._storage is not None:
+            dirs = [self._storage.run_dir(i) for i in runs]
+        else:
+            try:
+                dirs = [os.path.join(storage_dir, name)
+                        for name in sorted(os.listdir(storage_dir))]
+            except OSError:
+                return
+        sweep_stale_pgid_files(dirs)
+
+    def _stored_phases(self, runs: Optional[range]) -> List[list]:
+        """``metadata["phases"]`` of the run an attempt stored (the
+        rows the run child stored of itself, cli/run_cmd.py), looked
+        for in the dirs that attempt created; ``[]`` where it stored
+        none."""
+        for i in reversed(runs or ()):
+            try:
+                rows = self._storage.get_metadata(i).get("phases")
+            except Exception:
+                continue  # allocated, no result: killed or aborted
+            return [list(r) for r in rows or []]
+        return []
+
+    def _attempt_phases(self, t0: float, wall_s: float,
+                        respawn: Optional[float],
+                        new_runs: Optional[range]) -> List[list]:
         """One attempt's cycle as ``[name, parent, start_s, seconds]``
         rows counted from the spawn stamp ``t0``: the rows the child
         stored with its run, and around them what only the supervisor
         sees — ``respawn``, from the previous attempt's reap to this
-        spawn (so it starts before 0), and ``teardown``, from the end of
-        the child's last row to the reap (the ``result.json`` write, the
-        storage's close, the exit hooks, the interpreter's exit). An
-        attempt whose child stored no run has neither child rows nor
-        ``teardown``. The two are observed here, into the supervisor's
-        own ``nmz_run_phase_seconds``; the child observed its own."""
+        spawn (so it starts before 0; the run was told it and stored a
+        row of its own, which is left out here: this one stands for
+        both), and ``teardown``, from the end of the child's last row
+        to the reap (the ``result.json`` write, the storage's close,
+        the exit hooks, the interpreter's exit). An attempt whose child
+        stored no run has neither child rows nor ``teardown``. The two
+        are observed here, into the supervisor's own
+        ``nmz_run_phase_seconds``; the child observed its own."""
         before, after = [], []
-        if self._last_reap is not None:
-            gap = t0 - self._last_reap
-            before.append(["respawn", None, round(-gap, 6), round(gap, 6)])
+        if respawn is not None:
+            before.append([obs_spans.RESPAWN_PHASE, None,
+                           round(-respawn, 6), round(respawn, 6)])
         self._last_reap = t0 + wall_s
-        n, rows = self._newest_run()
-        if n == self._stored_runs:
-            rows = []  # the newest run is an earlier attempt's
-        self._stored_runs = n
+        rows = [r for r in self._stored_phases(new_runs)
+                if not r or r[0] != obs_spans.RESPAWN_PHASE]
         try:
             end = max(r[2] + r[3] for r in rows if r[1] is None)
         except (TypeError, ValueError, IndexError):
@@ -558,6 +631,9 @@ class Campaign:
                     "wall_s": round(time.monotonic() - t0, 3),
                     "wall_deadline_hit": False, "error": str(e)}
         wall_s = time.monotonic() - t0
+        # the slot's run is stored in this storage and finished: the
+        # progress fold reads it like a run child's
+        self._catch_up()
         if self._abort.is_set():
             cls = CLASS_INTERRUPTED
         elif crashed:
@@ -817,15 +893,31 @@ class Campaign:
         previous_handlers = self._install_signal_handlers()
         self._start_telemetry()
         try:
+            # the one place a campaign, fresh or resumed, visits every
+            # stored run, before its first run is wanted: the handle's
+            # `init()`, the sweep for a breadcrumb that a supervisor
+            # killed earlier may have left in any dir, and the rows the
+            # first progress document will rest on
+            self._catch_up()
+            self._sweep_pgid_files(None)
+            try:
+                self._fold_new_runs()
+            except Exception:
+                log.warning("could not read the stored history; the "
+                            "first slot will", exc_info=True)
+                self._fold = None
             return self._loop()
         finally:
             self._end_standby()
             self._stop_telemetry()
             self._restore_signal_handlers(previous_handlers)
+            self._close_storage()
             self._checkpoint()
 
     def _finish(self, reason: str, status: int) -> int:
         self.state["stopped_reason"] = reason
+        # the document a campaign leaves behind rests on no fold
+        self._publish_progress(from_scratch=True)
         self._checkpoint()
         counts: Dict[str, int] = {}
         for slot in self.state["slots"]:
@@ -910,30 +1002,70 @@ class Campaign:
             if self._stop_requested.wait(delay):
                 return slot
 
-    def _publish_progress(self) -> Optional[Dict[str, Any]]:
+    def _fold_new_runs(self, from_scratch: bool = False) -> None:
+        """Bring the progress rows kept (analytics.ProgressFold) up to
+        date with the storage: read the runs allocated since the last
+        time, from the campaign's own handle. The whole history is read
+        — a fresh handle, every run — where nothing was read yet (the
+        campaign's start or resume), where the storage holds fewer runs
+        than were folded, and ``from_scratch``."""
+        from namazu_tpu.obs import analytics
+
+        if from_scratch:
+            self._close_storage()
+            self._fold = None
+        if self._allocated is None:
+            self._catch_up()
+        if self._fold is not None \
+                and self._fold.contradicted_by(self._allocated):
+            self._close_storage()
+            self._fold = None
+            self._catch_up()
+        if self._fold is None:
+            self._fold = analytics.ProgressFold()
+        self._fold.fold(self._open_storage(), self._allocated)
+
+    def _publish_progress(self, from_scratch: bool = False
+                          ) -> Optional[Dict[str, Any]]:
         """The live progress surface's supervisor face: after every
-        slot, recompute the storage's sequential statistics
-        (obs/analytics.progress_stats), publish the nmz_campaign_*
-        gauges the fleet federates, and stash the document in the
-        in-memory state for the on_slot callback. Best-effort — a
-        mid-write storage or a stats bug degrades to None, never kills
-        the campaign loop."""
+        slot, bring the storage's sequential statistics
+        (obs/analytics.progress_stats) up to date, publish the
+        nmz_campaign_* gauges the fleet federates, and stash the
+        document in the in-memory state for the on_slot callback.
+
+        Up to date, not recomputed: the rows of the runs below the
+        watermark are kept and only the runs the slot's attempts
+        created are read (``_fold_new_runs``) — the same arithmetic
+        over the same rows, so the document is byte-for-byte what
+        ``progress_stats`` over a fresh ``load_storage`` gives. A
+        document for which the whole history was read counts as
+        ``path="walk"`` of ``nmz_campaign_progress_folds_total``: a
+        campaign's first (read where the campaign started), one after
+        the storage contradicted the watermark, and ``from_scratch``
+        (the document ``_finish`` leaves in ``campaign.json``); every
+        other as ``path="fold"``. The checkpoint half of the inputs is
+        ``self.state``, which ``_checkpoint()`` has just written.
+        Best-effort — a mid-write storage or a stats bug degrades to
+        None, never kills the campaign loop."""
         try:
             from namazu_tpu.obs import analytics
-            from namazu_tpu.storage import load_storage
 
-            st = load_storage(self.spec.storage_dir)
-            try:
-                calib, ckpt = analytics._progress_inputs(
-                    self.spec.storage_dir)
-                progress = analytics.progress_stats(
-                    st, calibration=calib, checkpoint=ckpt)
-            finally:
-                st.close()
+            self._fold_new_runs(from_scratch)
+            progress = self._fold.document(
+                calibration=analytics._load_doc(self.spec.storage_dir,
+                                                "calibration.json"),
+                checkpoint=self.state)
         except Exception:
             log.warning("progress publication failed; continuing",
                         exc_info=True)
+            self._fold = None
             return None
+        path = self._fold.take_path()
+        obs_spans.campaign_progress_fold(path)
+        if not from_scratch and self.state["slots"]:
+            # beside the attempts' `start`: how this slot's document
+            # was made (on disk with the next checkpoint)
+            self.state["slots"][-1]["progress_path"] = path
         obs_spans.campaign_progress(
             rate=progress["repro_rate"],
             ci=progress["rate_ci95"],

@@ -94,9 +94,10 @@ def _standby_gate() -> Optional[float]:
     line from stdin, before this process has read, opened or bound
     anything of the storage. The line is the supervisor's JSON object
     ``{"spawned": <its monotonic stamp at the go, or null while the
-    campaign is not observed>, "env": {<name>: <value, or null = unset>}}``
-    — what it would have put into this attempt's environment at
-    ``Popen`` time. Returns the stamp of the arrival at the gate, or
+    campaign is not observed>, "respawn": <the seconds it took from the
+    previous reap to that stamp, or null>, "env": {<name>: <value, or
+    null = unset>}}`` — what it would have put into this attempt's
+    environment at ``Popen`` time. Returns the stamp of the arrival at the gate, or
     None when the run was never wanted: EOF (the supervisor closed the
     pipe, or died) or anything that is not that object."""
     # what a run imports inline later, whatever its config says: loaded
@@ -116,6 +117,9 @@ def _standby_gate() -> Optional[float]:
         spawned, env = go["spawned"], go["env"]
         if spawned is not None:
             os.environ[obs.spans.RUN_SPAWNED_ENV] = repr(float(spawned))
+            if go.get("respawn") is not None:
+                os.environ[obs.spans.RUN_RESPAWN_ENV] = repr(
+                    float(go["respawn"]))
         for name, value in env.items():
             if value is None:
                 os.environ.pop(name, None)

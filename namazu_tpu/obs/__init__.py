@@ -77,6 +77,7 @@ from namazu_tpu.obs.spans import (  # noqa: F401
     action_dispatched,
     action_unroutable,
     campaign_progress,
+    campaign_progress_fold,
     campaign_slot,
     carry,
     chaos_fault_injected,

@@ -60,7 +60,8 @@ __all__ = [
     "relation_bits_of",
     "coverage_stats", "reproduction_stats", "entity_stats",
     "convergence_stats", "suspicious_branches", "compute_payload",
-    "progress_stats", "progress_payload",
+    "progress_stats", "progress_rows", "progress_document",
+    "ProgressFold", "progress_payload",
     "payload", "set_storage_dir", "storage_dir",
     "set_knowledge_address", "knowledge_address",
     "StallDetector", "note_search_round", "reset_stall_detector",
@@ -323,31 +324,69 @@ def coverage_stats(storage, window: int = DEFAULT_WINDOW) -> Dict[str, Any]:
     }
 
 
-def reproduction_stats(storage) -> Dict[str, Any]:
-    """Failure (= bug reproduction) statistics across a storage's runs."""
-    n = storage.nr_stored_histories()
-    outcomes: List[Tuple[bool, float]] = []
-    quarantined = _quarantined_count(storage)
+#: one stored run as the reproduction and progress surfaces read it:
+#: ``(index, failure, required_time, virtual_time_s)`` — ``failure`` is
+#: the SPRT's outcome (True = repro), ``required_time`` None where the
+#: result states an outcome and no time (the run then counts for the
+#: band verdict and not for the rates), ``virtual_time_s`` the stored
+#: metadata value as it is, None on a wall run
+ProgressRow = Tuple[int, bool, Optional[float], Any]
+
+
+def progress_rows(storage, start: int = 0, stop: Optional[int] = None
+                  ) -> Tuple[List[ProgressRow], int]:
+    """The walk of the progress surface, over runs ``[start, stop)``
+    (``stop`` None: up to the last completed run): one
+    :data:`ProgressRow` per run that states an outcome, in storage
+    order, and how many of the range are crash-quarantined. A
+    quarantined run and one whose result cannot be read give no row.
+    Each run is visited once, its ``result.json`` parsed once; nothing
+    outside the range is touched, which is what lets a campaign
+    supervisor read only the runs its last attempt made
+    (:class:`ProgressFold`)."""
+    if stop is None:
+        stop = storage.nr_stored_histories()
     is_quarantined = getattr(storage, "is_quarantined", None)
+    rows: List[ProgressRow] = []
+    quarantined = 0
+    for i in range(start, stop):
+        if is_quarantined is not None and is_quarantined(i):
+            quarantined += 1
+            continue
+        try:
+            failure = not storage.is_successful(i)
+        except Exception:
+            continue
+        try:
+            t = storage.get_required_time(i)
+        except Exception:
+            rows.append((i, failure, None, None))
+            continue
+        try:
+            virtual = storage.get_metadata(i).get("virtual_time_s")
+        except Exception:
+            virtual = None
+        rows.append((i, failure, t, virtual))
+    return rows, quarantined
+
+
+def _reproduction_from_rows(rows: List[ProgressRow], quarantined: int
+                            ) -> Dict[str, Any]:
+    """:func:`reproduction_stats`'s arithmetic over kept rows. Every sum
+    is taken anew over the row list, in its order: a total carried from
+    one call to the next would round differently from the one a fresh
+    walk computes."""
+    outcomes: List[Tuple[bool, float]] = []
     # virtual-clock runs (doc/performance.md "Virtual clock") record
     # their VIRTUAL elapsed as metadata beside the wall required_time;
     # a wall run's virtual time IS its wall time, so the virtual total
     # stays well-defined over mixed storages
     total_virtual = 0.0
     vclock_runs = 0
-    for i in range(n):
-        if is_quarantined is not None and is_quarantined(i):
+    for _i, failure, t, virtual in rows:
+        if t is None:
             continue
-        try:
-            t = storage.get_required_time(i)
-            outcomes.append((storage.is_successful(i), t))
-        except Exception:
-            continue
-        try:
-            meta = storage.get_metadata(i)
-        except Exception:
-            meta = {}
-        virtual = meta.get("virtual_time_s")
+        outcomes.append((not failure, t))
         if virtual is not None:
             total_virtual += float(virtual)
             vclock_runs += 1
@@ -399,21 +438,18 @@ def reproduction_stats(storage) -> Dict[str, Any]:
     return out
 
 
+def reproduction_stats(storage) -> Dict[str, Any]:
+    """Failure (= bug reproduction) statistics across a storage's runs."""
+    rows, _ = progress_rows(storage)
+    # counted over ALL allocated run dirs, as coverage_stats counts it
+    return _reproduction_from_rows(rows, _quarantined_count(storage))
+
+
 def _run_outcomes(storage) -> List[bool]:
     """The storage's completed-run outcome sequence in campaign order
     (True = failure = repro), quarantined runs excluded — what the
     progress surface replays through the band SPRT."""
-    n = storage.nr_stored_histories()
-    is_quarantined = getattr(storage, "is_quarantined", None)
-    outcomes: List[bool] = []
-    for i in range(n):
-        if is_quarantined is not None and is_quarantined(i):
-            continue
-        try:
-            outcomes.append(not storage.is_successful(i))
-        except Exception:
-            continue
-    return outcomes
+    return [failure for _i, failure, _t, _v in progress_rows(storage)[0]]
 
 
 def progress_stats(storage, coverage: Optional[Dict[str, Any]] = None,
@@ -423,14 +459,29 @@ def progress_stats(storage, coverage: Optional[Dict[str, Any]] = None,
     """The live campaign-progress document (obs/stats.py machinery over
     one storage): measured rate + CI, repros/hour, ETA forecasts, the
     sequential band verdict, and the search-pays/random-suffices regime
-    call. Pure function of its inputs — no wall-clock reads — so the
-    REST ``/progress`` body, the ``/analytics`` fold, and ``tools
-    report`` all agree byte-for-byte. Every field is ``None`` rather
+    call. The walk (:func:`progress_rows` over the whole storage) and
+    then the arithmetic (:func:`progress_document`), so the REST
+    ``/progress`` body, the ``/analytics`` fold, ``tools report`` and a
+    campaign supervisor's fold (:class:`ProgressFold`) all agree
+    byte-for-byte."""
+    rows, _ = progress_rows(storage)
+    return progress_document(rows, _quarantined_count(storage),
+                             coverage=coverage, calibration=calibration,
+                             checkpoint=checkpoint)
+
+
+def progress_document(rows: List[ProgressRow], quarantined: int,
+                      coverage: Optional[Dict[str, Any]] = None,
+                      calibration: Optional[Dict[str, Any]] = None,
+                      checkpoint: Optional[Dict[str, Any]] = None
+                      ) -> Dict[str, Any]:
+    """:func:`progress_stats`'s arithmetic. Pure function of its inputs
+    — no storage, no wall-clock reads. Every field is ``None`` rather
     than NaN on a young campaign (0 or 1 completed runs, no failures
     yet): the document must always survive ``json.dumps(...,
     allow_nan=False)``."""
-    repro = reproduction_stats(storage)
-    outcomes = _run_outcomes(storage)
+    repro = _reproduction_from_rows(rows, quarantined)
+    outcomes = [failure for _i, failure, _t, _v in rows]
     runs = len(outcomes)
     failures = sum(outcomes)
     band = tuple(stats.DEFAULT_BAND)
@@ -509,6 +560,77 @@ def progress_stats(storage, coverage: Optional[Dict[str, Any]] = None,
     return doc
 
 
+class ProgressFold:
+    """One storage's progress rows kept from slot to slot, for a reader
+    that stays (the campaign supervisor): :meth:`fold` reads the runs at
+    or past the watermark — each once — and advances it, and
+    :meth:`document` is :func:`progress_document` over everything kept,
+    so after every slot the document equals what :func:`progress_stats`
+    walks the whole storage for.
+
+    What lets the rows below the watermark stand: a run dir whose child
+    has been reaped is written by nothing again (storage/naive.py:
+    every write goes to the handle's current run dir; ``init()`` and
+    fsck mark only result-less runs, and the storage's ``refresh()``
+    has applied ``init()``'s marking to the dirs being folded). A stored
+    run edited by hand under a running campaign is seen by whoever
+    recomputes (``/progress``, ``tools report``) and by this reader at
+    its next whole walk."""
+
+    def __init__(self) -> None:
+        self._rows: List[ProgressRow] = []
+        self._quarantined = 0
+        #: how many allocated runs have been folded; None = none yet
+        self.watermark: Optional[int] = None
+        self._walked = False
+
+    def contradicted_by(self, allocated: int) -> bool:
+        """Whether a storage holding ``allocated`` runs has fewer than
+        were folded: the rows kept describe runs that are gone."""
+        return self.watermark is not None and allocated < self.watermark
+
+    def fold(self, storage, allocated: int) -> None:
+        """Read runs ``[watermark, allocated)`` of ``storage`` into the
+        rows kept; the whole range anew (a walk) where nothing was
+        folded yet or the watermark is contradicted."""
+        walk = self.watermark is None or self.contradicted_by(allocated)
+        rows, quarantined = progress_rows(
+            storage, 0 if walk else self.watermark, allocated)
+        if walk:
+            self._rows, self._quarantined = rows, quarantined
+            self._walked = True
+        else:
+            self._rows += rows
+            self._quarantined += quarantined
+        self.watermark = allocated
+
+    def take_path(self) -> str:
+        """How the rows got here since this was last asked: ``"walk"``
+        where the whole history was read, else ``"fold"`` — asked once a
+        published document, for its counter."""
+        path, self._walked = "walk" if self._walked else "fold", False
+        return path
+
+    def document(self, coverage: Optional[Dict[str, Any]] = None,
+                 calibration: Optional[Dict[str, Any]] = None,
+                 checkpoint: Optional[Dict[str, Any]] = None
+                 ) -> Dict[str, Any]:
+        return progress_document(self._rows, self._quarantined,
+                                 coverage=coverage, calibration=calibration,
+                                 checkpoint=checkpoint)
+
+
+def _load_doc(dir_path: str, name: str) -> Optional[Dict[str, Any]]:
+    """A storage dir's JSON object ``name``; None when absent, torn or
+    no object."""
+    try:
+        with open(os.path.join(dir_path, name)) as f:
+            doc = json.load(f)
+    except (OSError, ValueError):
+        return None
+    return doc if isinstance(doc, dict) else None
+
+
 def _progress_inputs(dir_path: Optional[str]
                      ) -> Tuple[Optional[Dict[str, Any]],
                                 Optional[Dict[str, Any]]]:
@@ -516,22 +638,10 @@ def _progress_inputs(dir_path: Optional[str]
     (calibration.json, namazu_tpu/calibrate) and campaign checkpoint
     (campaign.json) — (None, None) when absent or unreadable, so a torn
     file degrades the fold instead of failing the payload."""
-    calib = ckpt = None
-    if dir_path:
-        for name, slot in (("calibration.json", "calib"),
-                           ("campaign.json", "ckpt")):
-            path = os.path.join(dir_path, name)
-            try:
-                with open(path) as f:
-                    doc = json.load(f)
-                if isinstance(doc, dict):
-                    if slot == "calib":
-                        calib = doc
-                    else:
-                        ckpt = doc
-            except (OSError, ValueError):
-                continue
-    return calib, ckpt
+    if not dir_path:
+        return None, None
+    return (_load_doc(dir_path, "calibration.json"),
+            _load_doc(dir_path, "campaign.json"))
 
 
 def entity_stats(storage,

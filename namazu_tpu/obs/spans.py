@@ -159,6 +159,9 @@ FLEET_STALE_INSTANCES = "nmz_fleet_stale_instances"
 SLO_BURN = "nmz_slo_burn"
 SLO_BREACHES = "nmz_slo_breaches_total"
 CAMPAIGN_SLOTS = "nmz_campaign_slots_total"
+# progress documents a campaign supervisor published, by path: folded
+# from the slot's own runs, or walked over the whole stored history
+CAMPAIGN_PROGRESS_FOLDS = "nmz_campaign_progress_folds_total"
 # tenancy plane (doc/tenancy.md): per-namespace serving telemetry —
 # the `run` label is the namespace name, the /fleet RUN dimension
 TENANCY_EVENTS = "nmz_tenancy_events_total"
@@ -603,6 +606,21 @@ def campaign_slot(cls: str) -> None:
         CAMPAIGN_SLOTS, "campaign run slots finished, by class",
         ("slot_class",),
     ).labels(slot_class=cls).inc()
+
+
+def campaign_progress_fold(path: str) -> None:
+    """One progress document published by a campaign supervisor, by how
+    it was made: ``fold`` = only the runs of the slot just finished were
+    read and folded into the rows kept, ``walk`` = the whole stored
+    history was read (a campaign's first document, a watermark the
+    storage contradicts, the document a campaign leaves behind)."""
+    if not metrics.enabled():
+        return
+    metrics.get().counter(
+        CAMPAIGN_PROGRESS_FOLDS,
+        "campaign progress documents published, by fold or whole walk",
+        ("path",),
+    ).labels(path=path).inc()
 
 
 def tenancy_events(run: str, n: int = 1) -> None:
@@ -1180,15 +1198,26 @@ def _trace_annotation(name: str, rid: Optional[str] = None):
 #: child's stamps are on the same clock
 RUN_SPAWNED_ENV = "NMZ_RUN_SPAWNED"
 
+#: its neighbour: how long the supervisor took from the previous
+#: attempt's reap to that stamp (seconds, ``repr`` of a float), which it
+#: knows at the stamp and hands over the same two ways. The run records
+#: it as its own ``respawn`` row, so the stretch between two runs
+#: travels with the run that waited for it. Absent on a campaign's first
+#: attempt and while the campaign is not observed
+RUN_RESPAWN_ENV = "NMZ_RUN_RESPAWN"
+
 #: what a run records of itself from that stamp on, the wait of a
 #: standby child before it (a row of the run's too, kept out of
 #: RUN_PHASES because the benchmark's eight ``run_<phase>_s`` are that
-#: tuple's names), and what only the supervisor sees
+#: tuple's names), and what only the supervisor sees — of which
+#: ``respawn`` is handed to the run (``RUN_RESPAWN_ENV``) and stored
+#: with it, and ``teardown`` ends after the run has stored its rows
 RUN_PHASES = ("boot", "prepare", "testee", "drain", "search", "endpoints",
               "validate", "record")
 STANDBY_PHASE = "standby"
-SUPERVISOR_PHASES = ("teardown", "respawn")
-_STORED_PHASES = frozenset(RUN_PHASES + (STANDBY_PHASE,))
+RESPAWN_PHASE = "respawn"
+SUPERVISOR_PHASES = ("teardown", RESPAWN_PHASE)
+_STORED_PHASES = frozenset(RUN_PHASES + (STANDBY_PHASE, RESPAWN_PHASE))
 
 _PHASE_HELP = {
     SEARCH_PHASE: "wall time per search-plane phase",
@@ -1384,21 +1413,33 @@ def run_begin(run_id: str, entered: Optional[float],
     (``standby_since``: its arrival at the gate) has ``standby`` before
     that: the wait from the arrival to the stamp, which is then the
     go's, so the row starts before 0 and a child the go found still
-    importing has one of no length. The variable is taken out of
+    importing has one of no length. Where the supervisor also said how
+    long it took to get from the previous reap to the stamp
+    (``RUN_RESPAWN_ENV``), that is the run's ``respawn`` row: top-level,
+    ending at 0 like ``standby``, before the origin and so outside the
+    closure of the rows from 0 on. Both variables are taken out of
     the environment: what this run spawns is no child of that stamp.
     No scope while observability is off (``entered`` None)."""
     stamp = os.environ.pop(RUN_SPAWNED_ENV, None)
+    gap = os.environ.pop(RUN_RESPAWN_ENV, None)
     if entered is None or not metrics.enabled():
         return
     try:
         spawned = float(stamp) if stamp else None
     except ValueError:
         spawned = None
+    try:
+        respawn = float(gap) if gap and spawned is not None else None
+    except ValueError:
+        respawn = None
     _scope.rid = run_id
     # the scope: where the run's clock starts and where its rows do
     _scope.run = (entered if spawned is None else spawned,
                   _span_ring.end())
     if spawned is not None:
+        if respawn is not None and 0.0 <= respawn < float("inf"):
+            _record_phase(RUN_PHASE, RESPAWN_PHASE, respawn,
+                          spawned - respawn, None, {})
         if standby_since is not None:
             waited = max(0.0, spawned - standby_since)
             _record_phase(RUN_PHASE, STANDBY_PHASE, waited,

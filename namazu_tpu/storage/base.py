@@ -35,6 +35,16 @@ class HistoryStorage:
     def close(self) -> None:
         pass
 
+    def refresh(self) -> int:
+        """Catch a handle that is kept open up with the runs other
+        processes have allocated since ``init()`` or the last refresh,
+        doing for those runs, and for those alone, what ``init()``
+        would; returns how many runs are allocated now. A long-lived
+        reader (the campaign supervisor) calls it between two runs in
+        place of a fresh ``load_storage``, whose ``init()`` visits
+        every stored run."""
+        return self.nr_stored_histories()
+
     # -- per-run ---------------------------------------------------------
 
     def create_new_working_dir(self) -> str:

@@ -97,15 +97,22 @@ class NaiveStorage(HistoryStorage):
         self._next_run = int(self._load_meta()["next_run"])
         self._quarantine_crashed_runs()
 
-    def _quarantine_crashed_runs(self) -> None:
-        """Mark run dirs holding a trace but no result: the signature of
+    def refresh(self) -> int:
+        old = self._next_run
+        self._next_run = int(self._load_meta()["next_run"])
+        self._quarantine_crashed_runs(old)
+        return self._next_run
+
+    def _quarantine_crashed_runs(self, start: int = 0) -> None:
+        """Mark run dirs (from ``start`` on) holding a trace but no
+        result: the signature of
         a run killed between ``record_new_trace`` and ``record_result``.
         Dirs with NEITHER file are left unmarked here — an in-flight run
         looks exactly like that, and init() runs concurrently with live
         runs (the /analytics route loads the storage mid-experiment);
         ``tools fsck --repair``, which only an operator invokes on a
         quiescent storage, marks those too."""
-        for i in range(self._next_run):
+        for i in range(start, self._next_run):
             run_dir = self.run_dir(i)
             # the result first: a completed run costs one ``stat``
             if (not os.path.exists(os.path.join(run_dir, "result.json"))

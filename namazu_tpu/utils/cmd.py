@@ -13,7 +13,7 @@ import os
 import signal
 import subprocess
 import time
-from typing import Optional
+from typing import Iterable, Optional
 
 from namazu_tpu.utils.log import get_logger
 
@@ -160,22 +160,21 @@ class CmdFactory:
                 pass
 
 
-def sweep_stale_pgid_files(storage_dir: str) -> int:
+def sweep_stale_pgid_files(run_dirs: Iterable[str]) -> int:
     """Kill process groups whose ``phase.pgid`` breadcrumb outlived its
     writer (the `run` process was hard-killed mid-phase, so its finally
-    never removed the file and never killed the group). Called by the
-    campaign supervisor after every attempt; returns how many groups
-    were swept. The pgid-recycling race is accepted: the supervisor
-    runs this immediately after the slot ends, and a recycled pgid
-    would have to land inside that window on a group id we just
-    created."""
+    never removed the file and never killed the group), looking in
+    ``run_dirs`` and nowhere else. Called by the campaign supervisor
+    with every entry of the storage where a campaign starts (a
+    supervisor killed earlier may have left one anywhere) and, after an
+    attempt, with the dirs that attempt created — only they can hold
+    its breadcrumb; returns how many groups were swept. The
+    pgid-recycling race is accepted: the supervisor runs this
+    immediately after the slot ends, and a recycled pgid would have to
+    land inside that window on a group id we just created."""
     swept = 0
-    try:
-        run_dirs = sorted(os.listdir(storage_dir))
-    except OSError:
-        return 0
-    for name in run_dirs:
-        path = os.path.join(storage_dir, name, "phase.pgid")
+    for run_dir in run_dirs:
+        path = os.path.join(run_dir, "phase.pgid")
         try:
             with open(path) as f:
                 pgid = int(f.read().strip())

@@ -188,9 +188,11 @@ def test_a_campaign_adds_what_only_the_supervisor_sees(tmp_path, fresh_obs):
     assert [r[0] for r in second["phases"][3:]] == OWN + ["teardown"]
     for i, attempt in enumerate((first, second)):
         rows = attempt["phases"]
-        # the child's rows are the ones its run stored, untouched
+        # the child's rows are the ones its run stored, untouched (its
+        # copy of `respawn`, PR 44, is the supervisor's row again)
         assert [r for r in rows if r[0] not in spans.SUPERVISOR_PHASES] \
-            == stored_phases(storage, i)
+            == [r for r in stored_phases(storage, i)
+                if r[0] not in spans.SUPERVISOR_PHASES]
         assert all(r[0] in spans.SUPERVISOR_PHASES + spans.RUN_PHASES
                    + (spans.STANDBY_PHASE,) for r in rows)
         # the two rows before 0 each end at 0; from 0 on, in order
