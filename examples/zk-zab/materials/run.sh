@@ -10,7 +10,9 @@ PORT="${NMZ_REST_PORT:-10985}"
 URL="http://127.0.0.1:${PORT}"
 OUT="$NMZ_WORKING_DIR"
 M="$NMZ_MATERIALS_DIR"
-WRITES=100
+# the session's creates: 100 unless the config's run line says otherwise
+# (config_w21.toml: the benchmark's live cut, ../../README.md "zk-zab")
+WRITES="${NMZ_ZAB_WRITES:-100}"
 WINDOW=8
 
 links="127.0.9.4:127.0.0.4:2181:client:zk4"

@@ -290,8 +290,8 @@ def ingest_history(search, storage, p: IngestParams) -> List:
     ``ingest_pool``, doc/observability.md "Request spans"). The embed
     stage hands the whole history to the device at once
     (``SearchBase.embed_batch``); its row's ``pieces`` = device calls and
-    ``groups`` = padded trace lengths among the runs (each a compiled
-    embed of its own); the encode row's ``events`` = events of the runs
+    ``groups`` = padded trace lengths among the runs (all embedded at
+    the search's length class, by one program); the encode row's ``events`` = events of the runs
     ingested and ``cached`` = how many of those runs came from the
     encoded-run records (``RunRecordCache``) instead of the storage:
     every stored run charges ``ingest_read`` its signature (and its

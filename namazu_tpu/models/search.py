@@ -157,13 +157,19 @@ class _ResidentTraces:
     traces every search request; pre-fusion, every request re-uploaded
     the whole stack. Here each distinct trace (content-keyed) is
     uploaded ONCE into a row of a fixed device buffer (appends via the
-    donated ``dynamic_update_slice`` helper); a request's ordered
-    [T, Lmax] view is assembled device-side by a row gather + column
-    slice, so its arrays are value-identical to ``te.stack_traces`` of
-    the same references (the fused-vs-unfused bit-exactness contract).
-    Rows whose trace has left the reference window are evicted
-    oldest-first when the buffer is full; a longer-than-resident trace
-    forces a rebuild (lengths are quantized, so this converges fast).
+    donated ``dynamic_update_slice`` helper); a request's ordered view
+    is assembled device-side by a row gather. The buffers are
+    ``[capacity, L]`` with ``L`` the length the caller holds (a
+    search's length class, :meth:`SearchBase._hold_length`), and a view
+    is ``[T, L]`` whatever the references' own lengths: a shorter
+    trace's tail carries ``te.pad_trace_row``'s fills (masked, so it
+    adds no event), and the live part of every row is value-identical
+    to ``te.stack_traces`` of the same references (the
+    fused-vs-unfused contract). Rows whose trace has left the
+    reference window are evicted oldest-first when the buffer is full;
+    a view at another length than the resident one re-stages the
+    buffers at that length (the store keeps no length of its own: the
+    caller's class only grows, so this happens once per step of it).
     """
 
     def __init__(self, capacity: int = 16):
@@ -172,8 +178,8 @@ class _ResidentTraces:
         self.order: list = []  # digests, oldest first (eviction order)
         self.bufs = None  # dict name -> device array [N, L]
         self.L = 0
-        self.appends = 0  # rows uploaded incrementally (telemetry/tests)
-        self.rebuilds = 0  # full re-stagings (telemetry/tests)
+        self.appends = 0  # rows uploaded incrementally (tests)
+        self.rebuilds = 0  # full re-stagings (tests)
 
     @staticmethod
     def key_of(enc: "te.EncodedTrace") -> str:
@@ -186,29 +192,26 @@ class _ResidentTraces:
         h.update(enc.faultable.tobytes())
         return h.hexdigest()
 
-    def _pack(self, enc: "te.EncodedTrace", L: int):
-        """One trace as (hint, arrival, mask, faultable) rows padded to
-        L — ``te.pad_trace_row``, the host stacker's exact pad fills."""
-        return te.pad_trace_row(enc, L)
-
     def _put(self, key: str, enc: "te.EncodedTrace", slot: int) -> None:
         """Write one trace's rows at ``slot`` — the ONE way a row gets
         into the buffers, for the first staging and for every later
         append, so the row update (one program per dtype) is lowered
         where the buffers are born and no later request can find it
-        cold, whenever its run first moves the reference envelope."""
-        rows = self._pack(enc, self.L)
+        cold, whenever its run first moves the reference envelope.
+        The row is padded to the buffers' length with
+        ``te.pad_trace_row``, the host stacker's exact pad fills."""
+        rows = te.pad_trace_row(enc, self.L)
         for name in self.bufs:
             self.bufs[name] = _device_row_update(
                 self.bufs[name], rows[name], slot)
         self.slots[key] = slot
         self.order.append(key)
 
-    def _rebuild(self, encs, keys, Lmax: int) -> None:
+    def _rebuild(self, encs, keys, length: int) -> None:
         import jax.numpy as jnp
 
         self.capacity = max(self.capacity, len(encs))
-        self.L = max(self.L, Lmax)
+        self.L = length
         shape = (self.capacity, self.L)
         self.bufs = {
             "hint": jnp.asarray(np.zeros(shape, np.int32)),
@@ -222,6 +225,7 @@ class _ResidentTraces:
             if k not in self.slots:
                 self._put(k, e, len(self.slots))
         self.rebuilds += 1
+        obs.resident_trace_rows("restage", len(self.slots))
 
     def _append(self, key: str, enc: "te.EncodedTrace", live) -> None:
         if len(self.slots) < self.capacity:
@@ -231,34 +235,30 @@ class _ResidentTraces:
             victim = next(k for k in self.order if k not in live)
             slot = self.slots.pop(victim)
             self.order.remove(victim)
+            obs.resident_trace_rows("evict")
         self._put(key, enc, slot)
         self.appends += 1
+        obs.resident_trace_rows("append")
 
-    def view(self, encs):
-        """Device arrays (hint, arrival, mask, faultable), each [T, Lmax],
-        for the ordered references — uploading only rows not already
+    def view(self, encs, length: int):
+        """Device arrays (hint, arrival, mask, faultable), each
+        ``[T, length]`` (no trace of ``encs`` is longer), for the
+        ordered references — uploading only rows not already
         resident."""
         import jax.numpy as jnp
 
         keys = [self.key_of(e) for e in encs]
-        Lmax = max(e.hint_ids.shape[0] for e in encs)
         live = set(keys)
-        if (self.bufs is None or Lmax > self.L
+        if (self.bufs is None or length != self.L
                 or len(live) > self.capacity):
-            self._rebuild(encs, keys, Lmax)
+            self._rebuild(encs, keys, length)
         else:
             for k, e in zip(keys, encs):
                 if k not in self.slots:
                     self._append(k, e, live)
         idx = jnp.asarray([self.slots[k] for k in keys], jnp.int32)
-        return tuple(self.bufs[name][idx, :Lmax]
+        return tuple(self.bufs[name][idx]
                      for name in ("hint", "arr", "mask", "flt"))
-
-    def reset(self) -> None:
-        self.bufs = None
-        self.slots = {}
-        self.order = []
-        self.L = 0
 
 
 def make_score_weights(
@@ -388,7 +388,8 @@ class _EmbedBatch:
     """What an open :meth:`SearchBase.embed_batch` has queued: the
     traces to embed, in order, and per ring the ``(slot, row)`` writes
     the adds worked out. ``calls`` = device calls its flush made,
-    ``groups`` = padded trace lengths among the queued traces,
+    ``groups`` = padded trace lengths among the queued traces (all of
+    them embedded at the search's length class, by one program),
     ``overwrites`` = per ring, the writes whose slot held a live row."""
 
     def __init__(self) -> None:
@@ -437,6 +438,12 @@ class SearchBase:
         self._failure_digests = [""] * cfg.failure_size
         self._failure_digest_set: set = set()
         self._batch: Optional[_EmbedBatch] = None  # the open embed_batch
+        # the ONE trace length this search's programs take: the longest
+        # encoded length among every trace it has met, stored runs and
+        # references alike (_hold_length). Not in the checkpoint: every
+        # ingest hands over the whole stored history (models/ingest.py),
+        # so a restored search's first request finds it again
+        self.length_class = 0
         self.generations_run = 0
         # optional shared-surrogate hook (doc/knowledge.md): a callable
         # ``feats [N, K] -> probs [N] | None`` serving predictions from
@@ -562,31 +569,55 @@ class SearchBase:
 
     # -- embedding executed runs into the rings ----------------------------
 
+    def _hold_length(self, encs) -> int:
+        """The search's length class once it has met ``encs``: the
+        longest encoded length (``te._auto_length``: a multiple of
+        ``te.L_QUANTUM`` unless the caller stated one) of any trace it
+        was ever handed, which never shrinks. The embed program, the
+        resident reference rows, the fused step and the re-rank all
+        take this one length, so which runs a request happens to hold
+        changes no program's shape. An ingest hands over the whole
+        stored history at once (``_flush``), so the first request fixes
+        the class; a later run past it STEPS it — one re-staging of the
+        resident rows and one lowering of each program at the new
+        length, counted (``nmz_length_class_steps_total``)."""
+        longest = max((e.hint_ids.shape[0] for e in encs), default=0)
+        if longest > self.length_class:
+            if self.length_class:
+                log.info("a trace of padded length %d steps the search's "
+                         "length class from %d: its programs lower once "
+                         "more", longest, self.length_class)
+                obs.length_class_step()
+            self.length_class = longest
+        return self.length_class
+
     def _embed_chunks(self, encs):
         """Yield ``(indices, rows)`` per device call of the batched
-        embed program: the traces grouped by padded length (encodes
-        are quantized to ``te.L_QUANTUM``, so a request is one or two
-        groups), :data:`EMBED_CHUNK` at a time, a short chunk padded
-        with masked rows. ``rows`` f32[EMBED_CHUNK, K] stays on the
+        embed program: the traces :data:`EMBED_CHUNK` at a time, every
+        one at the search's length class (a shorter run's tail and a
+        short chunk's spare rows masked), so a request is
+        ``ceil(N / EMBED_CHUNK)`` calls of ONE program whatever lengths
+        its runs have. ``rows`` f32[EMBED_CHUNK, K] stays on the
         device; ``rows[j]`` embeds ``encs[indices[j]]``."""
         from namazu_tpu.ops.schedule import batched_trace_features
 
         embed = batched_trace_features(self.cfg.weights.tau, self.cfg.H)
-        by_length: dict = {}
-        for i, enc in enumerate(encs):
-            by_length.setdefault(enc.hint_ids.shape[0], []).append(i)
-        for L, group in by_length.items():
-            for k in range(0, len(group), EMBED_CHUNK):
-                indices = group[k:k + EMBED_CHUNK]
-                hint = np.zeros((EMBED_CHUNK, L), np.int32)
-                arrival = np.zeros((EMBED_CHUNK, L), np.float32)
-                mask = np.zeros((EMBED_CHUNK, L), bool)
-                for j, i in enumerate(indices):
-                    hint[j] = encs[i].hint_ids
-                    arrival[j] = encs[i].arrival
-                    mask[j] = encs[i].mask
-                obs.ingest_embed_call()
-                yield indices, embed(hint, arrival, mask, self.pairs)
+        L = self._hold_length(encs)
+        # the runs a per-length embed would have grouped apart
+        obs.embed_traces(
+            len(encs), sum(e.hint_ids.shape[0] < L for e in encs))
+        for k in range(0, len(encs), EMBED_CHUNK):
+            indices = list(range(k, min(k + EMBED_CHUNK, len(encs))))
+            hint = np.zeros((EMBED_CHUNK, L), np.int32)
+            arrival = np.zeros((EMBED_CHUNK, L), np.float32)
+            mask = np.zeros((EMBED_CHUNK, L), bool)
+            for j, i in enumerate(indices):
+                n = encs[i].hint_ids.shape[0]
+                hint[j, :n] = encs[i].hint_ids
+                arrival[j, :n] = encs[i].arrival
+                mask[j, :n] = encs[i].mask
+            obs.ingest_embed_call()
+            yield indices, embed(hint, arrival, mask, self.pairs)
 
     def _embed(self, encs) -> np.ndarray:
         """Feature rows f32[N, K] of executed traces, in order."""
@@ -978,16 +1009,19 @@ class ScheduleSearch(SearchBase):
     def _device_inputs_fused(self, encoded):
         """``(encs, traces, pairs, archive, failures)`` for the island
         step, device-resident: the ordered trace view comes from the
-        resident store (only missing rows upload), pairs/archive/failure
-        buffers from the device mirrors (synced by ``_mirror_rows``;
-        staged whole only after a bulk invalidation). Array VALUES are
-        those of ``_device_inputs`` for the same references."""
+        resident store (only missing rows upload) at the search's
+        length class, ``[T, class]`` whichever runs the references are,
+        pairs/archive/failure buffers from the device mirrors (synced
+        by ``_mirror_rows``; staged whole only after a bulk
+        invalidation). Array VALUES are those of ``_device_inputs`` for
+        the same references, with a masked tail where the class is
+        past their longest."""
         import jax.numpy as jnp
 
         from namazu_tpu.ops.schedule import TraceArrays
 
         encs = encoded if isinstance(encoded, (list, tuple)) else [encoded]
-        h, a, m, fb = self._traces.view(encs)
+        h, a, m, fb = self._traces.view(encs, self._hold_length(encs))
         trace = TraceArrays(h, a, m,
                             fb if self._coin is not None else None)
         if self._dev_pairs is None or self._dev_pairs_src is not self.pairs:
@@ -1110,9 +1144,10 @@ class ScheduleSearch(SearchBase):
         ``wait`` (the final block on the device). The in-step phases
         are ``jax.named_scope``-annotated in parallel/islands.py,
         visible in a device profile."""
-        with obs.search_phase("encode"):
+        with obs.search_phase("encode") as attrs:
             encs, trace, pairs, archive, failures = \
                 self._device_inputs_fused(encoded)
+            attrs["length_class"] = self.length_class
         import jax.numpy as jnp
 
         if self._coin is not None and self._dev_coin is None:

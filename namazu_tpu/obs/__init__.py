@@ -122,6 +122,7 @@ from namazu_tpu.obs.spans import (  # noqa: F401
     scorer_throughput,
     scorer_throughput_value,
     current_request,
+    embed_traces,
     ensure_compile_listener,
     evolve_request,
     evolve_table_request,
@@ -130,7 +131,9 @@ from namazu_tpu.obs.spans import (  # noqa: F401
     ingest_embed_call,
     ingest_events,
     ingest_runs,
+    length_class_step,
     rerank_request,
+    resident_trace_rows,
     ring_rows,
     run_begin,
     run_end,

@@ -77,6 +77,14 @@ RERANK_REQUESTS = "nmz_rerank_requests_total"
 RING_ROWS_WRITTEN = "nmz_ring_rows_written_total"
 RING_ROWS_OVERWRITTEN = "nmz_ring_rows_overwritten_total"
 FAILURE_SIGNATURES_DEDUPED = "nmz_failure_signatures_deduped_total"
+# one trace length per search (models/search.py ``_hold_length``): rows
+# the resident reference store took, gave up or staged anew; the traces
+# embedded and those of them shorter than the search's class; and how
+# often a run past the class stepped it
+RESIDENT_TRACE_ROWS = "nmz_resident_trace_rows_total"
+EMBED_TRACES = "nmz_embed_traces_total"
+EMBED_TRACES_BELOW_CLASS = "nmz_embed_traces_below_class_total"
+LENGTH_CLASS_STEPS = "nmz_length_class_steps_total"
 # the policy's reorder buffer (release_mode "reorder"): windows drained,
 # those whose paced drain ended after the NEXT window's boundary (the
 # scorer assumes a window's slots run from its own close), and how many
@@ -1682,6 +1690,52 @@ def failure_signatures_deduped(n: int = 1) -> None:
     metrics.get().counter(
         FAILURE_SIGNATURES_DEDUPED, "failure traces passed over because "
         "their signature is already in the failure ring").inc(n)
+
+
+def resident_trace_rows(op: str, n: int = 1) -> None:
+    """Rows of a search's device-resident reference traces
+    (``models/search.py::_ResidentTraces``), counted where the store
+    decides: ``append`` — a reference new to the store uploaded into a
+    row (under ``reference_mode = "recent"`` every passing run brings
+    one); ``evict`` — the append took the row of the oldest trace no
+    longer referenced; ``restage`` — rows written by a staging of the
+    whole store (its first, a window wider than its capacity, a step
+    of the search's length class)."""
+    if not metrics.enabled():
+        return
+    metrics.get().counter(
+        RESIDENT_TRACE_ROWS, "rows of the device-resident reference "
+        "traces by what the store did", ("op",)).labels(op=op).inc(n)
+
+
+def embed_traces(n: int, below_class: int) -> None:
+    """Traces one flush handed the batched embed program
+    (``SearchBase._embed_chunks``) and, of them, those whose own padded
+    length is under the search's length class: the runs a per-length
+    embed would have grouped apart, each group a program of its own.
+    Both samples are written, the second at 0 where none is shorter: a
+    share over a sample that is not there reads as nothing to read,
+    not as 0 %."""
+    if not metrics.enabled():
+        return
+    reg = metrics.get()
+    reg.counter(
+        EMBED_TRACES, "traces handed to the batched embed program").inc(n)
+    reg.counter(
+        EMBED_TRACES_BELOW_CLASS, "embedded traces whose own padded length "
+        "is under the search's length class").inc(below_class)
+
+
+def length_class_step() -> None:
+    """A search met a trace longer than its length class and stepped
+    the class to it: its programs lower once more at the new length
+    (the ``compile`` rows of that request name them)."""
+    if not metrics.enabled():
+        return
+    metrics.get().counter(
+        LENGTH_CLASS_STEPS, "steps of a search's length class: a trace "
+        "past it re-staged the resident rows and lowered the programs "
+        "at its length").inc()
 
 
 def search_device_trace(path: str) -> None:

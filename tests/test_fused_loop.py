@@ -296,21 +296,22 @@ def test_resident_store_evicts_stale_rows_and_rebuilds_on_growth():
 
     store = _ResidentTraces(capacity=4)
     e1, e2, e3 = enc_of(40, 1), enc_of(40, 2), enc_of(40, 3)
-    store.view([e1, e2])
+    L = e1.hint_ids.shape[0]
+    store.view([e1, e2], L)
     assert (store.rebuilds, store.appends) == (1, 0)
-    store.view([e1, e2, e3])
+    store.view([e1, e2, e3], L)
     assert (store.rebuilds, store.appends) == (1, 1)
     # same refs again: nothing new staged
-    store.view([e1, e2, e3])
+    store.view([e1, e2, e3], L)
     assert (store.rebuilds, store.appends) == (1, 1)
     # ring full: stale rows are evicted for new ones, no rebuild
     e4, e5 = enc_of(40, 4), enc_of(40, 5)
-    store.view([e3, e4, e5])
+    store.view([e3, e4, e5], L)
     assert store.rebuilds == 1
     assert len(store.slots) <= store.capacity
     # a longer trace forces the one legitimate re-staging
     long = enc_of(200, 6)  # auto-length pads past the resident L
-    h, arr, m, fb = store.view([e5, long])
+    h, arr, m, fb = store.view([e5, long], long.hint_ids.shape[0])
     assert store.rebuilds == 2
     # the view matches a fresh host stack of the same references
     sh, _se, sa, sm, sf = te.stack_traces([e5, long])
@@ -318,6 +319,15 @@ def test_resident_store_evicts_stale_rows_and_rebuilds_on_growth():
     assert np.array_equal(np.asarray(arr), sa)
     assert np.array_equal(np.asarray(m), sm)
     assert np.array_equal(np.asarray(fb), sf)
+    # ... and at the caller's length when the long trace has left the
+    # window: the short rows' tails masked, nothing staged anew
+    h, arr, m, fb = store.view([e5, e4], long.hint_ids.shape[0])
+    assert store.rebuilds == 2 and store.L == long.hint_ids.shape[0]
+    sh, _se, sa, sm, sf = te.stack_traces([e5, e4])
+    for got, want in ((h, sh), (arr, sa), (m, sm), (fb, sf)):
+        got = np.asarray(got)
+        assert got.shape == (2, store.L)
+        assert np.array_equal(got[:, :L], want) and not got[:, L:].any()
 
 
 # -- checkpoint compatibility ----------------------------------------------

@@ -312,11 +312,14 @@ def test_depths_10_23_66_75_lower_the_embed_program_once(backend,
 
 @pytest.mark.parametrize("short, long", [
     (10, 0), (64, 0), (65, 0), (70, 3), (130, 66)])
-def test_embed_calls_are_ceil_n_over_chunk_per_length(short, long,
-                                                      fresh_obs):
+def test_embed_calls_are_ceil_n_over_chunk_whatever_the_lengths(
+        short, long, fresh_obs):
+    # one length per search: the short and the long runs of a history
+    # share the chunks of ONE program (before: a chunk sequence, and a
+    # compiled embed, per padded length)
     s = MCTSSearch(cfg(archive_size=256), n_devices=1)
     st = history(short + long, long_from=short)
-    want = math.ceil(short / EMBED_CHUNK) + math.ceil(long / EMBED_CHUNK)
+    want = math.ceil((short + long) / EMBED_CHUNK)
     for request in (1, 2):
         ingest_history(s, st, IngestParams(H=H))
         assert embed_calls() == request * want
@@ -474,7 +477,9 @@ def test_ingest_counts_events_and_length_groups(short, long, groups,
     """``nmz_ingest_events_total`` and the ``ingest_encode`` row's
     ``events=`` count the events of the stored runs a request encoded;
     the ``ingest_embed`` row's ``groups=`` the padded lengths among
-    them, each a compiled embed of its own."""
+    them, all embedded at the search's length class by ONE program:
+    ``pieces=`` (device calls) is ``ceil(N / EMBED_CHUNK)`` whatever
+    the lengths."""
     s = MCTSSearch(cfg(archive_size=256), n_devices=1)
     st = history(short + long, long_from=short)
     events = 17 * short + 140 * long
@@ -488,7 +493,8 @@ def test_ingest_counts_events_and_length_groups(short, long, groups,
                        "cached": 0}] * 2
     embed = [r[7] for r in rows if r[1] == "ingest_embed"]
     assert [e["groups"] for e in embed] == [groups, groups]
-    assert [e["pieces"] for e in embed] == [groups, groups]
+    assert [e["pieces"] for e in embed] == [1, 1]
+    assert s.length_class == te._auto_length(140 if long else 17)
 
 
 @pytest.mark.parametrize("n_events, scorer", [

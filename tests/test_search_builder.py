@@ -40,7 +40,7 @@ def _policy(param: dict):
 
 
 def test_the_examples_are_where_they_were():
-    assert len(EXAMPLE_CONFIGS) == 7, EXAMPLE_CONFIGS
+    assert len(EXAMPLE_CONFIGS) == 8, EXAMPLE_CONFIGS
 
 
 @pytest.mark.parametrize("path", EXAMPLE_CONFIGS)

@@ -84,6 +84,33 @@ def test_a_recorded_run_holds_fle_zab_and_client_events_and_no_pings(
         f"/nmz/n{i:03d}" for i in range(100)]
 
 
+def test_a_run_at_twenty_one_writes_is_the_same_stream_cut_short(tmp_path):
+    """``config_w21.toml`` (the benchmark's ``zk2212-zab5-live``): the
+    recording config with ``NMZ_ZAB_WRITES=21`` on its run line, and
+    nothing else. A run is 14 events a write + 34 + the election's
+    53-100: 381-428, so a run pads to L 384 when its election took 56
+    messages or fewer and to L 512 otherwise (PERF.md section 4).
+    The bounds leave room for an election under six loaded workers,
+    not for a write more or less (14 events); a run that reproduces
+    stops the count early, so only a passing run is held to the lower
+    one."""
+    storage = init_storage(tmp_path, "config_w21.toml", "w21")
+    assert cli_main(["run", storage]) == 0
+    hints = hints_of(storage, 0)
+    kinds = collections.Counter(
+        ":".join(h.split(":")[1:3]) for h in hints)
+    assert kinds["cm:create"] == kinds["sm:reply"] == 21
+    assert lines_of(storage, 0, "acked") == [
+        f"/nmz/n{i:03d}" for i in range(21)]
+    assert not [h for h in hints if "ping" in h]
+    assert len(hints) <= 328 + 160, len(hints)
+    if load_storage(storage).is_successful(0):
+        assert 328 + 45 <= len(hints), len(hints)
+        for name in ("proposal", "ack", "commit"):
+            # the two pairs of server 5's DIFF ride the same hints
+            assert 3 * 21 <= kinds[f"zab:{name}"] <= 4 * 21 + 8, kinds
+
+
 def test_baseline_is_healthy(tmp_path):
     storage = init_storage(tmp_path, "config_baseline.toml", "base")
     assert cli_main(["run", storage]) == 0
