@@ -516,8 +516,9 @@ class Campaign:
 
     def _open_storage(self):
         """The campaign's one storage handle; opened — ``init()``'s
-        walk over every stored run — where there is none: at the
-        campaign's start or resume, and after something failed on it."""
+        walk from the storage's persisted watermark — where there is
+        none: at the campaign's start or resume, and after something
+        failed on it."""
         if self._storage is None:
             from namazu_tpu.storage import load_storage
 
@@ -536,8 +537,9 @@ class Campaign:
     def _catch_up(self) -> Optional[range]:
         """Catch the handle up with what an attempt just allocated
         (``refresh()``: ``storage.json`` re-read, ``init()``'s
-        quarantine applied to the new dirs alone) and return the new
-        runs' indices: the dirs the attempt created. None where that is
+        quarantine applied to the dirs not yet seen settled: the new
+        ones) and return the new runs' indices: the dirs the attempt
+        created. None where that is
         not known (no handle until now, or the storage cannot be read:
         anything may be new). Best-effort like the progress
         publication: a storage that cannot be read costs the attempt

@@ -150,6 +150,7 @@ from namazu_tpu.obs.spans import (  # noqa: F401
     sidecar_request,
     slo_breach,
     slo_burn,
+    storage_open,
     tenancy_events,
     tenancy_parked,
     tenancy_reclaim,

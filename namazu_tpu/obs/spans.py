@@ -71,6 +71,10 @@ INGEST_RUNS = "nmz_ingest_runs_total"
 INGEST_EMBED_CALLS = "nmz_ingest_embed_calls_total"
 INGEST_EVENTS = "nmz_ingest_events_total"
 INGEST_CACHED_RUNS = "nmz_ingest_cached_runs_total"
+# a stored history opened from its watermark (storage/naive.py): runs
+# allocated at each open or refresh, and those of them it did not visit
+STORAGE_OPEN_RUNS = "nmz_storage_open_runs_total"
+STORAGE_OPEN_SETTLED_RUNS = "nmz_storage_open_settled_runs_total"
 EVOLVE_REQUESTS = "nmz_evolve_requests_total"
 EVOLVE_TABLE_REQUESTS = "nmz_evolve_table_requests_total"
 RERANK_REQUESTS = "nmz_rerank_requests_total"
@@ -1598,6 +1602,23 @@ def ingest_cached_runs(n: int) -> None:
     metrics.get().counter(
         INGEST_CACHED_RUNS, "stored runs history ingests took from "
                             "their encoded-run records").inc(n)
+
+
+def storage_open(runs: int, visited: int) -> None:
+    """One ``init()`` / ``refresh()`` of a stored history
+    (storage/naive.py): ``runs`` allocated, ``visited`` of them looked
+    at for a crash to quarantine — the runs from the watermark on; the
+    rest were seen settled by an earlier open and cost this one
+    nothing. Settled over runs is how much of a history an open no
+    longer walks."""
+    if not metrics.enabled():
+        return
+    reg = metrics.get()
+    reg.counter(STORAGE_OPEN_RUNS, "runs allocated in the stored "
+                "histories opened or refreshed").inc(runs)
+    # written at 0 too: a history walked whole reads 0 %, not nothing
+    reg.counter(STORAGE_OPEN_SETTLED_RUNS, "runs of them that an open "
+                "did not visit: seen settled before").inc(runs - visited)
 
 
 def reorder_window_drained(policy: str, events: int,

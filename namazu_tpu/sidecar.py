@@ -58,7 +58,7 @@ import numpy as np
 from namazu_tpu import obs
 from namazu_tpu.endpoint.agent import read_frame, write_frame
 from namazu_tpu.endpoint.framed import FramedServer
-from namazu_tpu.storage import load_storage
+from namazu_tpu.storage import HistoryStorage, load_storage
 from namazu_tpu.utils.log import get_logger
 
 log = get_logger("sidecar")
@@ -139,6 +139,8 @@ class SearchService:
     def __init__(self) -> None:
         # key -> (params-fingerprint, search)
         self._searches: Dict[str, Tuple[str, object]] = {}
+        # key -> (storage dir, its open handle): see _get_storage
+        self._storages: Dict[str, Tuple[str, HistoryStorage]] = {}
         self._lock = threading.Lock()
         # one lock per key, held across the whole ingest+evolve+save:
         # a timed-out client's next request for the same storage must
@@ -202,6 +204,36 @@ class SearchService:
             self._searches[key] = (fp, search)
         return search, True
 
+    def _get_storage(self, key: str, storage_dir: str) -> HistoryStorage:
+        """The key's storage handle, caught up with the runs stored
+        since its last request (``refresh()``: from the handle's
+        watermark on, where a fresh ``load_storage`` of a history
+        nobody writes a watermark for — a tenant's synthesised one —
+        would walk every stored run at every request). Opened anew
+        where the key has none, where the request names another dir,
+        and where the refresh raises (the dir went away or came back
+        with fewer runs). A backend that has only the default
+        ``refresh()`` says nothing about what its handle remembers
+        from ``init()``, so none of its handles is kept. Called under
+        the key's lock."""
+        kept = self._storages.get(key)
+        if kept is not None and kept[0] == storage_dir:
+            try:
+                kept[1].refresh()
+                return kept[1]
+            except Exception as e:
+                log.info("storage handle of %s dropped (%s); opening it "
+                         "anew", storage_dir, e)
+        if kept is not None:
+            with self._lock:
+                del self._storages[key]
+            kept[1].close()
+        storage = load_storage(storage_dir)
+        if type(storage).refresh is not HistoryStorage.refresh:
+            with self._lock:
+                self._storages[key] = (storage_dir, storage)
+        return storage
+
     def _maybe_reload(self, search, checkpoint: str) -> None:
         """Reload a cached search whose on-disk checkpoint is AHEAD of
         it: the two homes of the search are interchangeable
@@ -251,13 +283,15 @@ class SearchService:
         params = req.get("search_params") or {}
         checkpoint = str(req.get("checkpoint") or "")
         storage_dir = req.get("storage")
-        with obs.search_phase("load"):
+        with obs.search_phase("load") as opened:
             search, fresh = self._get_search(key, params, checkpoint)
             try:
-                storage = (load_storage(storage_dir) if storage_dir
-                           else None)
+                storage = (self._get_storage(key, str(storage_dir))
+                           if storage_dir else None)
             except Exception as e:
                 return {"ok": False, "error": f"storage: {e}"}
+            if storage is not None and storage.last_open is not None:
+                opened["runs"], opened["visited"] = storage.last_open
         ip = req.get("ingest_params") or {}
         if ip.get("knowledge"):
             # a sidecar-hosted search serves knowledge-wired tenants

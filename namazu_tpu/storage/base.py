@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Any, Dict, Hashable, Iterable, List, Optional
+from typing import Any, Dict, Hashable, Iterable, List, Optional, Tuple
 
 from namazu_tpu.utils.trace import SingleTrace
 
@@ -22,6 +22,10 @@ class HistoryStorage:
 
     NAME = "abstract"
 
+    #: what the last ``init()`` / ``refresh()`` did: (runs allocated,
+    #: runs it visited); None where the backend visits no run to open
+    last_open: Optional[Tuple[int, int]] = None
+
     # -- lifecycle -------------------------------------------------------
 
     def create(self) -> None:
@@ -29,7 +33,22 @@ class HistoryStorage:
         raise NotImplementedError
 
     def init(self) -> None:
-        """Open an existing storage (every `run`)."""
+        """Open an existing storage (every `run`, every tool, the first
+        request of a key at a search home). A backend with crash
+        detection quarantines here the runs that hold a trace and no
+        result, and visits for that only the runs not yet seen
+        *settled*: a run is settled once it has a result or a
+        quarantine marker, and the storage records how far the settled
+        runs reach without a gap (storage/naive.py: ``"settled"`` in
+        ``storage.json``, absent = 0, past ``next_run`` = not trusted
+        and the storage walked whole). Only the calls that allocate a
+        run write that file (``create()``, ``create_new_working_dir()``);
+        ``init()`` and ``refresh()`` never do, so a reader cannot put a
+        stale ``next_run`` back under a writer. A run that is in flight
+        holds the watermark, so it is visited again by every open until
+        it settles. One narrowing follows: a run that LOSES its result
+        after it was seen settled is not quarantined by a later
+        ``init()``; ``fsck``, which visits every run, reports it."""
         raise NotImplementedError
 
     def close(self) -> None:
@@ -38,11 +57,16 @@ class HistoryStorage:
     def refresh(self) -> int:
         """Catch a handle that is kept open up with the runs other
         processes have allocated since ``init()`` or the last refresh,
-        doing for those runs, and for those alone, what ``init()``
-        would; returns how many runs are allocated now. A long-lived
-        reader (the campaign supervisor) calls it between two runs in
-        place of a fresh ``load_storage``, whose ``init()`` visits
-        every stored run."""
+        doing what ``init()`` would for the runs this handle has not
+        yet seen settled (the new ones, and any that was in flight last
+        time), and for those alone; returns how many runs are allocated
+        now. A long-lived reader (the campaign supervisor between two
+        runs, a search home between two requests of a key) calls it in
+        place of a fresh ``load_storage``. It raises where the handle
+        no longer describes the storage — the dir is gone, or fewer
+        runs are allocated than the handle has seen (shrunk, or made
+        anew) — and the caller opens a fresh handle. This default is
+        for a backend whose queries keep no state from ``init()``."""
         return self.nr_stored_histories()
 
     # -- per-run ---------------------------------------------------------

@@ -4,7 +4,13 @@ Parity: /root/reference/nmz/historystorage/naive — layout per storage dir:
 
 ::
 
-    storage.json          {"type": "naive", "next_run": N}
+    storage.json          {"type": "naive", "next_run": N, "settled": M}
+                          N run dirs are allocated; runs [0, M) were each
+                          seen settled (below). Written by the calls that
+                          allocate (create(), create_new_working_dir())
+                          and by no reader; a file without "settled"
+                          (every storage from before the field, a
+                          hand-written one) reads as M = 0.
     config.json           copy of the experiment config
     materials/            copy of the user's experiment scripts
     00000000/             one dir per run (%08x, parity naive.go:143-158)
@@ -21,6 +27,19 @@ Parity: /root/reference/nmz/historystorage/naive — layout per storage dir:
 
 The reference also writes per-action ``actions/<i>.{action,event}.json``
 files; here the whole trace is one JSON array — same information, one file.
+
+**Settled** (the watermark ``settled``): a run is settled once it holds a
+``result.json`` or the quarantine marker. Whether a run crashed is
+decided when it settles, so ``init()`` / ``refresh()`` look for crashed
+runs from the watermark on and not from run 0, and move the watermark
+over the settled runs they find in a row; the first run with neither
+(in flight, or killed before its trace: ``tools fsck --repair`` marks
+those) holds it back until it settles. Settled is not unchanged: a
+rewritten run still has a new ``run_signature``, and ``fsck`` still
+visits every run. The one narrowing: a run whose ``result.json`` is
+REMOVED after it was seen settled is no longer quarantined by the next
+``init()``; ``fsck`` lists it (``incomplete_unmarked``).
+
 All JSON writes are atomic (utils/atomic.py: tmp + fsync + rename), so a
 SIGKILL mid-write leaves the previous complete content, never a torn file.
 """
@@ -31,6 +50,7 @@ import json
 import os
 from typing import Any, Dict, Hashable, Iterable, List, Optional
 
+from namazu_tpu import obs
 from namazu_tpu.storage.base import HistoryStorage, StorageError, register_storage
 from namazu_tpu.utils.atomic import atomic_write_json, atomic_write_text, is_tmp_artifact
 from namazu_tpu.utils.log import get_logger
@@ -57,6 +77,7 @@ class NaiveStorage(HistoryStorage):
     def __init__(self, dir_path: str):
         self.dir = os.path.abspath(dir_path)
         self._next_run = 0
+        self._settled = 0  # the watermark (module docstring)
         self._current_run_dir: Optional[str] = None
         self._last_result: tuple = (None, None)  # see _result
 
@@ -77,7 +98,8 @@ class NaiveStorage(HistoryStorage):
 
     def _save_meta(self) -> None:
         atomic_write_json(self._meta_path(),
-                          {"type": self.NAME, "next_run": self._next_run})
+                          {"type": self.NAME, "next_run": self._next_run,
+                           "settled": self._settled})
 
     def _marker_path(self, i: int) -> str:
         return os.path.join(self.run_dir(i), INCOMPLETE_MARKER)
@@ -88,42 +110,64 @@ class NaiveStorage(HistoryStorage):
         os.makedirs(self.dir, exist_ok=True)
         if os.path.exists(self._meta_path()):
             raise StorageError(f"storage already exists: {self.dir}")
-        self._next_run = 0
+        self._next_run = self._settled = 0
         self._save_meta()
 
     def init(self) -> None:
         if not os.path.exists(self._meta_path()):
             raise StorageError(f"not a storage dir: {self.dir}")
-        self._next_run = int(self._load_meta()["next_run"])
+        meta = self._load_meta()
+        self._next_run = int(meta["next_run"])
+        settled = int(meta.get("settled", 0))
+        # a watermark past the allocated runs: the storage shrank under
+        # the file (an operator's edit), so nothing it says is trusted
+        self._settled = settled if 0 <= settled <= self._next_run else 0
         self._quarantine_crashed_runs()
 
     def refresh(self) -> int:
-        old = self._next_run
-        self._next_run = int(self._load_meta()["next_run"])
-        self._quarantine_crashed_runs(old)
+        next_run = int(self._load_meta()["next_run"])
+        if next_run < self._next_run:
+            raise StorageError(
+                f"{self.dir}: next_run went from {self._next_run} back to "
+                f"{next_run} under an open handle (the storage shrank or "
+                "was made anew); open it again")
+        self._next_run = next_run
+        self._quarantine_crashed_runs()
         return self._next_run
 
-    def _quarantine_crashed_runs(self, start: int = 0) -> None:
-        """Mark run dirs (from ``start`` on) holding a trace but no
-        result: the signature of
-        a run killed between ``record_new_trace`` and ``record_result``.
-        Dirs with NEITHER file are left unmarked here — an in-flight run
-        looks exactly like that, and init() runs concurrently with live
-        runs (the /analytics route loads the storage mid-experiment);
-        ``tools fsck --repair``, which only an operator invokes on a
-        quiescent storage, marks those too."""
-        for i in range(start, self._next_run):
+    def _quarantine_crashed_runs(self) -> None:
+        """Mark run dirs, from the watermark on, that hold a trace but
+        no result: the signature of a run killed between
+        ``record_new_trace`` and ``record_result``; and move the
+        watermark over the settled runs met in a row (module
+        docstring). Dirs with NEITHER file are left unmarked here — an
+        in-flight run looks exactly like that, and init() runs
+        concurrently with live runs (the /analytics route loads the
+        storage mid-experiment); ``tools fsck --repair``, which only an
+        operator invokes on a quiescent storage, marks those too. Such
+        a run holds the watermark, so the open after it crashed still
+        visits it."""
+        first = self._settled
+        in_a_row = True
+        for i in range(first, self._next_run):
             run_dir = self.run_dir(i)
             # the result first: a completed run costs one ``stat``
-            if (not os.path.exists(os.path.join(run_dir, "result.json"))
-                    and os.path.exists(os.path.join(run_dir, "trace.json"))
-                    and not os.path.exists(self._marker_path(i))):
+            settled = (os.path.exists(os.path.join(run_dir, "result.json"))
+                       or os.path.exists(self._marker_path(i)))
+            if not settled and os.path.exists(
+                    os.path.join(run_dir, "trace.json")):
                 atomic_write_text(
                     self._marker_path(i),
                     "crashed between trace and result; quarantined by "
                     "init()\n")
                 log.warning("run %08x has a trace but no result (crash "
                             "mid-run); quarantined", i)
+                settled = True
+            in_a_row = in_a_row and settled
+            if in_a_row:
+                self._settled = i + 1
+        self.last_open = (self._next_run, self._next_run - first)
+        obs.storage_open(*self.last_open)
 
     # -- per-run ---------------------------------------------------------
 

@@ -17,7 +17,7 @@ from namazu_tpu.endpoint.framed import FramedServer
 from namazu_tpu.obs import export, federation, spans
 from namazu_tpu.obs.context import wire_stamp
 from namazu_tpu.sidecar import DeviceTraceCapture, SidecarServer, request
-from namazu_tpu.storage import new_storage
+from namazu_tpu.storage import load_storage, new_storage
 from namazu_tpu.utils.config import Config
 
 from tests.test_tpu_policy import record_run
@@ -427,6 +427,57 @@ def test_device_trace_fails_open_while_a_capture_is_live(fresh_obs,
     assert cap.handle({"dir": str(tmp_path / "c"),
                       "seconds": "soon"})["ok"] is False
     cap.stop()  # nothing live: a no-op
+
+
+# -- the storage open ---------------------------------------------------------
+
+
+def open_counters():
+    reg = obs.metrics.registry()
+    return (reg.value(spans.STORAGE_OPEN_RUNS),
+            reg.value(spans.STORAGE_OPEN_SETTLED_RUNS))
+
+
+def test_an_open_counts_its_runs_and_those_it_did_not_visit(fresh_obs,
+                                                            tmp_path):
+    """``nmz_storage_open_runs_total`` / ``..._settled_runs_total`` move
+    by (N, N - visited) an ``init()`` or ``refresh()``, the ``load`` row
+    of a request says ``runs=`` / ``visited=``, and nothing moves with
+    observability off."""
+    st = make_history(tmp_path / "st")  # one handle: no watermark yet
+    kept = load_storage(st.dir)
+    assert open_counters() == (3, 0)  # walked whole, and written at 0
+    record_run(kept, ["c", "b", "a", "b", "a", "c"], successful=True)
+    load_storage(st.dir)  # the writer persisted what it had seen
+    assert open_counters() == (3 + 4, 0 + 3)
+    kept.refresh()
+    assert kept.last_open == (4, 1)
+    assert open_counters() == (7 + 4, 3 + 3)
+    srv = SidecarServer(port=0)
+    srv.start()
+    try:
+        addr = f"127.0.0.1:{srv.port}"
+        for _ in range(2):
+            assert request(addr, search_req(st))["ok"]
+        rows = wait_for_rows(
+            lambda: request(addr, {"op": "spans"})["rows"],
+            lambda rows: sum(r[1] == "reply" for r in rows) == 2)
+        obs.metrics.configure(False)
+        assert request(addr, search_req(st))["ok"]
+    finally:
+        srv.shutdown()
+    # the key's first request opened the storage, the second refreshed
+    assert [r[7] for r in rows if r[1] == "load"] == [
+        {"runs": 4, "visited": 1}, {"runs": 4, "visited": 0}]
+    text = export.render_span_trees(rows)
+    assert "runs=4  visited=1" in text and "runs=4  visited=0" in text
+    obs.metrics.configure(True)
+    assert open_counters() == (11 + 8, 6 + 7)
+    obs.metrics.configure(False)
+    load_storage(st.dir)
+    kept.refresh()
+    obs.metrics.configure(True)
+    assert open_counters() == (19, 13)
 
 
 # -- the two homes ------------------------------------------------------------
