@@ -87,6 +87,22 @@ EXIT_INFRA_STOP = 3     # K consecutive infra-class run slots
 EXIT_INTERRUPTED = 130  # stopped on SIGINT/SIGTERM (128 + SIGINT)
 
 
+def _reap(child: subprocess.Popen, deadline: Optional[float]) -> None:
+    """Wait for ``child`` for at most ``deadline`` seconds (None: for
+    good) and return when it EXITS: the blocking ``waitpid`` on a
+    thread of its own, joined for at most the deadline. ``Popen.wait``
+    with a timeout is a sleep loop backing off to 50 ms, which put
+    every run's wall on that grid (doc/performance.md "Between runs").
+    Raises ``TimeoutExpired`` as ``Popen.wait`` does; the reaper then
+    ends with the kill that follows."""
+    reaper = threading.Thread(target=child.wait, name="campaign-reap",
+                              daemon=True)
+    reaper.start()
+    reaper.join(deadline)
+    if reaper.is_alive():
+        raise subprocess.TimeoutExpired(child.args, deadline)
+
+
 @dataclass
 class CampaignSpec:
     """Everything that parameterizes one supervised campaign."""
@@ -470,7 +486,7 @@ class Campaign:
             try:
                 if not self._stop_requested.is_set():
                     self._start_standby()
-                child.wait(timeout=deadline)
+                _reap(child, deadline)
             except subprocess.TimeoutExpired:
                 timed_out = True
                 log.warning("run exceeded the %.1fs wall deadline; "

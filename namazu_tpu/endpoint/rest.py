@@ -50,6 +50,7 @@ from __future__ import annotations
 import itertools
 import json
 import re
+import selectors
 import socket as _socket
 import threading
 import time
@@ -403,7 +404,12 @@ class _TrackingHTTPServer(ThreadingHTTPServer):
     are served by at most ``max_threads`` lazily-spawned workers, with
     overflow connections queued — 8 campaigns' clients hitting one
     orchestrator grow a queue, not an unbounded thread count (the
-    stdlib mixin spawned one thread per connection, forever)."""
+    stdlib mixin spawned one thread per connection, forever), and (c)
+    a serve loop that ``shutdown()`` WAKES (doc/performance.md "Between
+    runs"): the loop blocks on the listening socket and one end of a
+    socketpair, with no timeout, and ``shutdown()`` writes the other
+    end — the stdlib's loop looked at its flag every 0.5 s, which put
+    the end of every run on that grid."""
 
     #: an idle pool worker exits after this long (a short burst's
     #: threads drain back instead of lingering for the process life)
@@ -425,6 +431,51 @@ class _TrackingHTTPServer(ThreadingHTTPServer):
         self._idle_waiters = 0
         self._threads_alive = 0
         self._pool_stopped = False
+        # the serve loop's wake-up: shutdown() writes _wake_w, the
+        # loop selects on _wake_r beside the listener
+        self._wake_r, self._wake_w = _socket.socketpair()
+        self._stop_requested = False
+        self._loop_left = threading.Event()
+
+    def serve_forever(self):
+        """``BaseServer.serve_forever`` for this server, less its
+        timer: one connection per readable listener, ``service_
+        actions`` per round, out when ``shutdown()`` has asked."""
+        self._loop_left.clear()
+        try:
+            with selectors.DefaultSelector() as selector:
+                selector.register(self, selectors.EVENT_READ)
+                selector.register(self._wake_r, selectors.EVENT_READ)
+                while not self._stop_requested:
+                    ready = {key.fileobj for key, _ in selector.select()}
+                    if self._stop_requested:
+                        break
+                    if self._wake_r in ready:
+                        # a wake no loop took (shutdown() raced a
+                        # loop already on its way out): not ours
+                        self._wake_r.recv(64)
+                    if self in ready:
+                        self._handle_request_noblock()
+                    self.service_actions()
+        finally:
+            self._stop_requested = False
+            self._loop_left.set()
+
+    def shutdown(self):
+        """Stop the serve loop NOW: flag, wake, wait for the loop to
+        leave (``BaseServer.shutdown``'s contract: call it while the
+        loop runs in another thread, or it waits for one to)."""
+        self._stop_requested = True
+        try:
+            self._wake_w.send(b"\0")
+        except OSError:
+            pass  # the pair is closed: no loop is left to wake
+        self._loop_left.wait()
+
+    def server_close(self):
+        super().server_close()
+        self._wake_r.close()
+        self._wake_w.close()
 
     def process_request(self, request, client_address):
         with self._open_lock:

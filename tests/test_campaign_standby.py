@@ -530,15 +530,15 @@ import sys
 from namazu_tpu.campaign import Campaign, CampaignSpec
 sys.exit(Campaign(CampaignSpec(
     storage_dir=sys.argv[1], runs=50, retries=0, python=sys.argv[2],
-    telemetry_collector="",
+    telemetry_collector="", run_wall_deadline_s=float(sys.argv[4]),
     extra_env={"STANDIN_RUN_S": sys.argv[3]})).run())
 """
 
 
-def supervisor(storage, tmp_path, run_s):
+def supervisor(storage, tmp_path, run_s, wall_deadline_s=0.0):
     proc = subprocess.Popen(
         [sys.executable, "-c", SUPERVISOR, storage, standin(tmp_path),
-         str(run_s)],
+         str(run_s), str(wall_deadline_s)],
         env=CmdFactory().env(), stdin=subprocess.DEVNULL,
         stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
         start_new_session=True)
@@ -552,9 +552,11 @@ def supervisor(storage, tmp_path, run_s):
                          ids=["one_sigterm_finishes_the_run",
                               "two_sigterms_abort_it"])
 def test_a_signalled_supervisor_leaves_no_standby(tmp_path, signals,
-                                                  status):
+                                                  status,
+                                                  wall_deadline_s=0.0):
     storage = init_storage(tmp_path)
-    proc = supervisor(storage, tmp_path, 1.0 if signals == 1 else 30)
+    proc = supervisor(storage, tmp_path, 1.0 if signals == 1 else 30,
+                      wall_deadline_s)
     try:
         for _ in range(signals):
             proc.send_signal(signal.SIGTERM)
@@ -585,3 +587,94 @@ def test_a_killed_supervisor_ends_its_standby_through_eof(tmp_path):
         for e in events(storage)))
     no_session_left(storage)
     assert sum(e["event"] == "run" for e in events(storage)) == 1
+
+
+# -- the reap (ISSUE 47): the attempt's wait returns on the child's exit,
+# under a wall deadline as without one; orderings, no duration -----------
+
+
+@pytest.mark.parametrize("deadline", [60.0, 0.0],
+                         ids=["under_a_wall_deadline", "without_one"])
+def test_an_attempt_never_enters_subprocess_s_sleep_loop(
+        tmp_path, fresh_obs, monkeypatch, deadline):
+    """``Popen.wait(timeout=)`` is a sleep loop backing off to 50 ms;
+    the attempt's wait for its run child must block in the kernel
+    instead. Every ``time.sleep`` of the subprocess module and every
+    ``Popen._wait`` is recorded while an attempt is inside
+    ``_one_attempt``: no sleep, and no wait with a timeout."""
+    import types
+
+    inside, sleeps, waits = [], [], []
+    proxy = types.SimpleNamespace(**{
+        k: getattr(time, k) for k in dir(time) if not k.startswith("__")})
+
+    def recording_sleep(seconds):
+        if inside:
+            sleeps.append(seconds)
+        time.sleep(seconds)
+
+    proxy.sleep = recording_sleep
+    monkeypatch.setattr(subprocess, "time", proxy)
+    plain_wait = subprocess.Popen._wait
+
+    def recording_wait(self, timeout):
+        if inside:
+            waits.append(timeout)
+        return plain_wait(self, timeout)
+
+    monkeypatch.setattr(subprocess.Popen, "_wait", recording_wait)
+    plain_attempt = Campaign._one_attempt
+
+    def attempt(self, slot_index=0):
+        inside.append(slot_index)
+        try:
+            return plain_attempt(self, slot_index)
+        finally:
+            inside.clear()
+
+    monkeypatch.setattr(Campaign, "_one_attempt", attempt)
+    storage = init_storage(tmp_path)
+    spec = spec_for(storage, tmp_path, runs=3, run_wall_deadline_s=deadline,
+                    extra_env={"STANDIN_RUN_S": "0.3"})
+    assert Campaign(spec).run() == 0
+    slots = load_checkpoint(storage)["slots"]
+    assert [s["class"] for s in slots] == [CLASS_EXPERIMENT] * 3
+    assert sleeps == []
+    assert waits and set(waits) == {None}  # the blocking waitpid alone
+
+
+@pytest.mark.parametrize("deadline", [None, 60.0],
+                         ids=["without_a_deadline", "under_one"])
+def test_the_reap_returns_with_the_exit_status(deadline):
+    from namazu_tpu.campaign import _reap
+
+    child = subprocess.Popen([sys.executable, "-c", "raise SystemExit(7)"],
+                             start_new_session=True)
+    _reap(child, deadline)
+    assert child.returncode == 7
+
+
+def test_the_reap_past_its_deadline_raises_what_popen_wait_raises():
+    from namazu_tpu.campaign import _reap
+
+    child = subprocess.Popen(["sleep", "600"], start_new_session=True)
+    try:
+        with pytest.raises(subprocess.TimeoutExpired) as ei:
+            _reap(child, 0.2)
+        assert ei.value.timeout == 0.2 and ei.value.cmd == child.args
+        assert child.poll() is None  # the kill is the caller's
+    finally:
+        kill_process_group(child)
+    # the reaper's own waitpid took the status; nothing is left to reap
+    assert wait_until(lambda: child.returncode == -signal.SIGTERM)
+
+
+@pytest.mark.parametrize("signals", [1, 2], ids=[
+    "one_sigterm_finishes_the_run", "two_sigterms_abort_it"])
+def test_a_signal_reaches_a_supervisor_waiting_under_a_wall_deadline(
+        tmp_path, signals):
+    """The supervisor's main thread waits in a join, not in a waitpid:
+    the handlers still run there, the second signal still kills the
+    run's group, and the campaign still ends 130."""
+    test_a_signalled_supervisor_leaves_no_standby(
+        tmp_path, signals, 130, wall_deadline_s=120.0)

@@ -221,3 +221,204 @@ def test_nop_actions_not_propagated_to_rest(rest_hub):
         assert resp.status == 200
     with urllib.request.urlopen(_url(rest, "/actions/log0"), timeout=10) as r:
         assert r.status == 204
+
+
+# -- a run's end waits on no poll (ISSUE 47): shutdown() WAKES the serve
+# loop. Orderings, not latencies: every bound below is one that loaded
+# workers cannot reach and that a tick could not satisfy either.
+
+_WOKE_WITHIN_S = 10.0
+
+
+def _recording_selectors(monkeypatch):
+    """The serve loop's selector, recording ``("enter", timeout)`` /
+    ``("leave",)`` around every ``select`` into the returned list."""
+    import selectors
+    import types
+
+    from namazu_tpu.endpoint import rest as rest_mod
+
+    log = []
+
+    class Recording(selectors.DefaultSelector):
+        def select(self, timeout=None):
+            log.append(("enter", timeout))
+            try:
+                return super().select(timeout)
+            finally:
+                log.append(("leave",))
+
+    monkeypatch.setattr(rest_mod, "selectors", types.SimpleNamespace(
+        DefaultSelector=Recording, EVENT_READ=selectors.EVENT_READ))
+    return log
+
+
+def _await(predicate, what):
+    import time
+
+    deadline = time.monotonic() + _WOKE_WITHIN_S
+    while not predicate():
+        assert time.monotonic() < deadline, f"never saw: {what}"
+        time.sleep(0.005)
+
+
+def _refuses(port):
+    import socket
+
+    try:
+        socket.create_connection(("127.0.0.1", port), timeout=5).close()
+    except ConnectionRefusedError:
+        return True
+    return False
+
+
+def _finishes(fn):
+    """Run ``fn`` on a thread; its result, once it is back within the
+    bound (the assertion is "came back", not how fast)."""
+    out = []
+    t = threading.Thread(target=lambda: out.append(fn()), daemon=True)
+    t.start()
+    t.join(_WOKE_WITHIN_S)
+    assert not t.is_alive(), "still waiting: nothing woke it"
+    return out[0]
+
+
+def _bare_rest(**kw):
+    hub = EndpointHub()
+    rest = RestEndpoint(port=0, **kw)
+    hub.add_endpoint(rest)
+    rest.start()
+    return rest
+
+
+def _parked_poll(rest, entity="parked"):
+    """A long-poll GET accepted and parked in its handler; returns the
+    thread and the list its outcome lands in."""
+    outcome = []
+
+    def poll():
+        try:
+            with urllib.request.urlopen(
+                    _url(rest, f"/actions/{entity}"), timeout=30) as r:
+                outcome.append(r.status)
+        except Exception as e:  # noqa: BLE001 - the outcome IS the error
+            outcome.append(e)
+
+    t = threading.Thread(target=poll, daemon=True)
+    t.start()
+    _await(lambda: entity in rest._queues, "the poll in its handler")
+    return t, outcome
+
+
+def test_shutdown_wakes_the_serve_loop(monkeypatch):
+    """The loop blocks with NO timeout (nothing but a connection or a
+    wake can end its wait), shutdown() is called while it is blocked,
+    and shutdown() returns: it woke the loop, it did not wait a tick."""
+    log = _recording_selectors(monkeypatch)
+    rest = _bare_rest()
+    port = rest.port
+    _await(lambda: log and log[-1][0] == "enter", "the loop in its select")
+    assert {e[1] for e in log if e[0] == "enter"} == {None}
+    log.append(("shutdown",))
+    _finishes(rest.shutdown)
+    # the select that was blocked when shutdown() came is the one that
+    # returned after it, and no other was entered: out at the first look
+    assert [e[0] for e in log[-3:]] == ["enter", "shutdown", "leave"]
+    assert _refuses(port)
+
+
+def test_request_accepted_before_shutdown_is_answered():
+    rest = _bare_rest(poll_timeout=1.0)
+    t, outcome = _parked_poll(rest)
+    _finishes(rest.shutdown)
+    t.join(_WOKE_WITHIN_S)
+    assert outcome == [204]  # its window ran out, answered all the same
+
+
+def test_sever_closes_the_listener_first_and_cuts_connections():
+    rest = _bare_rest(poll_timeout=20.0)
+    port = rest.port
+    t, outcome = _parked_poll(rest)
+    srv = rest._server
+    cut, listener_refused = srv.sever_connections, []
+
+    def probing_cut():
+        listener_refused.append(_refuses(port))
+        return cut()
+
+    srv.sever_connections = probing_cut
+    assert _finishes(rest.sever) == 1
+    assert listener_refused == [True]
+    t.join(_WOKE_WITHIN_S)
+    assert not t.is_alive() and len(outcome) == 1
+    assert isinstance(outcome[0], Exception), outcome  # cut, not answered
+    # process death, then the orchestrator's own hub.shutdown() over it
+    # (Orchestrator.abandon): no loop is left to wake, nothing waits
+    _finishes(rest.shutdown)
+
+
+@pytest.mark.parametrize("case", ["twice", "before_start", "sever_twice",
+                                  "sever_before_start"])
+def test_shutdown_and_sever_where_no_loop_runs(case):
+    rest = RestEndpoint(port=0)
+    EndpointHub().add_endpoint(rest)
+    stop = rest.sever if case.startswith("sever") else rest.shutdown
+    if not case.endswith("before_start"):
+        rest.start()
+        port = rest.port
+        _finishes(stop)
+        assert _refuses(port)
+    _finishes(stop)
+
+
+def _bare_server():
+    from http.server import BaseHTTPRequestHandler
+
+    from namazu_tpu.endpoint.rest import _TrackingHTTPServer
+
+    class Ok(BaseHTTPRequestHandler):
+        def do_GET(self):
+            self.send_response(204)
+            self.end_headers()
+
+        def log_message(self, *a):
+            pass
+
+    return _TrackingHTTPServer(("127.0.0.1", 0), Ok)
+
+
+def test_shutdown_before_the_loop_is_in_waits_for_it():
+    """``BaseServer.shutdown``'s contract: a shutdown() that comes
+    before the loop's thread has entered waits for the loop, and the
+    loop leaves at its first look."""
+    srv = _bare_server()
+    stopper = threading.Thread(target=srv.shutdown, daemon=True)
+    stopper.start()
+    loop = threading.Thread(target=srv.serve_forever, daemon=True)
+    loop.start()
+    for t in (stopper, loop):
+        t.join(_WOKE_WITHIN_S)
+        assert not t.is_alive()
+    srv.server_close()
+    srv.stop_pool()
+
+
+def test_a_second_loop_is_not_spun_by_the_first_one_s_wake(monkeypatch):
+    """The wake byte of one shutdown() does not keep a later loop of
+    the same server busy, and that loop serves and is woken in turn."""
+    log = _recording_selectors(monkeypatch)
+    srv = _bare_server()
+    port = srv.server_address[1]
+    for _ in range(2):
+        loop = threading.Thread(target=srv.serve_forever, daemon=True)
+        loop.start()
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/", timeout=30) as r:
+            assert r.status == 204
+        _finishes(srv.shutdown)
+        loop.join(_WOKE_WITHIN_S)
+        assert not loop.is_alive()
+    # two connections, two wakes, at most one stale wake drained
+    assert sum(e[0] == "enter" for e in log) <= 6
+    srv.server_close()
+    srv.stop_pool()
