@@ -1486,7 +1486,7 @@ def main(argv=None) -> None:
     from namazu_tpu.ops.schedule import (
         ScoreWeights,
         TraceArrays,
-        score_population,
+        score_population_multi,
     )
 
     if args.smoke:
@@ -1502,9 +1502,9 @@ def main(argv=None) -> None:
         arrivals=[i * 1e-3 for i in range(n_ev)],
         L=L, H=H,
     )
-    trace = TraceArrays(
-        jnp.asarray(enc.hint_ids), jnp.asarray(enc.arrival),
-        jnp.asarray(enc.mask),
+    trace = TraceArrays(  # one trace, as the [1, L] stack the scorer takes
+        jnp.asarray(enc.hint_ids)[None], jnp.asarray(enc.arrival)[None],
+        jnp.asarray(enc.mask)[None],
     )
     pairs = jnp.asarray(te.sample_pairs(K, H, 0))
     archive = jnp.asarray(
@@ -1525,8 +1525,8 @@ def main(argv=None) -> None:
         # population by its own fitness (what GA mutation does), which
         # also keeps XLA from collapsing the loop.
         def step(_, d):
-            fit, _f = score_population(d, trace, pairs, archive, failures,
-                                       weights)
+            fit, _f = score_population_multi(d, trace, pairs, archive,
+                                             failures, weights)
             return d + 1e-9 * fit[:, None]
         return jax.lax.fori_loop(0, iters, step, delays)
 
@@ -1552,8 +1552,7 @@ def main(argv=None) -> None:
     # numpy baseline on a small slice, per-schedule rate extrapolated
     nb = 64
     np_args = (
-        np.asarray(pop.delays)[:nb], np.asarray(trace.hint_ids),
-        np.asarray(trace.arrival), np.asarray(trace.mask),
+        np.asarray(pop.delays)[:nb], enc.hint_ids, enc.arrival, enc.mask,
         np.asarray(pairs), np.asarray(archive), np.asarray(failures),
     )
     # Pin the BLAS pool at runtime (numpy's BLAS read its env when this

@@ -621,25 +621,27 @@ def _numpy_agreement() -> dict:
     from namazu_tpu.ops.schedule import (
         ScoreWeights,
         TraceArrays,
-        score_population_jit,
+        score_population_multi,
     )
 
     P, H, L, K, A, F, sample = 8192, 256, 256, 256, 1024, 64, 64
     enc = te.encode_event_stream(
         [f"hint:{i % 96}" for i in range(240)],
         arrivals=[i * 1e-3 for i in range(240)], L=L, H=H)
-    trace = TraceArrays(jnp.asarray(enc.hint_ids),
-                        jnp.asarray(enc.arrival), jnp.asarray(enc.mask))
+    # one trace, as the [1, L] stack the scorer takes
+    trace = TraceArrays(jnp.asarray(enc.hint_ids)[None],
+                        jnp.asarray(enc.arrival)[None],
+                        jnp.asarray(enc.mask)[None])
     pairs = te.sample_pairs(K, H, 0)
     archive = np.random.RandomState(0).rand(A, K).astype(np.float32)
     failures = np.random.RandomState(1).rand(F, K).astype(np.float32)
     pop = init_population(jax.random.PRNGKey(0), P, H,
                           GAConfig(max_delay=0.1))
     w = ScoreWeights()
-    fitness, feats = score_population_jit(
+    fitness, feats = score_population_multi(
         pop.delays, trace, jnp.asarray(pairs), jnp.asarray(archive),
         jnp.asarray(failures), w)
-    fitness = np.asarray(fitness)
+    fitness, feats = np.asarray(fitness), feats[:, 0]
     assert fitness.shape == (P,) and np.isfinite(fitness).all()
 
     delays = np.asarray(pop.delays)[:sample]

@@ -1,7 +1,7 @@
 """``nmz-tpu sidecar`` — run the persistent search sidecar.
 
 The orchestrator ⇄ JAX boundary of SURVEY.md §5.8: a long-lived process
-holding the compiled search plane (device mesh, jitted GA/MCTS step,
+holding the compiled search plane (device mesh, jitted GA step,
 archives) that per-run policies query over loopback instead of paying
 search construction + jit warm-up inside every two-second experiment
 process. Point a policy at it with ``sidecar = "127.0.0.1:10990"`` in

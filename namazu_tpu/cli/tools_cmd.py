@@ -1537,7 +1537,7 @@ def ab(args) -> int:
 
     With ``--prime-runs``, the recorded history is produced up front
     under ``--prime-config`` and each phase runs on its own CLONE of it:
-    the right shape for search-vs-search comparisons (e.g. GA vs MCTS),
+    the right shape for search-vs-search comparisons (two settings of the GA),
     where both sides must train on identical failures and neither may
     learn from the other's runs.
     """

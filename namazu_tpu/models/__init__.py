@@ -19,21 +19,16 @@ SEARCH_DEFAULTS = {
     "max_interval": 0.1,  # seconds; the genome's delay range
     "max_fault": 0.0,  # per-hint fault probability cap (0 = off)
     "surrogate_topk": 16,  # 0 = fitness argmax only (no surrogate)
-    # novelty anneal (GA backend): explore at full w_novelty until the
+    # novelty anneal: explore at full w_novelty until the
     # failure archive holds this many DISTINCT signatures (0 = static
     # weights), then scale novelty down, never below the floor
     "min_failure_signatures": 0,
     "novelty_floor": 0.25,
-    "search_backend": "ga",  # "ga" (island GA) | "mcts" (config 5)
     # causality guidance (doc/search.md)
     "guidance": False,
     "guidance_bonus": 0.5,
     "guidance_width": 0,  # 0 = guidance.DEFAULT_WIDTH
     "guidance_window": 0,  # 0 = guidance.DEFAULT_WINDOW
-    "mcts_tree_depth": 24,
-    "mcts_levels": 8,
-    "mcts_simulations": 256,
-    "mcts_rollouts": 64,
     "release_mode": "delay",  # "delay" | "reorder" (BASELINE config 3)
     # fitness weights (ops/schedule.py ScoreWeights)
     "w_novelty": 1.0,
@@ -45,3 +40,17 @@ SEARCH_DEFAULTS = {
     "reorder_window": 0.05,
     "devices": None,  # None = every device of the process
 }
+
+
+def refuse_search_backend(stated) -> None:
+    """``search_backend`` went with the MCTS backend (PR 48): there is
+    one search, the island GA. ``"ga"`` (a config not yet edited, or the
+    params of an older policy over the sidecar's wire) is that search;
+    any other value is refused here, by ``TPUSearchPolicy.load_config``
+    and by ``models.search.build_search_from_params`` alike — a hunt
+    that asked for MCTS must not silently run the GA."""
+    if stated not in (None, "ga"):
+        raise ValueError(
+            f"search_backend = {stated!r}: the MCTS backend was removed "
+            "and the island GA is the one search (PARITY.md, config 5); "
+            "drop the key to search with it")

@@ -289,7 +289,7 @@ def ingest_history(search, storage, p: IngestParams) -> List:
     (``ingest_read`` / ``ingest_encode`` / ``ingest_embed`` /
     ``ingest_pool``, doc/observability.md "Request spans"). The embed
     stage hands the whole history to the device at once
-    (``SearchBase.embed_batch``); its row's ``pieces`` = device calls and
+    (``ScheduleSearch.embed_batch``); its row's ``pieces`` = device calls and
     ``groups`` = padded trace lengths among the runs (all embedded at
     the search's length class, by one program); the encode row's ``events`` = events of the runs
     ingested and ``cached`` = how many of those runs came from the

@@ -18,7 +18,7 @@ from typing import NamedTuple, Optional
 import numpy as np
 
 from namazu_tpu import obs
-from namazu_tpu.models import SEARCH_DEFAULTS
+from namazu_tpu.models import SEARCH_DEFAULTS, refuse_search_backend
 from namazu_tpu.models.ga import GAConfig
 from namazu_tpu.ops import trace_encoding as te
 from namazu_tpu.ops.schedule import ScoreWeights, scorer_branch
@@ -49,7 +49,7 @@ class SearchConfig(NamedTuple):
     # with the highest predicted repro instead of the raw fitness argmax.
     # 0 disables (fitness argmax, the pre-surrogate behavior).
     surrogate_topk: int = SEARCH_DEFAULTS["surrogate_topk"]
-    # novelty anneal (GA backend): with fewer than this many DISTINCT
+    # novelty anneal: with fewer than this many DISTINCT
     # failure signatures in the archive the search keeps its full
     # configured novelty weight (keep exploring — exploiting 1-2
     # signatures overfits their noise, the round-4 A/B floor's root
@@ -160,7 +160,7 @@ class _ResidentTraces:
     donated ``dynamic_update_slice`` helper); a request's ordered view
     is assembled device-side by a row gather. The buffers are
     ``[capacity, L]`` with ``L`` the length the caller holds (a
-    search's length class, :meth:`SearchBase._hold_length`), and a view
+    search's length class, :meth:`ScheduleSearch._hold_length`), and a view
     is ``[T, L]`` whatever the references' own lengths: a shorter
     trace's tail carries ``te.pad_trace_row``'s fills (masked, so it
     adds no event), and the live part of every row is value-identical
@@ -296,11 +296,15 @@ def make_score_weights(
 
 
 def build_search_from_params(params: dict, mesh=None):
-    """A search backend from the flat JSON-able knobs the policy states
+    """The search from the flat JSON-able knobs the policy states
     (``TPUSearchPolicy._search_params``, in-process or over the
     sidecar's wire): the ONE place that turns knobs into a
-    ``SearchConfig``, weights, a backend and its guidance wiring. A
-    knob ``params`` leaves out takes its ``SEARCH_DEFAULTS`` value."""
+    ``SearchConfig``, weights, the search and its guidance wiring. A
+    knob ``params`` leaves out takes its ``SEARCH_DEFAULTS`` value; the
+    keys an older policy still states for the backend that went
+    (``REMOVED_KEYS``, policy/tpu.py) build the same search as params
+    without them, and a backend other than the GA is refused."""
+    refuse_search_backend(params.get("search_backend"))
     p = {**SEARCH_DEFAULTS, **params}
     cfg = SearchConfig(
         H=p["H"], L=p["L"], K=p["K"],
@@ -322,31 +326,7 @@ def build_search_from_params(params: dict, mesh=None):
         guidance_bonus=p["guidance_bonus"],
         fused_chunk=p["fused_chunk"],
     )
-    if p["search_backend"] == "mcts":
-        if cfg.surrogate_topk > 0:
-            log.warning(
-                "surrogate re-ranking (surrogate_topk=%d) applies to "
-                "the GA backend only; the mcts backend returns its "
-                "fitness argmax", cfg.surrogate_topk)
-        if p["guidance"]:
-            log.warning(
-                "causality guidance (guidance=true) biases the GA "
-                "backend's pick/mutation only; the mcts backend "
-                "still feeds the coverage map and metrics")
-        from namazu_tpu.models.mcts import MCTSConfig
-
-        mcts_cfg = MCTSConfig(
-            tree_depth=p["mcts_tree_depth"],
-            n_levels=p["mcts_levels"],
-            simulations=p["mcts_simulations"],
-            rollouts=p["mcts_rollouts"],
-            max_delay=p["max_interval"],
-            max_fault=p["max_fault"],
-        )
-        search = MCTSSearch(cfg, mcts_cfg=mcts_cfg, mesh=mesh,
-                            n_devices=p["devices"])
-    else:
-        search = ScheduleSearch(cfg, mesh=mesh, n_devices=p["devices"])
+    search = ScheduleSearch(cfg, mesh=mesh, n_devices=p["devices"])
     if p["guidance"]:
         # wired BEFORE any checkpoint load/ingest so the archive's
         # DAG-shape feature fragments stay slot-aligned
@@ -385,7 +365,7 @@ _RING_LABEL = {"archive": "archive", "failures": "failure"}
 
 
 class _EmbedBatch:
-    """What an open :meth:`SearchBase.embed_batch` has queued: the
+    """What an open :meth:`ScheduleSearch.embed_batch` has queued: the
     traces to embed, in order, and per ring the ``(slot, row)`` writes
     the adds worked out. ``calls`` = device calls its flush made,
     ``groups`` = padded trace lengths among the queued traces (all of
@@ -407,17 +387,26 @@ class _EmbedBatch:
         return len(self.encs) - 1
 
 
-class SearchBase:
-    """Shared host-side state of every search backend: the precedence-pair
-    sample, the novelty/failure feature archives (ring buffers), and the
-    backend-tagged ``.npz`` checkpoint format."""
+class ScheduleSearch:
+    """The search: the precedence-pair sample, the novelty/failure
+    feature archives (host ring buffers mirrored to the device), the
+    island GA's state on the mesh, the re-rank, and the ``.npz``
+    checkpoint."""
 
-    BACKEND = "base"
+    #: the checkpoint's ``backend`` tag and the ``backend`` label of the
+    #: search gauges (doc/observability.md)
+    BACKEND = "ga"
 
-    def __init__(self, cfg: SearchConfig):
+    def __init__(self, cfg: SearchConfig = SearchConfig(),
+                 mesh=None, n_devices: Optional[int] = None):
+        import jax
+
+        from namazu_tpu.parallel.islands import init_island_state
+        from namazu_tpu.parallel.mesh import make_mesh
+
         configure_compile_cache()
-        # both backends construct through here: the first search of a
-        # process is what starts counting its lowerings
+        # the first search of a process is what starts counting its
+        # lowerings
         obs.ensure_compile_listener()
         self.cfg = cfg
         self.pairs = te.sample_pairs(cfg.K, cfg.H, cfg.seed)
@@ -467,6 +456,32 @@ class SearchBase:
         # non-zero; coin=None keeps the pre-config-4 jit cache entry
         self._coin = (te.fault_coin(cfg.seed, cfg.H)
                       if cfg.ga.max_fault > 0 else None)
+        self.mesh = mesh if mesh is not None else make_mesh(n_devices)
+        n_islands = self.mesh.size
+        # population must divide evenly across islands
+        per_island = max(1, cfg.population // n_islands)
+        self.population = per_island * n_islands
+
+        self._key = jax.random.PRNGKey(cfg.seed)
+        self._state = init_island_state(
+            jax.random.PRNGKey(cfg.seed + 1), self.population, cfg.H, cfg.ga
+        )
+        self._surrogate = None  # built lazily on first labeled training
+        # fused-loop machinery (doc/performance.md "Fused search loop"):
+        # per-chunk-length fused step cache, device mirrors of the host
+        # archive rings (kept in sync by _mirror_rows' scatters),
+        # and the device-resident reference-trace store
+        self._fused_steps: dict = {}
+        self._dev_mirrors = {"archive": None, "failures": None}
+        self._dev_pairs = None
+        self._dev_pairs_src = None
+        self._dev_coin = None
+        self._traces = _ResidentTraces()
+        # host-side snapshot of (best_delays, best_faults, best_fitness)
+        # from the last COMPLETED round: donation means a failed fused
+        # dispatch leaves self._state pointing at deleted buffers, and
+        # this (a few KB) is what _recover_state rebuilds the best from
+        self._best_snapshot = None
 
     # -- causality guidance (doc/search.md) -------------------------------
 
@@ -503,8 +518,7 @@ class SearchBase:
             # fragments are both stale — same contract as the
             # checkpoint-restore width guard. The next ingest re-feeds
             # the full history with fragments attached.
-            if getattr(self, "_surrogate", None) is not None:
-                self._surrogate = None
+            self._surrogate = None
             if self._archive_n > 0:
                 self.archive[:] = 0.5
                 self.archive_labels[:] = 0.0
@@ -565,7 +579,10 @@ class SearchBase:
 
     def _reset_best(self) -> None:
         """Invalidate the best-so-far record (feature space changed)."""
-        raise NotImplementedError
+        import jax.numpy as jnp
+
+        self._state = self._state._replace(
+            best_fitness=jnp.full((), -jnp.inf, jnp.float32))
 
     # -- embedding executed runs into the rings ----------------------------
 
@@ -684,11 +701,6 @@ class SearchBase:
                 ring[slots[which][written]] = host[written]
             self._mirror_rows(rows, slots)
 
-    def seed_population(self, delay_tables) -> None:
-        """Inject imitation genomes before evolving; backends without an
-        explicit population (MCTS builds its tree from scratch each run)
-        ignore seeds."""
-
     def add_executed_trace(self, encoded: te.EncodedTrace,
                            reproduced: bool = False,
                            arrival: Optional[te.EncodedTrace] = None
@@ -750,17 +762,29 @@ class SearchBase:
         into the novelty archive / surrogate training set."""
         return digest in self._failure_digest_set
 
+    # -- device-resident mirrors (fused loop) -----------------------------
+
     def _mirror_rows(self, rows, slots: dict) -> None:
-        """Hook: each ring ``which`` took ``rows[j]`` (on the device)
-        at ``slots[which][j]`` wherever that is in range — backends
-        with device-resident mirrors (ScheduleSearch's fused loop)
-        apply the same writes there instead of re-uploading the whole
-        buffers next run. Base: no mirror."""
+        """A chunk of rows went into the host rings: apply the same
+        writes to the device mirrors (one call, both donated), so the
+        next fused run stages nothing. No mirrors (none built yet, or
+        invalidated — they are built and dropped together) = nothing
+        to do: the next fused run stages the host rings."""
+        m = self._dev_mirrors
+        if m["archive"] is not None and m["failures"] is not None:
+            m["archive"], m["failures"] = _device_rows_scatter(
+                m["archive"], m["failures"], rows, slots["archive"],
+                slots["failures"])
 
     def _mirror_invalidate(self) -> None:
-        """Hook: a bulk archive/pairs mutation happened (checkpoint
-        load, pair refit, guidance rewiring) — device mirrors must be
-        rebuilt from the host arrays on the next run."""
+        """Bulk host-side mutation (checkpoint load, pair refit,
+        guidance rewiring): device mirrors rebuild from the host arrays
+        on the next fused run. The resident TRACE rows stay — they are
+        content-keyed and none of these mutations rewrites a recorded
+        trace."""
+        self._dev_mirrors = {"archive": None, "failures": None}
+        self._dev_pairs = None
+        self._dev_pairs_src = None
 
     def _record_progress(self, generations: int, elapsed: float,
                          schedules_scored: int, best_fitness: float,
@@ -806,206 +830,6 @@ class SearchBase:
         known = np.isfinite(labels)
         return feats[known], labels[known]
 
-    def _device_inputs(self, encoded):
-        """(encs, traces, pairs, archive, failures) as device arrays
-        staged from the host, from one encoded trace or a list of them.
-        ``MCTSSearch.run`` is its one caller: the GA keeps its inputs
-        resident (``ScheduleSearch._device_inputs_fused``)."""
-        import jax.numpy as jnp
-
-        from namazu_tpu.ops.schedule import TraceArrays
-
-        encs = encoded if isinstance(encoded, (list, tuple)) else [encoded]
-        h, _, a, m, fb = te.stack_traces(encs)
-        # the faultable flag only matters when the fault half is scored;
-        # leaving it None otherwise keeps the fault-off jit cache entry
-        trace = TraceArrays(
-            jnp.asarray(h), jnp.asarray(a), jnp.asarray(m),
-            jnp.asarray(fb) if self._coin is not None else None,
-        )
-        return encs, trace, jnp.asarray(self.pairs), \
-            jnp.asarray(self.archive), jnp.asarray(self.failures)
-
-    # -- persistence -----------------------------------------------------
-
-    def _state_dict(self) -> dict:
-        raise NotImplementedError
-
-    def _restore_state(self, z) -> None:
-        raise NotImplementedError
-
-    def save(self, path: str) -> None:
-        import jax
-
-        with obs.search_phase("save"):
-            flat = {
-                "backend": np.asarray(self.BACKEND),
-                "hint_space": np.asarray(te.HINT_SPACE),
-                "pairs": self.pairs,
-                "archive": self.archive,
-                "archive_labels": self.archive_labels,
-                "archive_n": np.asarray(self._archive_n),
-                "failures": self.failures,
-                "failure_n": np.asarray(self._failure_n),
-                "failure_digests": np.asarray(self._failure_digests),
-                "key": np.asarray(jax.random.key_data(self._key)),
-                "generations_run": np.asarray(self.generations_run),
-            }
-            if self.guidance_feats is not None:
-                flat["guidance_feats"] = self.guidance_feats
-            flat.update(self._state_dict())
-            tmp = path + ".tmp.npz"
-            np.savez(tmp, **flat)
-            os.replace(tmp, path)
-
-    def load(self, path: str) -> None:
-        import jax
-        import jax.numpy as jnp
-
-        with np.load(path) as z:
-            # pre-backend-tag checkpoints (GA only) have no "backend" key
-            saved = str(z["backend"]) if "backend" in z else "ga"
-            if saved != self.BACKEND:
-                raise ValueError(
-                    f"checkpoint {path} was written by the {saved!r} "
-                    f"backend, not {self.BACKEND!r}"
-                )
-            if ("best_delays" in z
-                    and z["best_delays"].shape != (self.cfg.H,)):
-                # a mismatched genome length would load silently and
-                # IndexError later on the policy's event hot path
-                raise ValueError(
-                    f"checkpoint {path} has H={z['best_delays'].shape[0]} "
-                    f"delay buckets, config has H={self.cfg.H}"
-                )
-            space = te.checkpoint_hint_space(z)
-            if space != te.HINT_SPACE:
-                # every archived feature and evolved delay table keys
-                # buckets in the old hint space; resuming from it would
-                # deliver arbitrary delays under a "searched schedule" log
-                raise ValueError(
-                    f"checkpoint {path} was built in hint space {space!r}; "
-                    f"this build hashes {te.HINT_SPACE!r} — delete it and "
-                    "re-record"
-                )
-            if "pairs" in z:  # pre-informative-pairs checkpoints lack it
-                self.pairs = z["pairs"]
-            self.archive = z["archive"]
-            if "archive_labels" in z:
-                self.archive_labels = z["archive_labels"]
-            else:
-                # pre-surrogate checkpoint: outcomes of the archived runs
-                # are unknown — NaN marks the slots unusable as training
-                # data (a 0.0 default would teach the surrogate that the
-                # runs that DID reproduce predict no-repro)
-                self.archive_labels = np.full(
-                    (self.cfg.archive_size,), np.nan, np.float32)
-            self._archive_n = int(z["archive_n"])
-            if self.guidance_feats is not None:
-                if "guidance_feats" in z \
-                        and z["guidance_feats"].shape \
-                        == self.guidance_feats.shape:
-                    self.guidance_feats = np.array(z["guidance_feats"])
-                else:
-                    # a pre-guidance (or differently-sized) checkpoint:
-                    # its archive rows have no aligned DAG-shape
-                    # fragment, and training a widened surrogate on
-                    # zero-filled fragments would teach it that shape
-                    # features mean nothing. Drop the archive — the
-                    # very next ingest re-feeds the full stored history
-                    # with fragments attached (models/ingest.py).
-                    self.archive[:] = 0.5
-                    self.archive_labels[:] = 0.0
-                    self._archive_n = 0
-            self.failures = z["failures"]
-            self._failure_n = int(z["failure_n"])
-            if "failure_digests" in z:
-                self._failure_digests = [str(d) for d in
-                                         z["failure_digests"]]
-                self._failure_digest_set = {d for d in
-                                            self._failure_digests if d}
-            else:
-                # pre-dedupe checkpoint: ring contents are unkeyed (and
-                # possibly duplicated); the next ingest re-keys afresh
-                self._failure_digests = [""] * self.cfg.failure_size
-                self._failure_digest_set = set()
-            self._key = jax.random.wrap_key_data(jnp.asarray(z["key"]))
-            self.generations_run = int(z["generations_run"])
-            self._restore_state(z)
-        # every buffer just changed wholesale; device-resident mirrors
-        # (fused loop) must rebuild from the restored host arrays
-        self._mirror_invalidate()
-
-
-class ScheduleSearch(SearchBase):
-    BACKEND = "ga"
-
-    def __init__(self, cfg: SearchConfig = SearchConfig(),
-                 mesh=None, n_devices: Optional[int] = None):
-        import jax
-
-        from namazu_tpu.parallel.islands import init_island_state
-        from namazu_tpu.parallel.mesh import make_mesh
-
-        super().__init__(cfg)
-        self.mesh = mesh if mesh is not None else make_mesh(n_devices)
-        n_islands = self.mesh.size
-        # population must divide evenly across islands
-        per_island = max(1, cfg.population // n_islands)
-        self.population = per_island * n_islands
-
-        self._key = jax.random.PRNGKey(cfg.seed)
-        self._state = init_island_state(
-            jax.random.PRNGKey(cfg.seed + 1), self.population, cfg.H, cfg.ga
-        )
-        self._surrogate = None  # built lazily on first labeled training
-        # fused-loop machinery (doc/performance.md "Fused search loop"):
-        # per-chunk-length fused step cache, device mirrors of the host
-        # archive rings (kept in sync by _mirror_rows' scatters),
-        # and the device-resident reference-trace store
-        self._fused_steps: dict = {}
-        self._dev_mirrors = {"archive": None, "failures": None}
-        self._dev_pairs = None
-        self._dev_pairs_src = None
-        self._dev_coin = None
-        self._traces = _ResidentTraces()
-        # host-side snapshot of (best_delays, best_faults, best_fitness)
-        # from the last COMPLETED round: donation means a failed fused
-        # dispatch leaves self._state pointing at deleted buffers, and
-        # this (a few KB) is what _recover_state rebuilds the best from
-        self._best_snapshot = None
-
-    def _reset_best(self) -> None:
-        import jax.numpy as jnp
-
-        self._state = self._state._replace(
-            best_fitness=jnp.full((), -jnp.inf, jnp.float32))
-
-    # -- device-resident mirrors (fused loop) -----------------------------
-
-    def _mirror_rows(self, rows, slots: dict) -> None:
-        """A chunk of rows went into the host rings: apply the same
-        writes to the device mirrors (one call, both donated), so the
-        next fused run stages nothing. No mirrors (none built yet, or
-        invalidated — they are built and dropped together) = nothing
-        to do: the next fused run stages the host rings."""
-        m = self._dev_mirrors
-        if m["archive"] is not None and m["failures"] is not None:
-            m["archive"], m["failures"] = _device_rows_scatter(
-                m["archive"], m["failures"], rows, slots["archive"],
-                slots["failures"])
-
-    def _mirror_invalidate(self) -> None:
-        """Bulk host-side mutation (checkpoint load, pair refit,
-        guidance rewiring): device mirrors rebuild from the host arrays
-        on the next fused run. The resident TRACE rows stay — they are
-        content-keyed and none of these mutations rewrites a recorded
-        trace."""
-        if getattr(self, "_dev_mirrors", None) is not None:
-            self._dev_mirrors = {"archive": None, "failures": None}
-            self._dev_pairs = None
-            self._dev_pairs_src = None
-
     def _device_inputs_fused(self, encoded):
         """``(encs, traces, pairs, archive, failures)`` for the island
         step, device-resident: the ordered trace view comes from the
@@ -1013,7 +837,7 @@ class ScheduleSearch(SearchBase):
         length class, ``[T, class]`` whichever runs the references are,
         pairs/archive/failure buffers from the device mirrors (synced
         by ``_mirror_rows``; staged whole only after a bulk
-        invalidation). Array VALUES are those of ``_device_inputs`` for
+        invalidation). Array VALUES are those of ``te.stack_traces`` of
         the same references, with a masked tail where the class is
         past their longest."""
         import jax.numpy as jnp
@@ -1443,204 +1267,183 @@ class ScheduleSearch(SearchBase):
 
     # -- persistence -----------------------------------------------------
 
-    def _state_dict(self) -> dict:
-        pop_delays, pop_faults = self._fetch_population()
-        d = {
-            "pop_delays": pop_delays,
-            "pop_faults": pop_faults,
-            "gen": np.asarray(self._state.gen),
-            "best_fitness": np.asarray(self._state.best_fitness),
-            "best_delays": np.asarray(self._state.best_delays),
-            "best_faults": np.asarray(self._state.best_faults),
-        }
-        if self._surrogate is not None:
-            from jax.flatten_util import ravel_pytree
+    def save(self, path: str) -> None:
+        import jax
 
-            vec, _ = ravel_pytree(self._surrogate.state.params)
-            d["surrogate_params"] = np.asarray(vec)
-        return d
+        with obs.search_phase("save"):
+            flat = {
+                "backend": np.asarray(self.BACKEND),
+                "hint_space": np.asarray(te.HINT_SPACE),
+                "pairs": self.pairs,
+                "archive": self.archive,
+                "archive_labels": self.archive_labels,
+                "archive_n": np.asarray(self._archive_n),
+                "failures": self.failures,
+                "failure_n": np.asarray(self._failure_n),
+                "failure_digests": np.asarray(self._failure_digests),
+                "key": np.asarray(jax.random.key_data(self._key)),
+                "generations_run": np.asarray(self.generations_run),
+            }
+            if self.guidance_feats is not None:
+                flat["guidance_feats"] = self.guidance_feats
+            pop_delays, pop_faults = self._fetch_population()
+            flat.update(
+                pop_delays=pop_delays,
+                pop_faults=pop_faults,
+                gen=np.asarray(self._state.gen),
+                best_fitness=np.asarray(self._state.best_fitness),
+                best_delays=np.asarray(self._state.best_delays),
+                best_faults=np.asarray(self._state.best_faults),
+            )
+            if self._surrogate is not None:
+                from jax.flatten_util import ravel_pytree
 
-    def _restore_state(self, z) -> None:
+                vec, _ = ravel_pytree(self._surrogate.state.params)
+                flat["surrogate_params"] = np.asarray(vec)
+            tmp = path + ".tmp.npz"
+            np.savez(tmp, **flat)
+            os.replace(tmp, path)
+
+    def load(self, path: str) -> None:
+        import jax
         import jax.numpy as jnp
 
-        from namazu_tpu.parallel.islands import IslandState
         from namazu_tpu.models.ga import Population
+        from namazu_tpu.parallel.islands import IslandState
 
-        pd = np.asarray(z["pop_delays"])
-        pf = np.asarray(z["pop_faults"])
-        expected = (self.population, self.cfg.H)
-        if pd.shape != expected or pf.shape != expected:
-            # a population/genome-width mismatch (config changed between
-            # runs, or a checkpoint from a differently-sized mesh) must
-            # not crash the load OR shard-mismatch later inside the
-            # step: keep the fresh population and re-evolve — archives,
-            # best tables, and the RNG stream restore as usual (the PR
-            # 11 width-mismatch-retrains rule extended to the island
-            # state; pinned by tests/test_fused_loop.py)
-            log.warning(
-                "checkpoint population %s does not fit this config %s; "
-                "keeping a fresh population (archives and best tables "
-                "restored)", pd.shape, expected)
-            pop = self._state.pop
-        else:
-            pop = Population(delays=jnp.asarray(pd),
-                             faults=jnp.asarray(pf))
-        self._state = IslandState(
-            pop=pop,
-            gen=jnp.asarray(z["gen"]),
-            best_fitness=jnp.asarray(z["best_fitness"]),
-            best_delays=jnp.asarray(z["best_delays"]),
-            best_faults=jnp.asarray(z["best_faults"]),
-        )
-        # the recovery snapshot tracks the restored best too — a fused
-        # dispatch failing right after a checkpoint load must not lose
-        # the loaded tables (_recover_state)
-        self._best_snapshot = (
-            np.asarray(z["best_delays"]),
-            np.asarray(z["best_faults"]),
-            float(z["best_fitness"]),
-        )
-        if "surrogate_params" in z:
-            from jax.flatten_util import ravel_pytree
-
-            from namazu_tpu.models.surrogate import RewardSurrogate
-
-            # deterministic re-init yields the unravel structure; the
-            # optimizer restarts (momentum is not worth persisting)
-            self._surrogate = RewardSurrogate(
-                K=self._surrogate_input_dims(), seed=self.cfg.seed)
-            ref, unravel = ravel_pytree(self._surrogate.state.params)
-            saved = jnp.asarray(z["surrogate_params"])
-            if saved.shape == ref.shape:
-                self._surrogate.state = self._surrogate.state._replace(
-                    params=unravel(saved)
+        with np.load(path) as z:
+            # pre-backend-tag checkpoints have no "backend" key
+            saved = str(z["backend"]) if "backend" in z else self.BACKEND
+            if saved != self.BACKEND:
+                raise ValueError(
+                    f"checkpoint {path} was written by the {saved!r} "
+                    f"search backend, which this build does not have "
+                    f"(its one search is {self.BACKEND!r}); delete it "
+                    "and search again"
                 )
+            if ("best_delays" in z
+                    and z["best_delays"].shape != (self.cfg.H,)):
+                # a mismatched genome length would load silently and
+                # IndexError later on the policy's event hot path
+                raise ValueError(
+                    f"checkpoint {path} has H={z['best_delays'].shape[0]} "
+                    f"delay buckets, config has H={self.cfg.H}"
+                )
+            space = te.checkpoint_hint_space(z)
+            if space != te.HINT_SPACE:
+                # every archived feature and evolved delay table keys
+                # buckets in the old hint space; resuming from it would
+                # deliver arbitrary delays under a "searched schedule" log
+                raise ValueError(
+                    f"checkpoint {path} was built in hint space {space!r}; "
+                    f"this build hashes {te.HINT_SPACE!r} — delete it and "
+                    "re-record"
+                )
+            if "pairs" in z:  # pre-informative-pairs checkpoints lack it
+                self.pairs = z["pairs"]
+            self.archive = z["archive"]
+            if "archive_labels" in z:
+                self.archive_labels = z["archive_labels"]
             else:
-                # guidance was toggled since this checkpoint was
-                # written: the feature widths differ, so the persisted
-                # weights don't apply — retrain from the labeled
-                # archive instead of failing the whole load
-                self._surrogate = None
+                # pre-surrogate checkpoint: outcomes of the archived runs
+                # are unknown — NaN marks the slots unusable as training
+                # data (a 0.0 default would teach the surrogate that the
+                # runs that DID reproduce predict no-repro)
+                self.archive_labels = np.full(
+                    (self.cfg.archive_size,), np.nan, np.float32)
+            self._archive_n = int(z["archive_n"])
+            if self.guidance_feats is not None:
+                if "guidance_feats" in z \
+                        and z["guidance_feats"].shape \
+                        == self.guidance_feats.shape:
+                    self.guidance_feats = np.array(z["guidance_feats"])
+                else:
+                    # a pre-guidance (or differently-sized) checkpoint:
+                    # its archive rows have no aligned DAG-shape
+                    # fragment, and training a widened surrogate on
+                    # zero-filled fragments would teach it that shape
+                    # features mean nothing. Drop the archive — the
+                    # very next ingest re-feeds the full stored history
+                    # with fragments attached (models/ingest.py).
+                    self.archive[:] = 0.5
+                    self.archive_labels[:] = 0.0
+                    self._archive_n = 0
+            self.failures = z["failures"]
+            self._failure_n = int(z["failure_n"])
+            if "failure_digests" in z:
+                self._failure_digests = [str(d) for d in
+                                         z["failure_digests"]]
+                self._failure_digest_set = {d for d in
+                                            self._failure_digests if d}
+            else:
+                # pre-dedupe checkpoint: ring contents are unkeyed (and
+                # possibly duplicated); the next ingest re-keys afresh
+                self._failure_digests = [""] * self.cfg.failure_size
+                self._failure_digest_set = set()
+            self._key = jax.random.wrap_key_data(jnp.asarray(z["key"]))
+            self.generations_run = int(z["generations_run"])
+            pd = np.asarray(z["pop_delays"])
+            pf = np.asarray(z["pop_faults"])
+            expected = (self.population, self.cfg.H)
+            if pd.shape != expected or pf.shape != expected:
+                # a population/genome-width mismatch (config changed
+                # between runs, or a checkpoint from a differently-sized
+                # mesh) must not crash the load OR shard-mismatch later
+                # inside the step: keep the fresh population and
+                # re-evolve — archives, best tables, and the RNG stream
+                # restore as usual (the PR 11 width-mismatch-retrains
+                # rule extended to the island state; pinned by
+                # tests/test_fused_loop.py)
+                log.warning(
+                    "checkpoint population %s does not fit this config %s; "
+                    "keeping a fresh population (archives and best tables "
+                    "restored)", pd.shape, expected)
+                pop = self._state.pop
+            else:
+                pop = Population(delays=jnp.asarray(pd),
+                                 faults=jnp.asarray(pf))
+            self._state = IslandState(
+                pop=pop,
+                gen=jnp.asarray(z["gen"]),
+                best_fitness=jnp.asarray(z["best_fitness"]),
+                best_delays=jnp.asarray(z["best_delays"]),
+                best_faults=jnp.asarray(z["best_faults"]),
+            )
+            # the recovery snapshot tracks the restored best too — a
+            # fused dispatch failing right after a checkpoint load must
+            # not lose the loaded tables (_recover_state)
+            self._best_snapshot = (
+                np.asarray(z["best_delays"]),
+                np.asarray(z["best_faults"]),
+                float(z["best_fitness"]),
+            )
+            if "surrogate_params" in z:
+                from jax.flatten_util import ravel_pytree
+
+                from namazu_tpu.models.surrogate import RewardSurrogate
+
+                # deterministic re-init yields the unravel structure;
+                # the optimizer restarts (momentum is not worth
+                # persisting)
+                self._surrogate = RewardSurrogate(
+                    K=self._surrogate_input_dims(), seed=self.cfg.seed)
+                ref, unravel = ravel_pytree(self._surrogate.state.params)
+                vec = jnp.asarray(z["surrogate_params"])
+                if vec.shape == ref.shape:
+                    self._surrogate.state = self._surrogate.state._replace(
+                        params=unravel(vec)
+                    )
+                else:
+                    # guidance was toggled since this checkpoint was
+                    # written: the feature widths differ, so the
+                    # persisted weights don't apply — retrain from the
+                    # labeled archive instead of failing the whole load
+                    self._surrogate = None
+        # every buffer just changed wholesale; device-resident mirrors
+        # (fused loop) must rebuild from the restored host arrays
+        self._mirror_invalidate()
 
 
-class MCTSSearch(SearchBase):
-    """Config-5 backend: root-parallel MCTS (models/mcts.py) behind the
-    same driver API as :class:`ScheduleSearch`, so ``policy/tpu.py`` can
-    swap backends with one config key (``search_backend = "mcts"``)."""
-
-    BACKEND = "mcts"
-
-    def __init__(self, cfg: SearchConfig = SearchConfig(), mcts_cfg=None,
-                 mesh=None, n_devices: Optional[int] = None):
-        import jax
-
-        from namazu_tpu.models.mcts import MCTSConfig, make_parallel_mcts
-        from namazu_tpu.parallel.mesh import make_mesh
-
-        super().__init__(cfg)
-        self.mesh = mesh if mesh is not None else make_mesh(n_devices)
-        self.mcts_cfg = mcts_cfg if mcts_cfg is not None else MCTSConfig(
-            max_delay=cfg.ga.max_delay, max_fault=cfg.ga.max_fault
-        )
-        if self.mcts_cfg.max_fault > 0 and self._coin is None:
-            # an explicit mcts_cfg can enable fault search even when
-            # cfg.ga doesn't — the rollouts still need the fault coin
-            self._coin = te.fault_coin(cfg.seed, cfg.H)
-        if self.mcts_cfg.tree_depth > cfg.H:
-            # the tree cannot decide more buckets than the genome has
-            self.mcts_cfg = self.mcts_cfg._replace(tree_depth=cfg.H)
-        self._run = make_parallel_mcts(self.mesh, cfg.H, self.mcts_cfg,
-                                       cfg.weights)
-        self._key = jax.random.PRNGKey(cfg.seed)
-        self._best_fitness = float("-inf")
-        self._best_delays = np.zeros((cfg.H,), np.float32)
-        self._best_faults = np.zeros((cfg.H,), np.float32)
-        self._seed_tables: Optional[np.ndarray] = None  # f32[S, H]
-
-    def _reset_best(self) -> None:
-        self._best_fitness = float("-inf")
-
-    #: seed tables are cyclically tiled to this fixed row count so the
-    #: jitted search sees ONE seeds shape — otherwise every new recorded
-    #: failure (S = 1, 2, 3, ...) would force a full recompile of the
-    #: parallel MCTS
-    SEED_ROWS = 16
-
-    def seed_population(self, delay_tables) -> None:
-        """Demonstration tables steer the rollouts: half of each rollout
-        batch completes unpinned buckets from a noise-perturbed seed
-        (models/mcts.py _make_rollout) — the MCTS analogue of the GA's
-        population seeding, same source (recorded failures' injected
-        delays)."""
-        if len(delay_tables) == 0:
-            return
-        raw = np.clip(
-            np.stack([np.asarray(t, np.float32) for t in delay_tables]),
-            0.0, self.mcts_cfg.max_delay)
-        reps = -(-self.SEED_ROWS // raw.shape[0])
-        self._seed_tables = np.tile(raw, (reps, 1))[: self.SEED_ROWS]
-
-    def _hint_order(self, encs) -> np.ndarray:
-        """Bucket ids ordered by frequency across the reference traces —
-        the tree decides the most-often-hit buckets first."""
-        counts = np.zeros((self.cfg.H,), np.int64)
-        for e in encs:
-            counts += np.bincount(e.hint_ids[e.mask],
-                                  minlength=self.cfg.H)
-        return np.argsort(-counts)[: self.mcts_cfg.tree_depth].astype(
-            np.int32
-        )
-
-    def run(self, encoded, generations: int = 1) -> BestSchedule:
-        """Run ``max(1, generations // 64)`` independent tree searches of
-        ``mcts_cfg.simulations`` expansions each (the GA's ``generations``
-        knob maps onto simulation budget so configs stay comparable);
-        returns the best schedule seen so far (monotonic across calls)."""
-        import jax
-        import jax.numpy as jnp
-
-        encs, trace, pairs, archive, failures = self._device_inputs(encoded)
-        hint_order = jnp.asarray(self._hint_order(encs))
-        coin = None if self._coin is None else jnp.asarray(self._coin)
-        seeds = (None if self._seed_tables is None
-                 else jnp.asarray(self._seed_tables))
-
-        searches = max(1, generations // 64)
-        t0 = time.perf_counter()
-        for _ in range(searches):
-            self._key, sub = jax.random.split(self._key)
-            fit, d, f = self._run(sub, trace, pairs, archive, failures,
-                                  hint_order, coin, seeds)
-            fit = float(fit)
-            if fit > self._best_fitness:
-                self._best_fitness = fit
-                self._best_delays = np.asarray(d)
-                self._best_faults = np.asarray(f)
-        elapsed = time.perf_counter() - t0
-        sims = searches * self.mcts_cfg.simulations
-        self.generations_run += sims
-        self._record_progress(sims, elapsed,
-                              sims * self.mcts_cfg.rollouts,
-                              self._best_fitness)
-        return self.best()
-
-    def best(self) -> BestSchedule:
-        return BestSchedule(
-            delays=self._best_delays,
-            faults=self._best_faults,
-            fitness=self._best_fitness,
-        )
-
-    # -- persistence -----------------------------------------------------
-
-    def _state_dict(self) -> dict:
-        return {
-            "best_fitness": np.asarray(self._best_fitness, np.float32),
-            "best_delays": self._best_delays,
-            "best_faults": self._best_faults,
-        }
-
-    def _restore_state(self, z) -> None:
-        self._best_fitness = float(z["best_fitness"])
-        self._best_delays = z["best_delays"]
-        self._best_faults = z["best_faults"]
+#: held by tests/benchmarks/, which patch ``SearchBase._flush`` and
+#: ``.add_executed_trace``: the same object, so they land on what runs
+SearchBase = ScheduleSearch

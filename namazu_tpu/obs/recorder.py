@@ -248,9 +248,9 @@ class FlightRecorder:
         # namespace resolve here, untagged ones keep resolving to
         # `_current` — so N tenants' records never interleave
         self._pinned: Dict[str, RunTrace] = {}
-        # cumulative GA generations (or MCTS simulations) evolved in this
-        # process; decisions snapshot it so a replayed delay points back
-        # at the search round that produced its table
+        # cumulative GA generations evolved in this process; decisions
+        # snapshot it so a replayed delay points back at the search
+        # round that produced its table
         self._gen_seq = 0
 
     # -- run lifecycle ----------------------------------------------------

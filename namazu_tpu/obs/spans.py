@@ -947,7 +947,7 @@ def search_round(backend: str, generations: int, elapsed: float,
             "round (host_io seconds / evolve seconds)", ("backend",),
         ).labels(backend=backend).set(host_io_s / elapsed)
     reg.counter(
-        SEARCH_GENERATIONS, "GA generations (or MCTS simulations) run",
+        SEARCH_GENERATIONS, "GA generations run",
         ("backend",),
     ).labels(backend=backend).inc(generations)
     if elapsed > 0:
@@ -1534,8 +1534,8 @@ def ensure_compile_listener() -> None:
     one), and leaves a ``compile`` row under the current request —
     which step recompiled, for which request, and under ``fun_name``
     the name jax gives the lowered module (``jit(<function>)``; absent
-    where a jax passes none). Called by the search backends'
-    constructors (the first thing in a process that can compile)."""
+    where a jax passes none). Called by the search's constructor
+    (the first thing in a process that can compile)."""
     global _compile_listener_on
     if _compile_listener_on:
         return
@@ -1571,7 +1571,7 @@ def ingest_runs(n: int) -> None:
 
 def ingest_embed_call() -> None:
     """One device call of the batched embed program
-    (``SearchBase._embed_chunks``): up to ``EMBED_CHUNK`` executed
+    (``ScheduleSearch._embed_chunks``): up to ``EMBED_CHUNK`` executed
     traces embedded at once. ``nmz_ingest_runs_total`` over this is
     how full the chunks run."""
     if not metrics.enabled():
@@ -1644,7 +1644,7 @@ def reorder_window_drained(policy: str, events: int,
 
 
 def evolve_request(scorer: str) -> None:
-    """One evolve of a search backend, by the first-occurrence branch
+    """One evolve of the search, by the first-occurrence branch
     its compiled step took for the request's padded trace length
     (``ops/schedule.py::scorer_branch``: ``dense`` | ``blockwise`` |
     ``order``)."""
@@ -1731,7 +1731,7 @@ def resident_trace_rows(op: str, n: int = 1) -> None:
 
 def embed_traces(n: int, below_class: int) -> None:
     """Traces one flush handed the batched embed program
-    (``SearchBase._embed_chunks``) and, of them, those whose own padded
+    (``ScheduleSearch._embed_chunks``) and, of them, those whose own padded
     length is under the search's length class: the runs a per-length
     embed would have grouped apart, each group a program of its own.
     Both samples are written, the second at 0 where none is shorter: a

@@ -455,11 +455,9 @@ def _population_features(
     tables: Optional[_DelayTables] = None,
 ) -> tuple[jax.Array, jax.Array]:
     """(features f32[P, K], dropped-event counts i32[P]) of a population
-    against one trace. In delay mode the trace's tables (``tables``, or
-    built here) come before the ``vmap`` over genomes, so that no
+    against one trace. In delay mode the trace's ``tables`` come from
+    the caller, built before this ``vmap`` over genomes, so that no
     per-event op carries the population dimension."""
-    if tables is None and not weights.order_mode:
-        tables = _delay_tables(trace, delays.shape[-1], faults is not None)
     return jax.vmap(
         lambda d, f: _genome_features(d, trace, pairs, weights.tau,
                                       weights.order_mode, weights.order_gap,
@@ -538,7 +536,7 @@ def min_sq_distance(feats: jax.Array, archive: jax.Array,
     tests/test_fused_loop.py). ``None`` keeps the pre-occupancy graph:
     every row is live — the in-repo search passes None, because its
     rings deliberately treat unoccupied slots as neutral 0.5 feature
-    points (SearchBase), and masking them out would change fitness.
+    points (ScheduleSearch), and masking them out would change fitness.
     """
     dt = _matmul_dtype()
     f16 = feats.astype(dt)
@@ -592,84 +590,7 @@ def _min_sq_pair_best(feats: jax.Array, archive: jax.Array,
     return nov, bug
 
 
-def score_population(
-    delays: jax.Array,  # [P, H]
-    trace: TraceArrays,
-    pairs: jax.Array,  # [K, 2]
-    archive: jax.Array,  # [A, K] features of executed schedules
-    failure_feats: jax.Array,  # [F, K] features of bug-reproducing runs
-    weights: ScoreWeights = ScoreWeights(),
-    faults: Optional[jax.Array] = None,  # [P, H] fault probabilities
-    coin: Optional[jax.Array] = None,  # [H] deterministic fault coin
-    novelty_scale: Optional[jax.Array] = None,  # dynamic f32 scalar
-    archive_n: Optional[jax.Array] = None,  # dynamic i32 occupancy
-    failure_n: Optional[jax.Array] = None,  # dynamic i32 occupancy
-) -> tuple[jax.Array, jax.Array]:
-    """Fitness f32[P] and features f32[P,K] for a whole population.
-
-    With ``faults``/``coin``, the genome's fault half is part of the
-    counterfactual: dropped events reshape the features, and a
-    ``fault_cost`` per dropped event keeps "drop everything" from being
-    the novelty optimum. In delay mode no per-event op runs under the
-    population ``vmap``: the trace's tables are built once, blockwise
-    for a long trace (see :func:`_population_features`).
-
-    ``novelty_scale`` multiplies ``weights.novelty`` as a *traced*
-    scalar — the novelty-anneal lever (exploration weight decays as the
-    failure archive accumulates distinct signatures) without a new jit
-    specialization per annealed value. ``None`` keeps the pre-anneal
-    graph.
-
-    ``archive_n``/``failure_n`` (traced i32 scalars) are ring
-    occupancies for fixed-capacity archive buffers: rows past the
-    occupancy are masked out of the distance min, equivalent to slicing
-    ``archive[:n]`` but shape-stable, so an external driver whose
-    archive grows mid-run pays ZERO recompilations instead of one per
-    occupancy (compile-count pinned by test). This is the EXPORTED
-    scoring seam's contract; the in-repo search passes ``None`` (the
-    default, and the pre-occupancy graphs) on purpose — SearchBase's
-    rings treat unoccupied slots as neutral 0.5 feature points, and
-    masking them would change fitness."""
-    feats, ndrop = _population_features(delays, trace, pairs, weights,
-                                        faults, coin)
-    if faults is None:
-        fault_pen = 0.0
-    else:
-        live = jnp.maximum(jnp.sum(trace.mask), 1)
-        fault_pen = weights.fault_cost * ndrop / live
-    nov_d2, bug_d2 = _min_sq_pair_best(feats, archive, failure_feats,
-                                       archive_n=archive_n,
-                                       failure_n=failure_n)
-    novelty = nov_d2
-    bug = -bug_d2
-    delay_cost = jnp.mean(delays, axis=-1)
-    w_nov = (weights.novelty if novelty_scale is None
-             else weights.novelty * novelty_scale)
-    fitness = (
-        w_nov * novelty
-        + weights.bug * bug
-        - weights.delay_cost * delay_cost
-        - fault_pen
-    )
-    return fitness, feats
-
-
-@functools.partial(jax.jit, static_argnames=("weights",))
-def score_population_jit(delays, trace, pairs, archive, failure_feats,
-                         weights: ScoreWeights = ScoreWeights(),
-                         faults=None, coin=None, novelty_scale=None,
-                         archive_n=None, failure_n=None):
-    """Jitted :func:`score_population`. ``archive_n``/``failure_n`` are
-    TRACED occupancy scalars — one compiled specialization serves every
-    occupancy of a fixed-capacity archive buffer (the mid-run recompile
-    fix; see ``score_population``)."""
-    return score_population(delays, trace, pairs, archive, failure_feats,
-                            weights, faults=faults, coin=coin,
-                            novelty_scale=novelty_scale,
-                            archive_n=archive_n, failure_n=failure_n)
-
-
-# -- multi-trace scoring ----------------------------------------------------
+# -- population scoring -----------------------------------------------------
 
 
 def trace_tables(traces: TraceArrays, H: int, weights: ScoreWeights,
@@ -700,11 +621,34 @@ def score_population_multi(
     failure_n: Optional[jax.Array] = None,  # dynamic i32 occupancy
     tables: Optional[_DelayTables] = None,  # trace_tables(traces, ...)
 ) -> tuple[jax.Array, jax.Array]:
-    """Fitness averaged over T recorded traces (novelty against ONE run
-    is mostly its noise): (fitness [P], feats [P, T, K]). ONE compiled
-    program on concrete arrays (the re-rank), inline under a trace.
-    ``tables``: the traces' :func:`trace_tables` where the caller built
-    them already; built here otherwise."""
+    """The population scorer: fitness averaged over T recorded traces
+    (novelty against ONE run is mostly its noise): (fitness [P], feats
+    [P, T, K]). ONE compiled program on concrete arrays (the re-rank),
+    inline under a trace. ``tables``: the traces' :func:`trace_tables`
+    where the caller built them already; built here otherwise.
+
+    With ``faults``/``coin``, the genome's fault half is part of the
+    counterfactual: dropped events reshape the features, and a
+    ``fault_cost`` per dropped event (each trace's dropped share,
+    averaged over the traces) keeps "drop everything" from being the
+    novelty optimum. In delay mode no per-event op runs under the
+    population ``vmap``: the traces' tables are built once, blockwise
+    for a long trace (see :func:`_population_features`).
+
+    ``novelty_scale`` multiplies ``weights.novelty`` as a *traced*
+    scalar — the novelty-anneal lever (exploration weight decays as the
+    failure archive accumulates distinct signatures) without a new jit
+    specialization per annealed value. ``None`` keeps the pre-anneal
+    graph.
+
+    ``archive_n``/``failure_n`` (traced i32 scalars) are ring
+    occupancies for fixed-capacity archive buffers: rows past the
+    occupancy are masked out of the distance min, equivalent to slicing
+    ``archive[:n]`` but shape-stable, so one compiled specialization
+    serves every occupancy (compile-count pinned by test). The search
+    passes ``None`` (the default, and the pre-occupancy graphs) on
+    purpose — its rings treat unoccupied slots as neutral 0.5 feature
+    points, and masking them would change fitness."""
     args = (delays, traces, pairs, archive, failure_feats, weights, faults,
             coin, novelty_scale, archive_n, failure_n, tables)
     if _all_concrete(args):
@@ -842,18 +786,3 @@ def first_occurrence_blockwise(
         ),
     )
     return first, ndrop
-
-
-def schedule_features_long(
-    delays: jax.Array, trace: TraceArrays, pairs: jax.Array, tau: float,
-    chunk: int = LONG_TRACE_CHUNK,
-    faults: Optional[jax.Array] = None,
-    coin: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Feature vector for long traces (thousands of events) with bounded
-    memory; numerically identical to :func:`schedule_features`."""
-    first, _ = first_occurrence_blockwise(
-        delays, trace.hint_ids, trace.arrival, trace.mask, chunk,
-        faults=faults, coin=coin, faultable=trace.faultable,
-    )
-    return precedence_features(first, pairs, tau)

@@ -28,7 +28,7 @@ import time
 from typing import Optional
 
 from namazu_tpu import obs
-from namazu_tpu.models import SEARCH_DEFAULTS
+from namazu_tpu.models import SEARCH_DEFAULTS, refuse_search_backend
 from namazu_tpu.policy.base import QueueBackedPolicy, register_policy
 from namazu_tpu.policy.edge_table import TablePublisher
 from namazu_tpu.policy.replayable import (
@@ -46,6 +46,8 @@ from namazu_tpu.utils.log import get_logger
 log = get_logger("policy.tpu")
 
 
+_MCTS_GONE = "the MCTS backend was removed; the island GA searches"
+
 #: ``tpu_search`` keys that went with the code they selected, and what a
 #: config that still sets one gets instead (``load_config`` says so once
 #: per key: a removed key must not fail silently)
@@ -57,6 +59,12 @@ REMOVED_KEYS = {
                          "migrates every generation",
     "dcn_hosts": "the search runs on a flat mesh over this process's "
                  "chips (devices = N takes the first N)",
+    "search_backend": "there is one search, the island GA (any other "
+                      "value is refused)",
+    "mcts_simulations": _MCTS_GONE,
+    "mcts_tree_depth": _MCTS_GONE,
+    "mcts_levels": _MCTS_GONE,
+    "mcts_rollouts": _MCTS_GONE,
 }
 
 
@@ -107,7 +115,6 @@ class TPUSearchPolicy(QueueBackedPolicy):
         # then one evolution over the batch of new outcomes.
         self.search_every = 1
         self.max_fault = d["max_fault"]
-        self.search_backend = d["search_backend"]
         # release modes (BASELINE config 3): "delay" replays the table as
         # literal per-hint delays; "reorder" treats it as per-hint
         # *priorities* — events buffered for reorder_window seconds are
@@ -134,10 +141,6 @@ class TPUSearchPolicy(QueueBackedPolicy):
         # invariant is testable with a scripted clock and zero real
         # sleeps instead of margin-widened wall-clock waits
         self._now = time.monotonic
-        self.mcts_simulations = d["mcts_simulations"]
-        self.mcts_tree_depth = d["mcts_tree_depth"]
-        self.mcts_levels = d["mcts_levels"]
-        self.mcts_rollouts = d["mcts_rollouts"]
         self.surrogate_topk = d["surrogate_topk"]
         # cross-batch failure-signature pool directory ("" = off); see
         # models/failure_pool.py. Relative paths anchor to the PARENT of
@@ -240,6 +243,7 @@ class TPUSearchPolicy(QueueBackedPolicy):
         self.K = int(p("feature_pairs", self.K))
         self.migrate_k = int(p("migrate_k", self.migrate_k))
         self.fused_chunk = max(1, int(p("fused_chunk", self.fused_chunk)))
+        refuse_search_backend(p("search_backend", None))
         for key, instead in REMOVED_KEYS.items():
             if p(key, None) is not None:
                 log.warning("tpu_search key %r was removed and is "
@@ -263,21 +267,6 @@ class TPUSearchPolicy(QueueBackedPolicy):
                 "\"search.npz\""
             )
         self.max_fault = float(p("max_fault", self.max_fault))
-        self.search_backend = str(p("search_backend", self.search_backend))
-        if self.search_backend not in ("ga", "mcts"):
-            # fail fast: an exception inside the background search thread
-            # would be logged-and-swallowed, silently degrading to hash
-            # delays for the whole experiment
-            raise ValueError(
-                f"unknown search_backend {self.search_backend!r} "
-                "(expected 'ga' or 'mcts')"
-            )
-        self.mcts_simulations = int(p("mcts_simulations",
-                                      self.mcts_simulations))
-        self.mcts_tree_depth = int(p("mcts_tree_depth",
-                                     self.mcts_tree_depth))
-        self.mcts_levels = int(p("mcts_levels", self.mcts_levels))
-        self.mcts_rollouts = int(p("mcts_rollouts", self.mcts_rollouts))
         self.surrogate_topk = int(p("surrogate_topk", self.surrogate_topk))
         self.failure_pool = os.path.expanduser(os.path.expandvars(
             str(p("failure_pool", self.failure_pool) or "")))
@@ -294,12 +283,6 @@ class TPUSearchPolicy(QueueBackedPolicy):
             p("guidance_bitmap_width", self.guidance_width))
         self.guidance_window = int(
             p("guidance_window", self.guidance_window))
-        if (self.min_failure_signatures > 0
-                and self.search_backend == "mcts"):
-            log.warning(
-                "novelty anneal (min_failure_signatures=%d) applies to "
-                "the GA backend only; the mcts backend scores with "
-                "static weights", self.min_failure_signatures)
         self.w_novelty = float(p("w_novelty", self.w_novelty))
         self.w_bug = float(p("w_bug", self.w_bug))
         self.w_delay_cost = float(p("w_delay_cost", self.w_delay_cost))
@@ -855,15 +838,10 @@ class TPUSearchPolicy(QueueBackedPolicy):
             "surrogate_topk": self.surrogate_topk,
             "min_failure_signatures": self.min_failure_signatures,
             "novelty_floor": self.novelty_floor,
-            "search_backend": self.search_backend,
             "guidance": self._guidance_active(),
             "guidance_bonus": self.guidance_bonus,
             "guidance_width": self.guidance_width,
             "guidance_window": self.guidance_window,
-            "mcts_tree_depth": self.mcts_tree_depth,
-            "mcts_levels": self.mcts_levels,
-            "mcts_simulations": self.mcts_simulations,
-            "mcts_rollouts": self.mcts_rollouts,
             "release_mode": self.release_mode,
             "w_novelty": self.w_novelty, "w_bug": self.w_bug,
             "w_delay_cost": self.w_delay_cost,

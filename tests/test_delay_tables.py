@@ -19,6 +19,7 @@ from namazu_tpu.ops import trace_encoding as te
 from namazu_tpu.ops.schedule import ScoreWeights, TraceArrays
 from namazu_tpu.parallel.islands import IslandState, make_fused_island_step
 from namazu_tpu.parallel.mesh import make_mesh
+from tests.scoring import stack
 
 H, K = 32, 48
 TAU = 0.005
@@ -121,10 +122,9 @@ def _per_event_population(delays, trace, pairs, weights, faults=None,
 @pytest.mark.parametrize("T", [1, 4])
 def test_population_scores_equal_the_per_event_oracle(T, L, with_faults,
                                                       monkeypatch):
-    """``score_population`` (T 1) and ``score_population_multi`` (T 4)
-    at P 64: fitness and features equal, to the bit, to the same
-    function with the parent's per-event features in the tables'
-    place."""
+    """``score_population_multi`` at T 1 and T 4, P 64: fitness and
+    features equal, to the bit, to the same function with the parent's
+    per-event features in the tables' place."""
     P_ = 64
     rng = np.random.default_rng(L + T)
     traces = [make_trace(L, "mixed" if with_faults else "none", seed=t)
@@ -137,17 +137,12 @@ def test_population_scores_equal_the_per_event_oracle(T, L, with_faults,
     if with_faults:
         faults = jnp.asarray(rng.random((P_, H)).astype(np.float32))
         coin = jnp.asarray(te.fault_coin(3, H))
-    if T == 1:
-        def score(d, f):
-            return sch.score_population(d, traces[0], pairs, archive, fails,
-                                        ScoreWeights(), faults=f, coin=coin)
-    else:
-        stacked = jax.tree.map(lambda *x: jnp.stack(x), *traces)
+    stacked = stack(*traces)
 
-        def score(d, f):
-            return sch.score_population_multi(
-                d, stacked, pairs, archive, fails, ScoreWeights(),
-                faults=f, coin=coin)
+    def score(d, f):
+        return sch.score_population_multi(
+            d, stacked, pairs, archive, fails, ScoreWeights(),
+            faults=f, coin=coin)
 
     # each side under a jit of its own: traced anew, so the patched
     # name is what the oracle's side calls
@@ -157,17 +152,16 @@ def test_population_scores_equal_the_per_event_oracle(T, L, with_faults,
     np.testing.assert_array_equal(feats, want_feats)
     np.testing.assert_array_equal(fit, want_fit)
     assert np.ptp(np.asarray(fit)) > 0
-    if T > 1:
-        # the tables handed in, as the fused step hands them in once a
-        # dispatch: the same answer
-        monkeypatch.undo()
-        tables = sch.trace_tables(stacked, H, ScoreWeights(), with_faults)
-        held_fit, held_feats = jax.jit(
-            lambda d, f, tb: sch.score_population_multi(
-                d, stacked, pairs, archive, fails, ScoreWeights(),
-                faults=f, coin=coin, tables=tb))(delays, faults, tables)
-        np.testing.assert_array_equal(held_feats, want_feats)
-        np.testing.assert_array_equal(held_fit, want_fit)
+    # the tables handed in, as the fused step hands them in once a
+    # dispatch: the same answer
+    monkeypatch.undo()
+    tables = sch.trace_tables(stacked, H, ScoreWeights(), with_faults)
+    held_fit, held_feats = jax.jit(
+        lambda d, f, tb: sch.score_population_multi(
+            d, stacked, pairs, archive, fails, ScoreWeights(),
+            faults=f, coin=coin, tables=tb))(delays, faults, tables)
+    np.testing.assert_array_equal(held_feats, want_feats)
+    np.testing.assert_array_equal(held_fit, want_fit)
 
 
 # -- structure: what the compiled step holds --------------------------------

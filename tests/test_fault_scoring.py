@@ -22,10 +22,14 @@ from namazu_tpu.ops.schedule import (
     apply_faults,
     drop_mask,
     schedule_features,
-    score_population,
     score_population_multi,
     trace_features,
 )
+from tests.scoring import stack
+
+# the scorer takes a [T, L] stack: each property below is held at T 1
+# and at T 3, where the fault penalty is a mean over the traces
+STACKS = pytest.mark.parametrize("T", [1, 3])
 
 H, L, K = 32, 64, 64
 
@@ -110,9 +114,9 @@ def test_dropping_bucket_matches_skip_trace_features():
     assert not np.allclose(np.asarray(f_drop), np.asarray(f_plain))
 
 
-def test_fault_cost_penalizes_drop_everything():
-    enc = stream()
-    trace = arrays(enc)
+@STACKS
+def test_fault_cost_penalizes_drop_everything(T):
+    traces = stack(*[arrays(stream(n=48 - 8 * t)) for t in range(T)])
     pairs = jnp.asarray(te.sample_pairs(K, H, 0))
     coin = jnp.asarray(te.fault_coin(0, H))
     archive = jnp.full((4, K), 0.5)
@@ -121,32 +125,55 @@ def test_fault_cost_penalizes_drop_everything():
                            fault_cost=1.0)
     delays = jnp.zeros((2, H))
     faults = jnp.stack([jnp.zeros(H), jnp.ones(H)])  # none vs all dropped
-    fit, _ = score_population(delays, trace, pairs, archive, fails,
-                              weights, faults=faults, coin=coin)
+    fit, _ = score_population_multi(delays, traces, pairs, archive, fails,
+                                    weights, faults=faults, coin=coin)
     assert float(fit[0]) == pytest.approx(0.0, abs=1e-6)
     assert float(fit[1]) == pytest.approx(-1.0, abs=1e-5)  # all live dropped
 
 
-def test_no_fault_args_is_backward_compatible():
-    enc = stream()
-    trace = arrays(enc)
+def test_fault_cost_is_the_mean_of_the_traces_dropped_shares():
+    """One bucket dropped, three traces that hold 3 of 48, 3 of 40 and
+    none of its events: the penalty is the mean of the three shares,
+    not the share of the events pooled."""
+    encs = [stream(), stream(n=40), stream(skip_hint="hint3")]
+    pairs = jnp.asarray(te.sample_pairs(K, H, 0))
+    coin = jnp.asarray(te.fault_coin(0, H))
+    bucket = te.hint_bucket("hint3", H)
+    faults = jnp.zeros((1, H)).at[0, bucket].set(float(coin[bucket]) + 1e-3)
+    shares = [float(((e.hint_ids == bucket) & e.mask).sum() / e.mask.sum())
+              for e in encs]
+    assert shares[0] > 0 and shares[2] == 0 and shares[0] != shares[1]
+    weights = ScoreWeights(novelty=0.0, bug=0.0, delay_cost=0.0,
+                           fault_cost=1.0)
+    fit, _ = score_population_multi(
+        jnp.zeros((1, H)), stack(*map(arrays, encs)), pairs,
+        jnp.full((4, K), 0.5), jnp.full((2, K), 0.5), weights,
+        faults=faults, coin=coin)
+    assert float(fit[0]) == pytest.approx(-np.mean(shares), abs=1e-6)
+
+
+@STACKS
+def test_no_fault_args_is_backward_compatible(T):
+    traces = stack(*[arrays(stream(n=48 - 8 * t)) for t in range(T)])
     pairs = jnp.asarray(te.sample_pairs(K, H, 0))
     archive = jnp.full((4, K), 0.5)
     fails = jnp.full((2, K), 0.5)
     pop = init_population(jax.random.PRNGKey(0), 16, H, GAConfig())
-    f1, _ = score_population(pop.delays, trace, pairs, archive, fails)
+    f1, _ = score_population_multi(pop.delays, traces, pairs, archive,
+                                   fails)
     coin = jnp.ones((H,))  # coin >= 1: fault half is a no-op
-    f2, _ = score_population(pop.delays, trace, pairs, archive, fails,
-                             faults=pop.faults, coin=coin)
+    f2, _ = score_population_multi(pop.delays, traces, pairs, archive,
+                                   fails, faults=pop.faults, coin=coin)
     assert np.allclose(np.asarray(f1), np.asarray(f2), atol=1e-6)
 
 
-def test_ga_learns_drop_requiring_bug():
+@STACKS
+def test_ga_learns_drop_requiring_bug(T):
     """Planted structure: the failure signature is the interleaving with
     every 'hint3' event missing. Only a genome that actually drops that
     bucket can match it; the GA must select the fault dimension."""
     full, skipped = stream(), stream(skip_hint="hint3")
-    trace = arrays(full)
+    trace = stack(*[arrays(full)] * T)
     pairs = jnp.asarray(te.sample_pairs(K, H, 0))
     coin = jnp.asarray(te.fault_coin(0, H))
     bucket = te.hint_bucket("hint3", H)
@@ -161,13 +188,14 @@ def test_ga_learns_drop_requiring_bug():
     pop = init_population(jax.random.PRNGKey(1), 256, H, cfg)
     key = jax.random.PRNGKey(2)
     for _ in range(25):
-        fit, _ = score_population(pop.delays, trace, pairs, archive,
-                                  target, weights, faults=pop.faults,
-                                  coin=coin)
+        fit, _ = score_population_multi(pop.delays, trace, pairs, archive,
+                                        target, weights, faults=pop.faults,
+                                        coin=coin)
         key, k = jax.random.split(key)
         pop = ga_generation(k, pop, fit, cfg)
-    fit, _ = score_population(pop.delays, trace, pairs, archive, target,
-                              weights, faults=pop.faults, coin=coin)
+    fit, _ = score_population_multi(pop.delays, trace, pairs, archive,
+                                    target, weights, faults=pop.faults,
+                                    coin=coin)
     best = int(jnp.argmax(fit))
     best_faults = np.asarray(pop.faults[best])
     coin_np = np.asarray(coin)
@@ -178,8 +206,8 @@ def test_ga_learns_drop_requiring_bug():
 
     # ablation: with the fault half disabled the same objective is
     # unreachable (the bug REQUIRES the drop)
-    nofault, _ = score_population(pop.delays, trace, pairs, archive,
-                                  target, weights)
+    nofault, _ = score_population_multi(pop.delays, trace, pairs, archive,
+                                        target, weights)
     assert float(fit[best]) > float(nofault.max()) + 0.005
 
 

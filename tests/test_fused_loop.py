@@ -32,19 +32,19 @@ import jax.numpy as jnp
 from namazu_tpu import obs
 from namazu_tpu.models.ga import GAConfig
 from namazu_tpu.models.search import ScheduleSearch, SearchConfig
+from namazu_tpu.ops import schedule
 from namazu_tpu.ops import trace_encoding as te
 from namazu_tpu.ops.schedule import (
     ScoreWeights,
     TraceArrays,
     min_sq_distance,
-    score_population,
-    score_population_jit,
 )
 from namazu_tpu.parallel.islands import (
     init_island_state,
     make_fused_island_step,
 )
 from namazu_tpu.parallel.mesh import make_mesh
+from tests.scoring import score_one
 
 H, L, K = 32, 64, 32
 
@@ -175,16 +175,21 @@ def test_scorer_occupancy_mask_equals_slicing_without_retrace():
     failures = jnp.asarray(rng.rand(8, K).astype(np.float32))
     delays = jnp.asarray(rng.rand(12, H).astype(np.float32) * 0.05)
 
-    before = score_population_jit._cache_size()
+    # on concrete arrays the scorer runs its compiled twin: that is
+    # the cache an occupancy must not grow
+    twin = schedule._score_population_multi_jit
+    before = twin._cache_size()
     cached = None
     for occ_a, occ_f in ((1, 1), (5, 3), (16, 8)):
-        fit_m, _ = score_population_jit(
+        fit_m, _ = score_one(
             delays, trace, pairs, archive, failures, ScoreWeights(),
             archive_n=jnp.asarray(occ_a, jnp.int32),
             failure_n=jnp.asarray(occ_f, jnp.int32))
-        fit_s, _ = score_population(
-            delays, trace, pairs, archive[:occ_a], failures[:occ_f],
-            ScoreWeights())
+        # the sliced reference under a jit of its own (inlined there,
+        # it leaves the twin's cache alone)
+        fit_s, _ = jax.jit(lambda a, f: score_one(
+            delays, trace, pairs, a, f, ScoreWeights()))(
+                archive[:occ_a], failures[:occ_f])
         # masking rows past the occupancy == slicing the buffer: each
         # candidate distance is the same math, but the sliced call's
         # differently-shaped matmul may accumulate in a different order,
@@ -193,7 +198,7 @@ def test_scorer_occupancy_mask_equals_slicing_without_retrace():
         # programs and stays exact)
         assert np.allclose(np.asarray(fit_m), np.asarray(fit_s),
                            rtol=1e-5, atol=1e-6)
-        size = score_population_jit._cache_size()
+        size = twin._cache_size()
         if cached is None:
             cached = size
             assert size == before + 1  # exactly one new specialization

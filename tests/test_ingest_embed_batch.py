@@ -18,7 +18,6 @@ from namazu_tpu.models.ga import GAConfig
 from namazu_tpu.models.ingest import IngestParams, ingest_history
 from namazu_tpu.models.search import (
     EMBED_CHUNK,
-    MCTSSearch,
     ScheduleSearch,
     SearchConfig,
 )
@@ -283,20 +282,17 @@ def test_without_mirrors_only_the_host_rings_are_written():
 # -- (d) one shape whatever the depth ----------------------------------------
 
 
-@pytest.mark.parametrize("backend", ["ga", "mcts"])
-def test_depths_10_23_66_75_lower_the_embed_program_once(backend,
+@pytest.mark.parametrize("mirrors", ["built", "unbuilt"])
+def test_depths_10_23_66_75_lower_the_embed_program_once(mirrors,
                                                          fresh_obs):
     # a tau of this test's own: the jitted embed is cached per (tau, H)
     # for the whole process, and this one has to start cold
-    weights = sch.ScoreWeights(tau=0.00512 if backend == "ga" else 0.00513)
-    c = cfg(weights=weights)
-    if backend == "ga":
-        s = ScheduleSearch(c, n_devices=1)
-        refs = ingest_history(s, history(10), IngestParams(H=H))
+    weights = sch.ScoreWeights(
+        tau=0.00512 if mirrors == "built" else 0.00513)
+    s = ScheduleSearch(cfg(weights=weights), n_devices=1)
+    refs = ingest_history(s, history(10), IngestParams(H=H))
+    if mirrors == "built":
         s.run(refs, generations=2)  # mirrors: the scatter compiles too
-        ingest_history(s, history(10), IngestParams(H=H))
-    else:
-        s = MCTSSearch(c, n_devices=1)
         ingest_history(s, history(10), IngestParams(H=H))
     embed = sch.batched_trace_features(weights.tau, H)
     assert embed._cache_size() == 1
@@ -317,7 +313,7 @@ def test_embed_calls_are_ceil_n_over_chunk_whatever_the_lengths(
     # one length per search: the short and the long runs of a history
     # share the chunks of ONE program (before: a chunk sequence, and a
     # compiled embed, per padded length)
-    s = MCTSSearch(cfg(archive_size=256), n_devices=1)
+    s = ScheduleSearch(cfg(archive_size=256), n_devices=1)
     st = history(short + long, long_from=short)
     want = math.ceil((short + long) / EMBED_CHUNK)
     for request in (1, 2):
@@ -394,22 +390,6 @@ def test_guidance_fragments_stay_slot_aligned(fresh_obs):
     assert len(labels) == 8
 
 
-# -- (h) the backend without mirrors -----------------------------------------
-
-
-def test_mcts_ingests_through_the_same_path(fresh_obs):
-    c = cfg(archive_size=16, failure_size=4)
-    s = MCTSSearch(c, n_devices=1)
-    replay = PerRunReplay(c)
-    for depth in (9, 20):
-        st = history(depth)
-        refs = ingest_history(s, st, IngestParams(H=H))
-        replay.request(st, s.pairs)
-        replay.check(s)
-    assert embed_calls() == 2
-    assert s.run(refs, generations=64).delays.shape == (H,)
-
-
 # -- (i) long traces ---------------------------------------------------------
 
 
@@ -480,7 +460,7 @@ def test_ingest_counts_events_and_length_groups(short, long, groups,
     them, all embedded at the search's length class by ONE program:
     ``pieces=`` (device calls) is ``ceil(N / EMBED_CHUNK)`` whatever
     the lengths."""
-    s = MCTSSearch(cfg(archive_size=256), n_devices=1)
+    s = ScheduleSearch(cfg(archive_size=256), n_devices=1)
     st = history(short + long, long_from=short)
     events = 17 * short + 140 * long
     for request in (1, 2):

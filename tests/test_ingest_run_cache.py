@@ -21,7 +21,7 @@ from namazu_tpu.models.ingest import (
     RunRecordCache,
     ingest_history,
 )
-from namazu_tpu.models.search import MCTSSearch
+from namazu_tpu.models.search import ScheduleSearch
 from namazu_tpu.obs import export, spans
 from namazu_tpu.signal.base import HINT_SPACE
 from namazu_tpu.storage import load_storage, new_storage
@@ -80,7 +80,7 @@ def rewrite_trace(st, i, trace):
 def ingested(storage, params=PARAMS, search=None):
     """One ingest into ``search`` (a fresh one unless given): everything
     the search and the caller hold of the history afterwards."""
-    s = search if search is not None else MCTSSearch(cfg(), n_devices=1)
+    s = search if search is not None else ScheduleSearch(cfg(), n_devices=1)
     refs = ingest_history(s, storage, params)
     return {
         "archive": s.archive.copy(), "labels": s.archive_labels.copy(),
@@ -175,8 +175,8 @@ def test_a_persistent_search_fills_its_rings_alike(tmp_path):
     """Two requests into ONE search (the sidecar's resident search):
     the second, all hits, writes the rows the first one wrote."""
     st = make_storage(tmp_path / "st", 9)
-    cached = MCTSSearch(cfg(), n_devices=1)
-    plain = MCTSSearch(cfg(), n_devices=1)
+    cached = ScheduleSearch(cfg(), n_devices=1)
+    plain = ScheduleSearch(cfg(), n_devices=1)
     for _ in range(2):
         a = ingested(st, search=cached)
         b = ingested(unsigned(st), search=plain)
@@ -299,11 +299,11 @@ def test_records_of_other_parameters_are_not_taken(tmp_path, parsed, other):
     st = make_storage(tmp_path / "st", 5)
     ingested(st)
     del parsed[:]
-    s = MCTSSearch(cfg(H=other.H, K=16 if other.H != H else cfg().K),
+    s = ScheduleSearch(cfg(H=other.H, K=16 if other.H != H else cfg().K),
                    n_devices=1)
     got = ingested(st, other, search=s)
     assert parsed == list(range(5))
-    plain = MCTSSearch(s.cfg, n_devices=1)
+    plain = ScheduleSearch(s.cfg, n_devices=1)
     assert_same(got, ingested(unsigned(st), other, search=plain))
     # and the first parameters' records are still there
     del parsed[:]
@@ -349,7 +349,7 @@ def test_a_backend_without_signatures_keeps_nothing(tmp_path, records,
 def test_the_arrays_of_a_kept_record_refuse_writes(tmp_path):
     st = make_storage(tmp_path / "st", 3)
     ingested(st)
-    s = MCTSSearch(cfg(), n_devices=1)
+    s = ScheduleSearch(cfg(), n_devices=1)
     refs = ingest_history(s, st, PARAMS)
     for ref in refs:
         for a in (ref.hint_ids, ref.entity_ids, ref.arrival, ref.mask,
@@ -442,7 +442,7 @@ def test_four_threads_on_four_storages_agree_with_four_in_turn(tmp_path):
 def test_cached_runs_are_counted_and_named_on_the_encode_row(tmp_path,
                                                              fresh_obs):
     st = make_storage(tmp_path / "st", 7)
-    s = MCTSSearch(cfg(), n_devices=1)
+    s = ScheduleSearch(cfg(), n_devices=1)
     value = obs.metrics.registry().value
     ingest_history(s, st, PARAMS)
     assert value(spans.INGEST_CACHED_RUNS) == 0

@@ -13,9 +13,8 @@ from namazu_tpu.ops.schedule import (
     first_occurrence,
     first_occurrence_blockwise,
     release_times,
+    precedence_features,
     schedule_features,
-    schedule_features_long,
-    score_population,
     score_population_multi,
 )
 from namazu_tpu.parallel.islands import (
@@ -23,6 +22,7 @@ from namazu_tpu.parallel.islands import (
     make_fused_island_step,
 )
 from namazu_tpu.parallel.mesh import make_mesh
+from tests.scoring import score_one, stack
 
 H, L, K = 32, 64, 64
 
@@ -39,11 +39,7 @@ def as_arrays(e):
 def test_multi_trace_matches_mean_of_single():
     t1 = as_arrays(enc([f"a{i % 7}" for i in range(40)]))
     t2 = as_arrays(enc([f"b{i % 5}" for i in range(30)]))
-    batch = TraceArrays(
-        jnp.stack([t1.hint_ids, t2.hint_ids]),
-        jnp.stack([t1.arrival, t2.arrival]),
-        jnp.stack([t1.mask, t2.mask]),
-    )
+    batch = stack(t1, t2)
     pairs = jnp.asarray(te.sample_pairs(K, H, 0))
     archive = jnp.asarray(np.random.RandomState(0).rand(8, K).astype(np.float32))
     fails = jnp.asarray(np.random.RandomState(1).rand(4, K).astype(np.float32))
@@ -53,14 +49,16 @@ def test_multi_trace_matches_mean_of_single():
 
     multi_fit, multi_feats = score_population_multi(
         delays, batch, pairs, archive, fails, w)
-    f1, _ = score_population(delays, t1, pairs, archive, fails, w)
-    f2, _ = score_population(delays, t2, pairs, archive, fails, w)
+    f1, feats1 = score_one(delays, t1, pairs, archive, fails, w)
+    f2, feats2 = score_one(delays, t2, pairs, archive, fails, w)
     # fitness decomposes: novelty/bug average over traces, delay cost once
     dc = w.delay_cost * delays.mean(axis=-1)
     want = ((f1 + dc) + (f2 + dc)) / 2 - dc
     assert np.allclose(np.asarray(multi_fit), np.asarray(want), rtol=1e-4,
                        atol=1e-5)
     assert multi_feats.shape == (16, 2, K)
+    np.testing.assert_array_equal(multi_feats[:, 0], feats1)
+    np.testing.assert_array_equal(multi_feats[:, 1], feats2)
 
 
 def test_blockwise_first_occurrence_matches_dense():
@@ -83,7 +81,9 @@ def test_long_trace_features_match_dense_and_scale():
     pairs = jnp.asarray(te.sample_pairs(K, H, 0))
     delays = jnp.asarray(
         np.random.RandomState(4).rand(H).astype(np.float32) * 0.05)
-    f_long = schedule_features_long(delays, tr, pairs, 0.005, chunk=512)
+    first, _ = first_occurrence_blockwise(
+        delays, tr.hint_ids, tr.arrival, tr.mask, chunk=512)
+    f_long = precedence_features(first, pairs, 0.005)
     f_dense = schedule_features(delays, tr, pairs, 0.005)
     assert np.allclose(np.asarray(f_long), np.asarray(f_dense), atol=1e-6)
 
@@ -142,7 +142,7 @@ def test_stack_traces_pads_ragged():
 
 
 def test_long_trace_population_scoring_matches_dense():
-    """score_population's automatic blockwise branch (L > threshold) is
+    """The scorer's automatic blockwise branch (L > threshold) is
     numerically identical to the dense scatter-min reference."""
     from namazu_tpu.ops.schedule import LONG_TRACE_THRESHOLD
     n = LONG_TRACE_THRESHOLD + 600
@@ -156,10 +156,9 @@ def test_long_trace_population_scoring_matches_dense():
         np.random.RandomState(1).rand(4, K).astype(np.float32))
     delays = jnp.asarray(
         np.random.RandomState(2).rand(8, H).astype(np.float32) * 0.05)
-    fit, feats = score_population(delays, tr, pairs, archive, fails,
-                                  ScoreWeights())
+    fit, feats = score_one(delays, tr, pairs, archive, fails,
+                           ScoreWeights())
     # dense reference, genome by genome
-    from namazu_tpu.ops.schedule import precedence_features
     for p in range(8):
         dense_first = first_occurrence(
             release_times(delays[p], tr), tr, H)
@@ -216,13 +215,12 @@ def test_bug_planted_past_event_256_is_visible_and_findable():
     key = jax.random.PRNGKey(2)
     fit0 = None
     for _ in range(12):
-        fit, _ = score_population(pop.delays, tr, pairs, archive, target,
-                                  w)
+        fit, _ = score_one(pop.delays, tr, pairs, archive, target, w)
         if fit0 is None:
             fit0 = float(fit.max())
         key, k = jax.random.split(key)
         pop = ga_generation(k, pop, fit, cfg)
-    fit, _ = score_population(pop.delays, tr, pairs, archive, target, w)
+    fit, _ = score_one(pop.delays, tr, pairs, archive, target, w)
     assert float(fit.max()) > fit0 + 1e-3
     best = np.asarray(pop.delays[int(jnp.argmax(fit))])
     # the winning genome delays the late bucket substantially

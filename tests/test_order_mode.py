@@ -22,8 +22,9 @@ from namazu_tpu.ops.schedule import (
     TraceArrays,
     order_release_times,
     schedule_features,
-    score_population,
+    score_population_multi,
 )
+from tests.scoring import score_one, stack
 
 H, L, K = 16, 32, 32
 
@@ -74,14 +75,19 @@ def test_order_features_distinguish_permutations():
     assert not np.allclose(np.asarray(f1), np.asarray(f2))
 
 
-def test_order_mode_population_scoring_and_ga():
+@pytest.mark.parametrize("T", [1, 3])
+def test_order_mode_population_scoring_and_ga(T):
     """GA in order mode finds a priority table matching a target
-    permutation's features better than the population average. Uses the
-    unbatched trace: score_population vmaps over genomes only."""
+    permutation's features better than the population average, against
+    one trace and against a stack of three that differ: the order
+    branch under the trace ``vmap`` scores each trace as it does
+    alone."""
     from namazu_tpu.models.ga import GAConfig, ga_generation, init_population
 
-    trace, enc = trace_of([f"h{i % 8}" for i in range(24)],
-                          [i * 1e-3 for i in range(24)])
+    singles = [trace_of([f"h{(i * (t + 1)) % 8}" for i in range(24)],
+                        [i * 1e-3 for i in range(24)])[0]
+               for t in range(T)]
+    trace, traces = singles[0], stack(*singles)
     pairs = jnp.asarray(te.sample_pairs(K, H, 1))
     w = ScoreWeights(order_mode=True, order_gap=0.001, tau=0.0005,
                      delay_cost=0.0)
@@ -93,20 +99,26 @@ def test_order_mode_population_scoring_and_ga():
 
     cfg = GAConfig(max_delay=1.0)
     pop = init_population(jax.random.PRNGKey(0), 128, H, cfg)
-    fit0, feats0 = score_population(pop.delays, trace, pairs, archive,
-                                    failures, w)
+    fit0, feats0 = score_population_multi(pop.delays, traces, pairs,
+                                          archive, failures, w)
     # scoring is genome-sensitive (guards against the rank computation
     # silently collapsing): different genomes -> different features
     assert float(jnp.std(feats0, axis=0).max()) > 0.0
+    alone = [score_one(pop.delays, tr, pairs, archive, failures, w)
+             for tr in singles]
+    for t, (_fit, feats) in enumerate(alone):
+        np.testing.assert_array_equal(feats0[:, t], feats)
+    np.testing.assert_allclose(
+        fit0, np.mean([f for f, _ in alone], axis=0), rtol=1e-5, atol=1e-6)
     mean0 = float(fit0.mean())
     key = jax.random.PRNGKey(1)
     for g in range(10):
-        fit, _ = score_population(pop.delays, trace, pairs,
-                                  archive, failures, w)
+        fit, _ = score_population_multi(pop.delays, traces, pairs,
+                                        archive, failures, w)
         key, k = jax.random.split(key)
         pop = ga_generation(k, pop, fit, cfg)
-    fitN, _ = score_population(pop.delays, trace, pairs, archive,
-                               failures, w)
+    fitN, _ = score_population_multi(pop.delays, traces, pairs, archive,
+                                     failures, w)
     assert float(fitN.max()) > mean0
 
 

@@ -316,8 +316,8 @@ def test_the_scorers_name_is_called_once_per_rerank_outside_jit(monkeypatch):
 
 
 def test_under_a_trace_the_scorer_runs_inline():
-    """The fused island step and MCTS call the same name with tracers:
-    no nested compiled call, the body as it was."""
+    """The fused island step calls the same name with tracers: no
+    nested compiled call, the body as it was."""
     s = make_search(seed=5)
     _encs, trace, pairs, archive, failures = s._device_inputs_fused(
         [enc_of(8)])

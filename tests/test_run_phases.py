@@ -19,7 +19,7 @@ from namazu_tpu.models.ingest import (
     RunRecordCache,
     ingest_history,
 )
-from namazu_tpu.models.search import MCTSSearch
+from namazu_tpu.models.search import ScheduleSearch
 from namazu_tpu.obs import federation, spans
 from namazu_tpu.orchestrator import Orchestrator
 from namazu_tpu.policy import create_policy
@@ -269,7 +269,7 @@ def records(monkeypatch):
 def test_ingest_observes_a_run_where_it_first_meets_it(tmp_path, fresh_obs,
                                                        records):
     st = make_storage(tmp_path / "st", 3)
-    search = MCTSSearch(cfg(), n_devices=1)
+    search = ScheduleSearch(cfg(), n_devices=1)
     ingest_history(search, st, PARAMS)
     names = [r[0] for r in PHASES]
     assert phase_counts() == {name: 3 for name in names}
@@ -296,7 +296,7 @@ def test_ingest_observes_a_run_where_it_first_meets_it(tmp_path, fresh_obs,
 
 def test_ingest_observes_nothing_without_phases_or_signatures(
         tmp_path, fresh_obs, records):
-    search = MCTSSearch(cfg(), n_devices=1)
+    search = ScheduleSearch(cfg(), n_devices=1)
     # the benchmark's synthesised histories and every older recording
     ingest_history(search, make_storage(tmp_path / "plain", 3, None), PARAMS)
     assert phase_counts() == {}

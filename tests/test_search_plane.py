@@ -22,9 +22,9 @@ from namazu_tpu.ops.schedule import (
     min_sq_distance,
     release_times,
     schedule_features,
-    score_population,
     trace_features,
 )
+from tests.scoring import score_one
 from namazu_tpu.parallel.islands import (
     init_island_state,
     make_fused_island_step,
@@ -122,7 +122,7 @@ def test_score_population_shapes():
     pop = init_population(jax.random.PRNGKey(0), 64, H, GAConfig())
     archive = jnp.full((16, K), 0.5)
     fails = jnp.full((4, K), 0.5)
-    fit, feats = score_population(pop.delays, trace, pairs, archive, fails)
+    fit, feats = score_one(pop.delays, trace, pairs, archive, fails)
     assert fit.shape == (64,)
     assert feats.shape == (64, K)
     assert np.isfinite(np.asarray(fit)).all()
@@ -145,14 +145,14 @@ def test_ga_improves_fitness_toward_target():
     key = jax.random.PRNGKey(2)
     first_best = None
     for g in range(30):
-        fit, _ = score_population(pop.delays, trace, pairs, archive, target,
-                                  weights)
+        fit, _ = score_one(pop.delays, trace, pairs, archive, target,
+                           weights)
         if first_best is None:
             first_best = float(fit.max())
         key, k = jax.random.split(key)
         pop = ga_generation(k, pop, fit, cfg)
-    fit, _ = score_population(pop.delays, trace, pairs, archive, target,
-                              weights)
+    fit, _ = score_one(pop.delays, trace, pairs, archive, target,
+                       weights)
     final_best = float(fit.max())
     assert final_best > first_best + 1e-3
     assert final_best > -0.05  # close to the target interleaving
